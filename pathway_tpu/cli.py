@@ -19,18 +19,28 @@ import sys
 
 import click
 
+from pathway_tpu.internals.chips import child_chip_env
 from pathway_tpu.internals.config import get_pathway_config
 
 
 def _spawn_processes(env_base: dict[str, str], processes: int, args: tuple[str, ...]) -> int:
-    """Fork one subprocess per process id; forward SIGINT/SIGTERM; return the
-    first non-zero exit code (killing the rest), else 0."""
+    """Fork one subprocess per process id, each with a chip of its own;
+    forward SIGINT/SIGTERM; return the first non-zero exit code (killing the
+    rest), else 0."""
     if not args:
         raise click.UsageError("no program given (e.g. `spawn -t 2 python script.py`)")
-    procs: list[subprocess.Popen] = []
-    for pid in range(processes):
-        env = dict(env_base, PATHWAY_PROCESS_ID=str(pid))
-        procs.append(subprocess.Popen(list(args), env=env))
+    try:
+        envs = [
+            dict(
+                env_base,
+                PATHWAY_PROCESS_ID=str(pid),
+                **child_chip_env(env_base, pid, processes),
+            )
+            for pid in range(processes)
+        ]
+    except ValueError as e:  # more processes than chips: nothing spawned yet
+        raise click.UsageError(str(e)) from e
+    procs = [subprocess.Popen(list(args), env=env) for env in envs]
 
     def forward(signum, frame):
         for p in procs:

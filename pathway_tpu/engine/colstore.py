@@ -261,16 +261,6 @@ class ColumnarMultimap:
             tok = _phases.start()
             try:
                 return jax_kernels.join_probe(seg.jk, q_jk)
-            except Exception:  # jax runtime failure → numpy, stop routing
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "JAX join-probe kernel failed; falling back to "
-                    "numpy and disabling kernel routing for this "
-                    "process",
-                    exc_info=True,
-                )
-                jax_kernels.disable()
             finally:
                 _phases.stop(tok, "kernel")
         lo = np.searchsorted(seg.jk, q_jk, side="left")

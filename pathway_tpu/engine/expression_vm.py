@@ -17,7 +17,7 @@ import numpy as np
 
 from pathway_tpu.internals import dtype as dt
 from pathway_tpu.internals import expression as expr_mod
-from pathway_tpu.internals.errors import ERROR, report_error
+from pathway_tpu.internals.errors import ERROR, is_device_error, report_error
 from pathway_tpu.internals.expression import (
     ApplyExpression,
     AsyncApplyExpression,
@@ -323,6 +323,8 @@ def _eval_apply(expr: ApplyExpression, ctx: EvalContext) -> np.ndarray:
         try:
             out[i] = fn(*args, **kwargs)
         except Exception as e:
+            if is_device_error(e):
+                raise
             out[i] = report_error(f"apply {getattr(fn, '__name__', fn)!s}: {e!r}")
     return _tighten(out, expr.return_type)
 
@@ -354,7 +356,9 @@ def _eval_batch_apply(expr: "BatchApplyExpression", ctx: EvalContext) -> np.ndar
                 )
             for j, i in enumerate(idx):
                 out[i] = results[j]
-        except Exception:
+        except Exception as batch_error:
+            if is_device_error(batch_error):
+                raise  # not about a row: never retried at another shape
             # row isolation: retry each row alone so one bad input doesn't error
             # the whole block (matches per-row ApplyExpression semantics; the
             # batch is already on the failing path so the cost is irrelevant)
@@ -366,6 +370,8 @@ def _eval_batch_apply(expr: "BatchApplyExpression", ctx: EvalContext) -> np.ndar
                     )
                     out[i] = r[0]
                 except Exception as e:
+                    if is_device_error(e):
+                        raise
                     out[i] = report_error(
                         f"apply {getattr(expr.fn, '__name__', expr.fn)!s}: {e!r}"
                     )

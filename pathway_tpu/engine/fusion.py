@@ -102,15 +102,15 @@ class ComposedSegment:
     traceable whitelist and the batch's column dtypes are numeric
     (``expression_vm.infer_fused_dtype``); the whitelist is chosen so XLA
     results are bit-identical to the numpy path (elementwise IEEE ops,
-    exact integer ops, no value-dependent fallbacks), and any kernel failure
-    falls back to numpy for good."""
+    exact integer ops, no value-dependent fallbacks). The kernel is a host
+    kernel (u64 keys under x64, numpy in and out): it runs on the XLA CPU
+    device (``jax_kernels.host_device``), and a failure in it propagates."""
 
     __slots__ = (
         "nodes",
         "stages",
         "label",
         "_kernels",
-        "_jax_dead",
         "_jax_cfg",
     )
 
@@ -129,7 +129,6 @@ class ComposedSegment:
         self.label = "+".join(n.name for n in nodes)
         # dtype signature -> _CompiledSegment | None (None = ineligible)
         self._kernels: dict[tuple, Any] = {}
-        self._jax_dead = False
         self._jax_cfg = None
 
     # ---------------------------------------------------------------- execute
@@ -153,16 +152,10 @@ class ComposedSegment:
         if aud is None and self._jax_wanted(len(batch)):
             # audited ticks stay on the host program: the fused kernel's
             # single lane mask cannot attribute per-member edge counts
-            kern = ent.jax_kernel(self)
-            if kern is not None:
-                out = self._run_jax(kern, batch, time)
-                if out is not None:
-                    return out
+            return self._run_jax(ent.jax_kernel(self), batch, time)
         return self._run_fast(ent.fast, batch, time, aud)
 
     def _jax_wanted(self, n: int) -> bool:
-        if self._jax_dead:
-            return False
         mode, min_rows, avail = self._jax_mode()
         if mode == "off" or not avail:
             return False
@@ -427,86 +420,72 @@ class _CompiledSegment:
         if self._jax is not _MISSING:
             return self._jax
         in_names, out_names = self.in_names, self.out_names
-        try:
-            import jax
+        import jax
 
-            from pathway_tpu.engine.expression_vm import trace_fused
-            from pathway_tpu.engine.jax_kernels import _donate_active
-            from pathway_tpu.observability import device as _dev_prof
+        from pathway_tpu.engine.expression_vm import trace_fused
+        from pathway_tpu.engine.jax_kernels import _donate_active, host_device
+        from pathway_tpu.observability import device as _dev_prof
 
-            stages = seg.stages
+        stages = seg.stages
 
-            def kernel(keys, cols):
-                import jax.numpy as jnp
+        def kernel(keys, cols):
+            import jax.numpy as jnp
 
-                env = dict(zip(in_names, cols))
-                mask = None
-                for st in stages:
-                    if st[0] == "filter":
-                        m = trace_fused(st[2], env, keys)
-                        mask = m if mask is None else mask & m
-                    elif st[0] == "rowwise":
-                        env = {
-                            name: trace_fused(e, env, keys) for name, e in st[2]
-                        }
-                    else:
-                        _, _, columns, rename = st
-                        env = {rename.get(c, c): env[c] for c in columns}
-                    # filtered-out lanes keep computing downstream stages —
-                    # the whitelist has no value-dependent failure modes, and
-                    # masked lanes are dropped on the host
-                if mask is None:
-                    mask = jnp.ones(keys.shape, dtype=bool)
-                return mask, tuple(env[c] for c in out_names)
+            env = dict(zip(in_names, cols))
+            mask = None
+            for st in stages:
+                if st[0] == "filter":
+                    m = trace_fused(st[2], env, keys)
+                    mask = m if mask is None else mask & m
+                elif st[0] == "rowwise":
+                    env = {
+                        name: trace_fused(e, env, keys) for name, e in st[2]
+                    }
+                else:
+                    _, _, columns, rename = st
+                    env = {rename.get(c, c): env[c] for c in columns}
+                # filtered-out lanes keep computing downstream stages —
+                # the whitelist has no value-dependent failure modes, and
+                # masked lanes are dropped on the host
+            if mask is None:
+                mask = jnp.ones(keys.shape, dtype=bool)
+            return mask, tuple(env[c] for c in out_names)
 
-            # per-tick blocks are dead after the launch: donate them on
-            # accelerator backends so XLA reuses their buffers for outputs
-            # (the PATHWAY_ARRANGE_DONATE discipline; CPU ignores donation)
-            if _donate_active(None):
-                jitted = jax.jit(kernel, donate_argnums=(0, 1))
-            else:
-                jitted = jax.jit(kernel)
-            wrapped = _dev_prof.traced_jit(f"engine.fused_chain/{seg.label}", jitted)
-            self._jax = (wrapped, in_names, out_names)
-        except Exception:  # pragma: no cover - jax import/trace failure
-            self._jax = None
+        # per-tick blocks are dead after the launch: the
+        # PATHWAY_ARRANGE_DONATE discipline, decided for the device the
+        # kernel runs on (auto never donates on the CPU backend)
+        if _donate_active(host_device()):
+            jitted = jax.jit(kernel, donate_argnums=(0, 1))
+        else:
+            jitted = jax.jit(kernel)
+        wrapped = _dev_prof.traced_jit(f"engine.fused_chain/{seg.label}", jitted)
+        self._jax = (wrapped, in_names, out_names)
         return self._jax
 
 
-def _seg_run_jax(self, kern, batch: DeltaBatch, time: int) -> DeltaBatch | None:
+def _seg_run_jax(self, kern, batch: DeltaBatch, time: int) -> DeltaBatch:
     wrapped, in_names, out_names = kern
-    from pathway_tpu.engine.jax_kernels import _bucket
-    from pathway_tpu import jax_compat
+    import jax
+
+    from pathway_tpu.engine.jax_kernels import _bucket, host_device
 
     n = len(batch)
     bs = _bucket(n)
-    try:
-        keys = batch.keys
+    keys = batch.keys
+    if bs != n:
+        keys = np.concatenate([keys, np.zeros(bs - n, dtype=np.uint64)])
+    cols = []
+    for c in in_names:
+        a = batch.data[c]
         if bs != n:
-            keys = np.concatenate(
-                [keys, np.zeros(bs - n, dtype=np.uint64)]
-            )
-        cols = []
-        for c in in_names:
-            a = batch.data[c]
-            if bs != n:
-                a = np.concatenate([a, np.zeros(bs - n, dtype=a.dtype)])
-            cols.append(a)
-        with jax_compat.enable_x64():
-            mask, outs = wrapped(keys, tuple(cols))
-            mask = np.asarray(mask)[:n]
-            outs = [np.asarray(o)[:n] for o in outs]
-    except Exception:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "fused chain kernel %s failed; falling back to numpy for "
-            "this process",
-            self.label,
-            exc_info=True,
-        )
-        self._jax_dead = True
-        return None
+            a = np.concatenate([a, np.zeros(bs - n, dtype=a.dtype)])
+        cols.append(a)
+    with jax.enable_x64():
+        # committed to the host device: the launch follows its inputs there
+        # whatever the default backend is
+        mask, outs = wrapped(*jax.device_put((keys, tuple(cols)), host_device()))
+        mask = np.asarray(mask)[:n]
+        outs = [np.asarray(o)[:n] for o in outs]
     # stats: the single fused lane mask can't attribute per-member
     # intermediate counts — block-in is booked for every member (the jax
     # tier only engages on large blocks / explicit opt-in; the register

@@ -648,8 +648,9 @@ def _launch_udf_batch(spec: MicrobatchUdfSpec, items: list) -> list:
     """Run one padded bucket through the UDF's batch fn. ``items`` are
     ``(args_tuple, kwargs_tuple)`` rows; a failing batch retries row by row so
     one bad input poisons only its own row (the inline BatchApply discipline,
-    ``expression_vm._eval_batch_apply``)."""
-    from pathway_tpu.internals.errors import report_error
+    ``expression_vm._eval_batch_apply``). A device error is not about a row
+    and propagates (``errors.is_device_error``)."""
+    from pathway_tpu.internals.errors import is_device_error, report_error
 
     args = [list(col) for col in zip(*(it[0] for it in items))]
     kwargs = {
@@ -662,7 +663,9 @@ def _launch_udf_batch(spec: MicrobatchUdfSpec, items: list) -> list:
                 f"batch UDF returned {len(results)} results for {len(items)} rows"
             )
         return list(results)
-    except Exception:
+    except Exception as batch_error:
+        if is_device_error(batch_error):
+            raise
         out = []
         # pad rows are the SAME object as the last real item (repeat-last
         # padding) — the identity cache computes each distinct row once, so
@@ -679,6 +682,8 @@ def _launch_udf_batch(spec: MicrobatchUdfSpec, items: list) -> list:
                 )
                 val = r[0]
             except Exception as e:
+                if is_device_error(e):
+                    raise
                 val = report_error(
                     f"apply {getattr(spec.fn, '__name__', spec.fn)!s}: {e!r}"
                 )
@@ -1884,6 +1889,19 @@ class JoinNode(Node):
     name = "join"
 
     snapshot_attrs = ("store", "jk_counts")
+
+    def drain(self):
+        """``_apply_side`` applies a block's retractions before its
+        insertions, which is right for one producer's block. Several blocks
+        accepted on a port in one tick (sharded runtimes: an upstream reduce
+        re-emits once per sweep round) concatenate to ``+a, -a, +b``: the
+        retraction of ``a`` would find nothing and ``a`` would stay beside
+        ``b``. Net such a port first."""
+        several = [len(buf) > 1 for buf in self._buffers]
+        return [
+            consolidate(b) if many and b is not None else b
+            for b, many in zip(super().drain(), several)
+        ]
 
     def exchange_key(self, port):
         col = self.left_on if port == 0 else self.right_on

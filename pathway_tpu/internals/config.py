@@ -309,8 +309,9 @@ class PathwayConfig:
 
     @property
     def microbatch_max_batch(self) -> int:
-        """Device launch chunk for cross-tick microbatching; 512 is the measured
-        best batch on v5e (BENCH_r05 ``device_docs_per_s_by_batch``)."""
+        """Device launch chunk for cross-tick microbatching; 512 was the best
+        batch on an earlier accelerator stack (record deleted; ROADMAP A1/A5
+        re-measure it)."""
         n = _env_int("PATHWAY_MICROBATCH_MAX_BATCH", 512)
         if n < 1:
             raise ValueError(

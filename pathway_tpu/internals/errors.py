@@ -44,6 +44,26 @@ def is_error(v: object) -> bool:
     return v is ERROR
 
 
+def mark_device_error(exc: BaseException, kernel: str) -> None:
+    """Record on an in-flight exception that it came out of a device kernel
+    launch (``observability.device.traced_jit`` calls this)."""
+    exc.pathway_device_kernel = kernel  # type: ignore[attr-defined]
+    exc.add_note(f"in device kernel {kernel!r}")
+
+
+def is_device_error(exc: BaseException) -> bool:
+    """A failure of the device runtime or its compilers: tracing, lowering
+    (Mosaic), compiling or running a kernel. It says nothing about any one
+    row, so batched-UDF dispatch must not retry it row by row — that turns
+    one failed launch at B=512 into 512 launches at B=1 (another shape, on
+    another attention block) and an exit code of 0."""
+    if getattr(exc, "pathway_device_kernel", None) is not None:
+        return True
+    import jax
+
+    return isinstance(exc, jax.errors.JaxRuntimeError)
+
+
 class EngineError(Exception):
     pass
 

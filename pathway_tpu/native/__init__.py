@@ -1,9 +1,16 @@
 """Native host-runtime kernels (C, built lazily with the toolchain in the
 image; every kernel has a bit-identical pure-Python fallback in the caller, so
-a missing compiler only costs speed, never correctness)."""
+a missing compiler only costs speed, never correctness).
+
+A build is keyed on the CONTENT of its source: the shared object is named
+after the source's digest, so a copied tree (which keeps no mtimes) and an
+edited source both get the object that matches what is on disk, built on the
+machine that runs it, and one failed compile decides nothing about the next
+process."""
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -13,20 +20,14 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD = os.path.join(_DIR, "_build")
 
 
-def _load(name: str):
-    so_path = os.path.join(_BUILD, f"{name}.so")
+def load(name: str):
+    """The compiled module for ``<name>.c``, building it first if no object
+    for this exact source exists. Raises when the build or the load fails."""
     src = os.path.join(_DIR, f"{name}.c")
-    src_mtime = os.path.getmtime(src)
-    marker = os.path.join(_BUILD, f"{name}.failed")
-    if not os.path.exists(so_path) or os.path.getmtime(so_path) < src_mtime:
-        # a recorded failure for this exact source skips the doomed compile on
-        # every later process start (cleared by touching the source)
-        if os.environ.get(f"PATHWAY_NATIVE_{name.upper()}_FAILED") == str(src_mtime):
-            raise RuntimeError(f"native build of {name} previously failed")
-        if os.path.exists(marker):
-            with open(marker) as f:
-                if f.read().strip() == str(src_mtime):
-                    raise RuntimeError(f"native build of {name} previously failed")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so_path = os.path.join(_BUILD, f"{name}-{digest}.so")
+    if not os.path.exists(so_path):
         os.makedirs(_BUILD, exist_ok=True)
         import numpy as np
 
@@ -37,16 +38,7 @@ def _load(name: str):
             f"-I{np.get_include()}",
             src, "-o", tmp,
         ]
-        try:
-            subprocess.run(cmd, check=True, capture_output=True)
-        except Exception:
-            try:
-                with open(marker, "w") as f:
-                    f.write(str(src_mtime))
-            except OSError:
-                pass  # read-only install: the env guard below still helps
-            os.environ[f"PATHWAY_NATIVE_{name.upper()}_FAILED"] = str(src_mtime)
-            raise
+        subprocess.run(cmd, check=True, capture_output=True)
         os.replace(tmp, so_path)  # atomic publish; racing winners are identical
     spec = importlib.util.spec_from_file_location(name, so_path)
     mod = importlib.util.module_from_spec(spec)
@@ -57,6 +49,6 @@ def _load(name: str):
 def try_load(name: str):
     """Compiled module or None (any build/load failure falls back to Python)."""
     try:
-        return _load(name)
+        return load(name)
     except Exception:
         return None

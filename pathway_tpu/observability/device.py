@@ -129,14 +129,11 @@ def _jax_mod():
 
 
 def _block(out: Any) -> None:
-    """Wait for device completion; silently a no-op off-device."""
+    """Wait for device completion (a device failure raises here, in the
+    launch that caused it)."""
     j = _jax_mod()
-    if j is None:
-        return
-    try:
+    if j is not None:
         j.block_until_ready(out)
-    except Exception:
-        pass
 
 
 _listener_registered = False
@@ -405,6 +402,11 @@ class _TracedJit:
                 st.note_split(self.label, t1 - t0, _time.perf_counter_ns() - t1)
                 return out
             return self.fn(*args, **kwargs)
+        except Exception as e:
+            from pathway_tpu.internals.errors import mark_device_error
+
+            mark_device_error(e, self.label)
+            raise
         finally:
             pop_label()
 
@@ -413,7 +415,12 @@ _wrappers: "weakref.WeakSet[_TracedJit]" = weakref.WeakSet()
 
 
 def traced_jit(label: str, fn: Callable) -> Callable:
-    """Wrap an (already-jitted) callable with compile/shape telemetry."""
+    """Wrap an (already-jitted) callable with compile/shape telemetry. Every
+    device kernel is built through here, so this is also where the compile
+    cache is placed — before anything compiles."""
+    from pathway_tpu.internals.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     return _TracedJit(label, fn)
 
 

@@ -11,12 +11,14 @@ to erase the win), unrolls the heads as 64-wide column slices, and computes
 scores→mask→softmax→context per head entirely on-chip. One HBM read of
 q/k/v and one write of ctx — the information-theoretic floor.
 
-Numerics mirror ``encoder._sdpa``'s fallback: f32 score accumulation, mask
-fill −1e30 (finite: fully-padded rows give uniform probs, not NaN), f32
-softmax, bf16 context matmul inputs with f32 accumulation.
+Numerics: f32 score accumulation, mask fill −1e30 (finite: fully-padded rows
+give uniform probs, not NaN), f32 softmax, bf16 context matmul inputs with
+f32 accumulation.
 
-``attention_short_flat`` returns ``None`` (caller uses the XLA path) when
-pallas is unavailable or the shapes don't meet the tile constraints.
+``attention_short_flat`` returns ``None`` (caller uses the XLA path) when the
+shapes don't meet the tile constraints — a selection made from what the code
+can see. A failure to trace, lower or compile the kernel is an error and
+propagates.
 """
 
 from __future__ import annotations
@@ -76,9 +78,9 @@ def _attention_short_impl(
 #: scoped-VMEM budget for one grid step (bytes): q/k/v/o tiles
 #: (double-buffered by the pipeline) + the per-head f32 score tile. The
 #: hardware limit is 16 MiB; Mosaic compile failures surface at OUTER-jit
-#: compile time where no fallback can catch them, so the gate must be
-#: sufficient, not optimistic. block_b=16 at (L=128, D=384) measures best
-#: (56% MFU) and sits at 14.7 MB under this budget.
+#: compile time, as a crash of that launch, so the gate must be sufficient,
+#: not optimistic. block_b=16 at (L=128, D=384) sits at 14.7 MB under this
+#: budget.
 _VMEM_BUDGET = 15 * 1024 * 1024
 
 
@@ -91,9 +93,9 @@ def _vmem_estimate(block_b: int, L: int, D: int) -> int:
 def attention_short_flat(q, k, v, mask, n_heads: int, scale: float, block_b: int = 16):
     """Flat-layout attention: [B, L, D] q/k/v + [B, L] key mask →
     [B, L, D] context, heads as D/n_heads column groups. Returns ``None``
-    when the kernel doesn't apply (caller falls back to XLA). The gate must
+    when the kernel doesn't apply (caller takes the XLA path). The gate must
     reject anything that could fail MOSAIC COMPILATION — those errors raise
-    at the enclosing jit's compile, past any try/except here."""
+    at the enclosing jit's compile and fail the launch."""
     B, L, D = q.shape
     hd = D // n_heads
     if L > 128 or L % 8 != 0 or hd % 64 != 0 or D % 128 != 0:
@@ -108,7 +110,4 @@ def attention_short_flat(q, k, v, mask, n_heads: int, scale: float, block_b: int
             break
     else:
         return None
-    try:
-        return _attention_short_impl(q, k, v, mask, n_heads, scale, block_b)
-    except Exception:
-        return None
+    return _attention_short_impl(q, k, v, mask, n_heads, scale, block_b)

@@ -142,36 +142,18 @@ def _use_pallas_attention() -> bool:
 
     if os.environ.get("PATHWAY_PALLAS_ATTENTION", "auto").lower() in ("off", "0", "false"):
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _sdpa(q, k, v, mask, scale):
     """Fused scaled-dot-product attention on [B, L, H, hd] tensors (r5 MFU
     item): ``jax.nn.dot_product_attention`` hands XLA one fusible attention
-    expression, with the manual chain as fallback for stacks without the
-    primitive. Key-padding mask is [B, L] bool. (The pallas short-seq kernel
+    expression. Key-padding mask is [B, L] bool. (The pallas short-seq kernel
     enters one level up, in ``_attention``, on the FLAT layout — reshaping
     to heads first costs more than the kernel saves, measured.)"""
-    try:
-        return jax.nn.dot_product_attention(
-            q, k, v, mask=mask[:, None, None, :], scale=scale
-        )
-    except (AttributeError, TypeError):
-        qh = q.transpose(0, 2, 1, 3)
-        kh = k.transpose(0, 2, 1, 3)
-        vh = v.transpose(0, 2, 1, 3)
-        scores = jnp.einsum(
-            "bhqd,bhkd->bhqk", qh, kh, preferred_element_type=jnp.float32
-        ) * scale
-        scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        ctx = jnp.einsum(
-            "bhqk,bhkd->bhqd", probs, vh, preferred_element_type=jnp.float32
-        ).astype(q.dtype)
-        return ctx.transpose(0, 2, 1, 3)
+    return jax.nn.dot_product_attention(
+        q, k, v, mask=mask[:, None, None, :], scale=scale
+    )
 
 
 def _attention(x, wqkv, wo, mask, n_heads, allow_pallas=True):

@@ -482,6 +482,9 @@ class ClusterRuntime:
         # tick, else None (see engine.graph.Scheduler)
         self._rp = None
         self.local_workers: dict[int, _LocalWorker] = {}
+        # set once every local worker graph exists: a faster peer's first
+        # blocks can arrive while this process is still building
+        self._built = threading.Event()
         # intra-process rows ride the local mesh; cross-process rows take the
         # TCP links (the ICI/DCN split — see parallel/device_plane.py)
         from pathway_tpu.parallel.device_plane import make_cluster_device_plane
@@ -547,9 +550,11 @@ class ClusterRuntime:
             self._ctx_local = ctx  # any local context (non-0 processes have no
             # global worker 0; persistence reads only the graph shape from it)
             self.local_workers[w] = _LocalWorker(w, ctx.graph)
+        self._built.set()
 
     # ---------------------------------------------------------------- routing
     def _on_remote_block(self, worker: int, node_index: int, port: int, batch: DeltaBatch) -> None:
+        self._built.wait()
         lw = self.local_workers[worker]
         with lw.lock:
             lw.graph.nodes[node_index].accept(port, batch)

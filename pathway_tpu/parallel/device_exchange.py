@@ -70,19 +70,18 @@ def _jitted_exchange(
         in_specs.append(P(axis))
     if fused:
         in_specs.append(P(None, axis))
-    from pathway_tpu.jax_compat import shard_map
     from pathway_tpu.observability import device as _dev_prof
 
     label = "device_exchange.fused_consolidate" if fused else "device_exchange.all_to_all"
     return _dev_prof.traced_jit(
         label,
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 kern,
                 mesh=mesh,
                 in_specs=tuple(in_specs),
                 out_specs=(P(None, axis), P(axis), P(axis), [P(axis)] * n_cols),
-                check=True,
+                check_vma=True,
             )
         ),
     )

@@ -104,18 +104,20 @@ class DeviceExchangePlane:
                     return True
                 if self._unavailable:
                     return False
-                try:
-                    import jax
-                    from jax.sharding import Mesh
+                import jax
+                from jax.sharding import Mesh
 
-                    devices = jax.devices()
-                    if len(devices) < self.n_workers:
-                        self._unavailable = True
-                        return False
-                    self.mesh = Mesh(np.array(devices[: self.n_workers]), (self.axis,))
-                except Exception:
+                devices = jax.devices()
+                if len(devices) < self.n_workers:
+                    if self.force:
+                        raise RuntimeError(
+                            f"PATHWAY_DEVICE_EXCHANGE=on needs one device per "
+                            f"worker: {self.n_workers} workers, "
+                            f"{len(devices)} device(s)"
+                        )
                     self._unavailable = True
                     return False
+                self.mesh = Mesh(np.array(devices[: self.n_workers]), (self.axis,))
         return True
 
     @staticmethod

@@ -27,6 +27,7 @@ import time as _time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
+from pathway_tpu.internals.chips import child_chip_env
 from pathway_tpu.internals.config import get_pathway_config
 from pathway_tpu.internals.telemetry import record_event
 
@@ -120,9 +121,14 @@ class Supervisor:
         env["PATHWAY_PROCESS_ID"] = str(pid)
         env["PATHWAY_FIRST_PORT"] = str(self.first_port)
         env["PATHWAY_SUPERVISOR_ATTEMPT"] = str(attempt)
+        # one process for each chip (ValueError: more processes than chips)
+        env.update(child_chip_env(self.env, pid, self.processes))
         return env
 
     def _launch(self, attempt: int) -> tuple[list[subprocess.Popen], list[str]]:
+        # every environment is built before the first child starts, so a
+        # usage error (more processes than chips) spawns nothing
+        envs = [self._child_env(pid, attempt) for pid in range(self.processes)]
         procs: list[subprocess.Popen] = []
         logs: list[str] = []
         for pid in range(self.processes):
@@ -136,7 +142,7 @@ class Supervisor:
                 procs.append(
                     subprocess.Popen(
                         self.program,
-                        env=self._child_env(pid, attempt),
+                        env=envs[pid],
                         stdout=out,
                         stderr=subprocess.STDOUT if out is not None else None,
                     )

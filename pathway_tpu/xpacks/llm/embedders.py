@@ -134,8 +134,9 @@ class SentenceTransformerEmbedder(BaseEmbedder):
     """
 
     is_batched = True
-    # cross-tick microbatcher knobs: 512 is the measured-best device batch
-    # (BENCH_r05 ``device_docs_per_s_by_batch``); buckets below 8 waste the MXU
+    # cross-tick microbatcher knobs: 512 was the best device batch on an
+    # earlier accelerator stack (its record is deleted; ROADMAP A1/A5
+    # re-measure it); buckets below 8 waste the MXU
     microbatch_max_batch = 512
     microbatch_min_bucket = 8
 

@@ -92,12 +92,17 @@ def test_explain_live_endpoint_with_span_ids(monkeypatch):
     monkeypatch.setenv("PATHWAY_TRACE", "on")
     monkeypatch.setenv("PATHWAY_MONITORING_HTTP_PORT", "20731")
 
+    probed = threading.Event()
+
     class Subj(pw.io.python.ConnectorSubject):
         def run(self):
             for i in range(120):
                 self.next(x=i)
                 if i % 10 == 9:
                     time.sleep(0.04)
+            # the run (and its monitoring server) ends with this source: hold
+            # it open until the probe has asked its question of the live run
+            probed.wait(timeout=15)
 
     G.clear()
     t = pw.io.python.read(Subj(), schema=pw.schema_from_types(x=int))
@@ -108,31 +113,39 @@ def test_explain_live_endpoint_with_span_ids(monkeypatch):
 
     def probe():
         try:
-            # discover live sinks via the error payload, then explain a row
-            # (retry while the server and the first sink rows come up)
-            base = "http://127.0.0.1:20731/explain"
-            deadline = time.monotonic() + 5.0
-            doc = None
-            while time.monotonic() < deadline:
-                try:
-                    doc = json.loads(urllib.request.urlopen(base, timeout=2).read())
-                    if doc.get("sinks"):
-                        break
-                except OSError:
-                    pass
-                time.sleep(0.05)
-            assert doc is not None, "monitoring server never came up"
-            got["listing"] = doc
-            sinks = doc.get("sinks") or []
-            if sinks:
-                from pathway_tpu.observability import lineage as lm
+            _probe()
+        finally:
+            probed.set()
 
+    def _probe():
+        try:
+            # discover live sinks via the error payload, then explain a row.
+            # The server is up before the run installs its lineage store, so
+            # an early listing can still show the PREVIOUS run's sinks: keep
+            # asking until a listed sink has rows in the store of this run.
+            from pathway_tpu.observability import lineage as lm
+
+            base = "http://127.0.0.1:20731/explain"
+            deadline = time.monotonic() + 12.0  # inside the source's 15 s hold
+            while time.monotonic() < deadline and "doc" not in got:
+                try:
+                    got["listing"] = json.loads(urllib.request.urlopen(base, timeout=2).read())
+                except OSError:
+                    time.sleep(0.05)
+                    continue
                 store = lm.current()
-                store.fold()
-                ring = store.sinks.get(sinks[0])
-                if ring and ring.data:
-                    key = next(iter(ring.data))
-                    got["doc"] = _explain_live(20731, sinks[0], key)
+                for sink in got["listing"].get("sinks") or []:
+                    if store is None:
+                        break
+                    store.fold()
+                    ring = store.sinks.get(sink)
+                    if ring and ring.data:
+                        doc = _explain_live(20731, sink, next(iter(ring.data)))
+                        if doc.get("ok"):  # not ok: that store was the stale one
+                            got["doc"] = doc
+                            break
+                time.sleep(0.05)
+            assert "listing" in got, "monitoring server never came up"
         except Exception as e:  # pragma: no cover - surfaced by asserts
             got["error"] = repr(e)
 
@@ -144,7 +157,7 @@ def test_explain_live_endpoint_with_span_ids(monkeypatch):
     assert got["listing"]["ok"] is False  # missing sink= lists the sinks
     assert got["listing"]["sinks"]
     doc = got.get("doc")
-    assert doc is not None and doc["ok"]
+    assert doc is not None and doc["ok"], got
     # with tracing on, ingested rows carry the originating tick span id
     spans = [i["span_id"] for i in doc["inputs"]]
     assert spans and any(s is not None for s in spans), doc["inputs"]
