@@ -397,7 +397,7 @@ class DeliveryPlane:
             if tracer is not None:
                 tracer.event(
                     "delivery/stage",
-                    **{"pathway.epoch": epoch, "pathway.rows": total},
+                    {"pathway.epoch": epoch, "pathway.rows": total},
                 )
             # crash window 1 (the r7 sharded-sink lesson): staged durable,
             # manifest commit NOT yet reached — a kill here must discard the
@@ -427,7 +427,7 @@ class DeliveryPlane:
             if tracer is not None:
                 tracer.event(
                     "delivery/publish",
-                    **{
+                    {
                         "pathway.rows": rows,
                         "pathway.epoch": max(w.published_epoch for w in self.writers),
                     },

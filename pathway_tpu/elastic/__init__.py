@@ -257,7 +257,7 @@ class ElasticPlane:
         if tracer is not None:
             tracer.event(
                 "elastic/rescale",
-                **{
+                {
                     "pathway.elastic.target": int(target),
                     "pathway.elastic.version": version,
                     "pathway.elastic.reason": reason,

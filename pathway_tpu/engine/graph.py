@@ -19,11 +19,13 @@ from typing import Callable
 import numpy as np
 
 from pathway_tpu.engine.blocks import DeltaBatch, concat_batches
+from pathway_tpu import observability as _obs
 from pathway_tpu.internals.trace import run_annotated as _run_annotated
 from pathway_tpu.observability import audit as _audit
 from pathway_tpu.observability import device as _device_prof
 from pathway_tpu.observability import engine_phases as _phases
 from pathway_tpu.observability import requests as _requests
+from pathway_tpu.observability import spans as _spans
 from pathway_tpu.resilience import faults as _faults
 
 END_OF_STREAM = np.iinfo(np.int64).max  # frontier value after all input closed
@@ -211,10 +213,11 @@ class Scheduler:
         self.graph = graph
         self.current_time = 0
         self.on_tick_done: list[Callable[[int], None]] = []
-        # live tracing (observability plane): None when PATHWAY_TRACE=off —
-        # the hot loops below pay exactly one is-not-None test per guard
+        # live tracing (observability plane): None when PATHWAY_TRACE=off and
+        # no profiler session is on; ``_tr`` is the tracer during a sampled
+        # tick — the hot loops below pay exactly one is-not-None test per guard
         self.tracer = None
-        self._trace_active = False
+        self._tr = None
         self.transient = transient
         # request-scoped tracing (observability/requests.py): the installed
         # plane while a request is in flight this tick, else None — sweep
@@ -255,64 +258,50 @@ class Scheduler:
                 routed = True
         return routed
 
-    def _sweep_legacy(self, time: int) -> bool:
-        """The r14 sweep, verbatim: one full topo scan, one node per step.
-        Active under ``PATHWAY_FUSE=off`` (plan is None)."""
+    def _run_node(self, node: Node, time: int, aud) -> None:
+        """One node step: drain, process, span, route."""
+        inputs = node.drain()
+        rows_in = sum(len(b) for b in inputs if b is not None)
+        node.stats_rows_in += rows_in
+        tr, rp = self._tr, self._rp
+        tok = (
+            _spans.step_begin(tr, rp, f"sweep/{node.name}")
+            if tr is not None or rp is not None
+            else None
+        )
+        t0 = _time.perf_counter_ns()
+        out = _run_annotated(node, node.process, inputs, time)
+        node.stats_time_ns += _time.perf_counter_ns() - t0
+        if tok is not None:
+            _spans.step_end(
+                tok, time, rows_in, sum(len(b) for b in out if b is not None),
+                {"pathway.operator.id": node.node_index},
+            )
+        if aud is not None:
+            # audit plane: per-edge cardinality/selectivity counters
+            aud.note_edge(node, inputs, out)
+        self._route(node, out)
+
+    def _sweep_legacy(self, time: int, aud) -> bool:
+        """The r14 sweep: one full topo scan, one node per step. Active under
+        ``PATHWAY_FUSE=off`` (plan is None)."""
         any_work = False
-        trace = self._trace_active
-        rp = self._rp
-        aud = _audit.current()
-        aud_note = aud is not None and aud.edge_sampled
         for node in self.graph.nodes:
-            if not node.has_pending():
-                continue
-            inputs = node.drain()
-            rows_in = sum(len(b) for b in inputs if b is not None)
-            node.stats_rows_in += rows_in
-            if trace or rp is not None:
-                w0 = _time.time_ns()
-                dev0 = _device_prof.thread_device_wait_ns() if trace else 0
-            t0 = _time.perf_counter_ns()
-            out = _run_annotated(node, node.process, inputs, time)
-            elapsed_ns = _time.perf_counter_ns() - t0
-            node.stats_time_ns += elapsed_ns
-            if trace or rp is not None:
-                w1 = _time.time_ns()
-                if rp is not None and (
-                    rows_in
-                    or any(b is not None and not b.is_empty for b in out)
-                ):
-                    # a no-op visit (nothing drained, nothing emitted) touched
-                    # no request's rows — don't spend the per-tick ring budget
-                    rp.note_stage(time, f"sweep/{node.name}", w0, w1, rows_in)
-            if trace:
-                dev_ns = _device_prof.thread_device_wait_ns() - dev0
-                self.tracer.span(
-                    f"sweep/{node.name}",
-                    w0,
-                    w1,
-                    {
-                        "pathway.operator.id": node.node_index,
-                        "pathway.rows_in": rows_in,
-                        "pathway.rows_out": sum(len(b) for b in out if b is not None),
-                        "pathway.device_ms": round(dev_ns / 1e6, 3),
-                    },
-                )
-                if dev_ns:
-                    _device_prof.stats().note_span_split(
-                        f"sweep/{node.name}", max(0, elapsed_ns - dev_ns), dev_ns
-                    )
-            if aud_note:
-                aud.note_edge(node, inputs, out)
-            self._route(node, out)
-            any_work = True
+            if node.has_pending():
+                self._run_node(node, time, aud)
+                any_work = True
         return any_work
 
     def _sweep(self, time: int) -> bool:
         """Drain the dirty steps in topo order; returns True if any step did
         work. Quiescence check is O(1): an empty dirty set."""
+        aud = _audit.current()
+        # edge cardinality recording rides the audit plane's deterministic
+        # tick sample — unsampled ticks pay only this flag read
+        if aud is not None and not aud.edge_sampled:
+            aud = None
         if self.plan is None:
-            return self._sweep_legacy(time)
+            return self._sweep_legacy(time, aud)
         dirty = self._dirty
         if not dirty:
             return False
@@ -320,12 +309,6 @@ class Scheduler:
         dirty.clear()
         self._heap = heap
         any_work = False
-        trace = self._trace_active
-        rp = self._rp
-        aud = _audit.current()
-        # edge cardinality recording rides the audit plane's deterministic
-        # tick sample — unsampled ticks pay only this flag read
-        aud_note = aud is not None and aud.edge_sampled
         by_pos = self.plan.by_pos
         last = -1
         try:
@@ -335,105 +318,44 @@ class Scheduler:
                     continue  # duplicate marks collapse (ascending pops)
                 last = pos
                 step = by_pos[pos]
-                chain = step.chain
-                if chain is not None:
-                    if self._run_chain(chain, time, trace, aud if aud_note else None):
+                if step.chain is not None:
+                    if self._run_chain(step.chain, time, aud):
                         any_work = True
-                    continue
-                node = step.node
-                if not node.has_pending():
-                    continue
-                inputs = node.drain()
-                rows_in = sum(len(b) for b in inputs if b is not None)
-                node.stats_rows_in += rows_in
-                if trace or rp is not None:
-                    w0 = _time.time_ns()
-                    # host/device split: traced dispatches inside this node
-                    # accumulate their block_until_ready wait on sampled ticks
-                    dev0 = _device_prof.thread_device_wait_ns() if trace else 0
-                t0 = _time.perf_counter_ns()
-                out = _run_annotated(node, node.process, inputs, time)
-                elapsed_ns = _time.perf_counter_ns() - t0
-                node.stats_time_ns += elapsed_ns
-                if trace or rp is not None:
-                    w1 = _time.time_ns()
-                    if rp is not None and (
-                        rows_in
-                        or any(b is not None and not b.is_empty for b in out)
-                    ):
-                        # a no-op visit (nothing drained, nothing emitted) touched
-                        # no request's rows — don't spend the per-tick ring budget
-                        rp.note_stage(time, f"sweep/{node.name}", w0, w1, rows_in)
-                if trace:
-                    dev_ns = _device_prof.thread_device_wait_ns() - dev0
-                    self.tracer.span(
-                        f"sweep/{node.name}",
-                        w0,
-                        w1,
-                        {
-                            "pathway.operator.id": node.node_index,
-                            "pathway.rows_in": rows_in,
-                            "pathway.rows_out": sum(
-                                len(b) for b in out if b is not None
-                            ),
-                            "pathway.device_ms": round(dev_ns / 1e6, 3),
-                        },
-                    )
-                    if dev_ns:
-                        _device_prof.stats().note_span_split(
-                            f"sweep/{node.name}", max(0, elapsed_ns - dev_ns), dev_ns
-                        )
-                if aud_note:
-                    # audit plane: per-edge cardinality/selectivity counters
-                    aud.note_edge(node, inputs, out)
-                self._route(node, out)
-                any_work = True
+                elif step.node.has_pending():
+                    self._run_node(step.node, time, aud)
+                    any_work = True
         finally:
             self._heap = None
         return any_work
 
-    def _run_chain(self, chain, time: int, trace: bool, aud) -> bool:
+    def _run_chain(self, chain, time: int, aud) -> bool:
         """One fused-chain step: drain, hand off member to member, route the
-        tail. Span + host/device attribution is per CHAIN — the device wait
-        AND any inner traced-jit cold (compile) wall are subtracted from the
-        host share so compile seconds stay counted once (r10 discipline)."""
-        rp = self._rp
-        if trace or rp is not None:
-            w0 = _time.time_ns()
-            dev0 = _device_prof.thread_device_wait_ns() if trace else 0
-            cold0 = _device_prof.thread_cold_s() if trace else 0.0
+        tail. The span is per CHAIN."""
+        tr, rp = self._tr, self._rp
+        tok = (
+            _spans.step_begin(tr, rp, f"sweep/chain{{{chain.label}}}")
+            if tr is not None or rp is not None
+            else None
+        )
         t0 = _time.perf_counter_ns()
-        tok = _phases.start()
+        ptok = _phases.start()
         try:
             out, processed, rows_in, rows_out = chain.execute(time, None, aud)
         finally:
-            _phases.stop(tok, "fused")
+            _phases.stop(ptok, "fused")
         if not processed:
+            if tok is not None:
+                _spans.step_drop(tok)
             return False
-        elapsed_ns = _time.perf_counter_ns() - t0
-        chain.tail.stats_time_ns += elapsed_ns
-        if rp is not None:
-            rp.note_stage(
-                time, f"sweep/chain{{{chain.label}}}", w0, _time.time_ns(), rows_in
+        chain.tail.stats_time_ns += _time.perf_counter_ns() - t0
+        if tok is not None:
+            _spans.step_end(
+                tok, time, rows_in, rows_out,
+                {
+                    "pathway.operator.id": chain.operator_ids(),
+                    "pathway.chain.nodes": len(chain.members),
+                },
             )
-        if trace:
-            dev_ns = _device_prof.thread_device_wait_ns() - dev0
-            cold_ns = int((_device_prof.thread_cold_s() - cold0) * 1e9)
-            name = f"sweep/chain{{{chain.label}}}"
-            attrs = {
-                "pathway.operator.id": chain.operator_ids(),
-                "pathway.chain.nodes": len(chain.members),
-                "pathway.rows_in": rows_in,
-                "pathway.rows_out": rows_out,
-                "pathway.device_ms": round(dev_ns / 1e6, 3),
-            }
-            if cold_ns:
-                attrs["pathway.compile_ms"] = round(cold_ns / 1e6, 3)
-            self.tracer.span(name, w0, _time.time_ns(), attrs)
-            if dev_ns:
-                _device_prof.stats().note_span_split(
-                    name, max(0, elapsed_ns - dev_ns - cold_ns), dev_ns
-                )
         self._route(chain.tail, out)
         return True
 
@@ -444,12 +366,16 @@ class Scheduler:
         # device plane: steps an armed jax.profiler window, stamps the flight
         # recorder's tick ring (two global reads when profiling is off)
         _device_prof.tick_hook(time)
-        tracer = self.tracer
-        tick_token = tracer.begin_tick(time) if tracer is not None else None
-        self._trace_active = tick_token is not None
+        # span plane: one flag read brings a tracer up for the ticks of a
+        # profiler session (transient inner graphs — iterate bodies — keep
+        # their own tick numbering out of the ring)
+        tracer = None
+        if not self.transient:
+            tracer = self.tracer = _obs.tick_tracer(self.tracer)
+        tick_tok = tracer.begin_tick(time) if tracer is not None else None
+        tr = self._tr = tracer if tick_tok is not None else None
         # request plane: active for this tick only while a request is in
-        # flight (one global read + one flag read); transient inner graphs
-        # (iterate bodies) keep their own tick numbering out of the ring
+        # flight (one global read + one flag read)
         rp = None if self.transient else _requests.current()
         if rp is not None and (not rp.hot or time == END_OF_STREAM):
             rp = None
@@ -460,19 +386,24 @@ class Scheduler:
         if aud is not None:
             aud.begin_tick(time)
         plan = self.plan
+        worked = False
         pollers = self.graph.nodes if plan is None else plan.pollers
         for node in pollers:
+            tok = tr.begin(f"tick/poll/{node.name}") if tr is not None else None
             polled = _run_annotated(node, node.poll, time)
             if polled:
+                worked = True
                 # fault plan (flip_diff/drop_retract) corrupts BEFORE the
                 # audit monitors observe — the tripwire sees exactly what the
                 # engine will
                 polled = _faults.corrupt_polled(0, time, polled)
                 if aud is not None:
                     aud.observe_input(node, polled, time)
+            if tok is not None:
+                tr.end(tok, {"pathway.rows": sum(len(b) for b in polled)}, keep=bool(polled))
             self._route(node, polled)
         while self._sweep(time):
-            pass
+            worked = True
         # frontier phase: notify in topo order; emissions re-enter the same
         # tick (only nodes that override on_frontier are visited)
         frontier = self.graph.nodes if plan is None else plan.frontier_nodes
@@ -480,20 +411,32 @@ class Scheduler:
         while progressed:
             progressed = False
             for node in frontier:
+                tok = tr.begin(f"frontier/{node.name}") if tr is not None else None
                 out = _run_annotated(node, node.on_frontier, time)
+                if tok is not None:
+                    tr.end(tok, {"pathway.rows_out": sum(len(b) for b in out)}, keep=bool(out))
                 if self._route(node, out):
                     progressed = True
             if progressed:
+                worked = True
                 while self._sweep(time):
                     pass
+        if tr is not None and not worked:
+            tr = None  # an idle tick: its wait is its whole record
         complete = self.graph.nodes if plan is None else plan.tick_complete_nodes
+        tok = tr.begin("tick/complete") if tr is not None else None
         for node in complete:
             _run_annotated(node, node.on_tick_complete, time)
+        if tok is not None:
+            tr.end(tok)
+            tok = tr.begin("tick/done")
         for cb in self.on_tick_done:
             cb(time)
-        if tick_token is not None:
-            self._trace_active = False
-            tracer.end_tick(time, tick_token)
+        if tok is not None:
+            tr.end(tok)
+        if tick_tok is not None:
+            self._tr = None
+            tracer.end_tick(time, tick_tok, worked)
 
     def close(self) -> None:
         """Input exhausted: flush temporal buffers and fire end callbacks."""

@@ -1048,7 +1048,7 @@ class MicrobatchApplyNode(Node):
             )
         ]
 
-    def _flush(self, time, only_full: bool = False):
+    def _flush(self, time, only_full: bool = False, reason: str = "full"):
         n = len(self.waiting)
         max_batch = self._effective_max_batch()
         consume = (n // max_batch) * max_batch if only_full else n
@@ -1058,25 +1058,25 @@ class MicrobatchApplyNode(Node):
         entries = [self.waiting.pop(k) for k in keys]
         from pathway_tpu import observability as _obs
 
-        tracer = _obs.current()
-        if tracer is not None and tracer.tick_span_id is not None:
+        tok = _obs.begin("microbatch/launch")
+        if tok is not None:
             import time as _t
 
-            w0 = _t.time_ns()
-            udf_vals = self._launch([e[3] for e in entries])
-            tracer.span(
-                "microbatch/launch",
-                w0,
-                _t.time_ns(),
-                **{
+            # how long the oldest row flushed sat in the buffer (entries keep
+            # their enqueue time for the deadline; the first is the oldest)
+            waited_ns = int((_t.perf_counter() - entries[0][1]) * 1e9)
+        udf_vals = self._launch([e[3] for e in entries])
+        if tok is not None:
+            _obs.end(
+                tok,
+                {
                     "pathway.operator.id": self.node_index,
                     "pathway.rows": consume,
-                    "pathway.only_full": only_full,
+                    "pathway.reason": reason,
+                    "pathway.oldest_wait_ns": waited_ns,
                     "pathway.udfs": ",".join(s.name for s in self.udf_specs),
                 },
             )
-        else:
-            udf_vals = self._launch([e[3] for e in entries])
         out_keys: list[int] = []
         out_diffs: list[int] = []
         out_rows: list[tuple] = []
@@ -1109,30 +1109,35 @@ class MicrobatchApplyNode(Node):
             )
         ]
 
-    def _should_flush(self, time) -> bool:
+    def _should_flush(self, time) -> str | None:
+        """Why the buffer flushes at this frontier (``drain``, ``deadline``),
+        or None when it keeps accumulating."""
         if time == END_OF_STREAM:
-            return True
+            return "drain"
         rt = self.runtime
         if rt is None or not getattr(rt, "streaming", False):
             # static run: exactly one tick — flush at its frontier (emissions
             # re-enter the same logical time, matching the inline path)
-            return True
+            return "drain"
         conns = getattr(rt, "connectors", None)
         if conns and all(d.is_finished() for d in conns):
             # drain tick: sources exhausted, nothing more will accumulate
-            return True
+            return "drain"
         first = next(iter(self.waiting.values()))
         deadline = self.flush_ms
         if deadline is None:
             deadline = getattr(rt, "autocommit_duration_ms", 20) or 20
         import time as _t
 
-        return (_t.perf_counter() - first[1]) * 1000.0 >= deadline
+        if (_t.perf_counter() - first[1]) * 1000.0 >= deadline:
+            return "deadline"
+        return None
 
     def on_frontier(self, time):
-        if not self.waiting or not self._should_flush(time):
+        reason = self._should_flush(time) if self.waiting else None
+        if reason is None:
             return []
-        return self._flush(time)
+        return self._flush(time, reason=reason)
 
 
 # ---------------------------------------------------------------------------- groupby

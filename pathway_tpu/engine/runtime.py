@@ -55,11 +55,13 @@ class TickWakeup:
                 self._deadline = deadline
             self._cond.notify_all()
 
-    def wait(self, timeout: float) -> None:
+    def wait(self, timeout: float) -> str:
         """Sleep until ``timeout`` elapses, a pending coalesce deadline
-        passes, or an immediate tick is requested — whichever is first. Both
-        request states are consumed on return: the tick that follows this
-        wait drains every queue, satisfying all requests made before it."""
+        passes, or an immediate tick is requested — whichever is first, and
+        say which: ``"arrival"`` (a request woke the loop) or ``"period"``.
+        Both request states are consumed on return: the tick that follows
+        this wait drains every queue, satisfying all requests made before
+        it."""
         end = _time.perf_counter() + timeout
         with self._cond:
             while not self._immediate:
@@ -69,8 +71,11 @@ class TickWakeup:
                 if remaining <= 0:
                     break
                 self._cond.wait(remaining)
+            coalesced = self._deadline is not None and self._deadline <= end
+            woken = "arrival" if self._immediate or coalesced else "period"
             self._immediate = False
             self._deadline = None
+        return woken
 
 
 class ConnectorDriver(Protocol):
@@ -222,7 +227,13 @@ class Runtime:
                 if not all_virtual:
                     elapsed = _time.perf_counter() - t0
                     if elapsed < period:
-                        self.wakeup.wait(period - elapsed)
+                        # the sleep between ticks has a span of its own, so
+                        # "host asleep on its period" and "host busy" part
+                        tr = scheduler.tracer
+                        tok = tr.begin("tick/wait") if tr is not None else None
+                        woken = self.wakeup.wait(period - elapsed)
+                        if tok is not None:
+                            tr.end(tok, {"pathway.woken": woken})
         finally:
             # doors answer 503 + Retry-After from here on: drain before the
             # connector stop flushes pending request futures

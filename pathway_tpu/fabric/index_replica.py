@@ -503,15 +503,15 @@ def local_retrieve_response(
     if flt is None:
         pairs: list[tuple] = []  # malformed filter → the empty reply
     else:
-        e0 = _time.time_ns()
+        e0 = _time.monotonic_ns()
         item = iroute.embed_query(str(query))
-        e1 = _time.time_ns()
+        e1 = _time.monotonic_ns()
         if item is _UNEMBEDDABLE:
             return None
         spans.append(("replica/embed", e0, e1, None))
-        s0 = _time.time_ns()
+        s0 = _time.monotonic_ns()
         pairs = rep.search_one(item, k, flt)
-        spans.append(("replica/search", s0, _time.time_ns(), {"rows": len(pairs)}))
+        spans.append(("replica/search", s0, _time.monotonic_ns(), {"rows": len(pairs)}))
     # the owner's MergeIndexRepliesNode orders the merged union by
     # (score desc, tie-order asc) and cuts to k; the groupby sort and the
     # final dist sort are stable, so reproducing that order here reproduces

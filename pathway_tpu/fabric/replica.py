@@ -281,7 +281,7 @@ def serve_table(
         if gated is not None:
             status, body, hdrs = gated
             return web.json_response(body, status=status, headers=hdrs or None)
-        t0 = _time.time_ns()
+        t0 = _time.monotonic_ns()
         key = request.rel_url.query.get(key_column)
         from pathway_tpu import fabric as _fabric
 
@@ -304,7 +304,7 @@ def serve_table(
             }
         if status == 200:
             state.responses_total += 1
-            state.latency.observe((_time.time_ns() - t0) / 1e9)
+            state.latency.observe((_time.monotonic_ns() - t0) / 1e9)
         else:
             state.errors_total += 1
         return web.Response(

@@ -60,6 +60,7 @@ from pathway_tpu.fabric import index_replica as _ireplica
 from pathway_tpu.fabric import replica as _replica
 from pathway_tpu.fabric.transport import FabricNode, FabricUnavailable
 from pathway_tpu.internals.telemetry import record_event
+from pathway_tpu.observability import spans as _spans
 
 #: minimum seconds between owner frontier casts while tables are idle — the
 #: replica staleness clock must keep advancing without data
@@ -292,7 +293,7 @@ class FabricPlane:
                     rs.errors_total += 1
                     return web.json_response({"error": str(e)}, status=400)
             values = S.build_row_values(rs, payload)
-            arrival_ns = _time.time_ns()
+            arrival_ns = _time.monotonic_ns()
             return await self._forward_values(rs, values, arrival_ns)
 
         return handler
@@ -324,7 +325,7 @@ class FabricPlane:
         rp = _req_trace.current()
         request_id = rp.begin(key, rs.route, arrival_ns) if rp is not None else None
         rs.forwarded_out_total += 1
-        t0 = _time.time_ns()
+        t0 = _time.monotonic_ns()
         loop = asyncio.get_running_loop()
         try:
             status, body, hdrs = await loop.run_in_executor(
@@ -336,7 +337,9 @@ class FabricPlane:
                         "route": rs.route,
                         "key": key,
                         "values": values,
-                        "arrival_ns": arrival_ns,
+                        # Unix time on the wire: a monotonic stamp means
+                        # nothing in another process
+                        "arrival_ns": _spans.unix_ns(arrival_ns),
                     },
                     self.timeout,
                 ),
@@ -360,7 +363,7 @@ class FabricPlane:
         finally:
             with rs.lock:
                 rs.fwd_inflight -= 1
-        t1 = _time.time_ns()
+        t1 = _time.monotonic_ns()
         headers = dict(hdrs or {})
         if request_id is not None:
             headers["X-Pathway-Request-Id"] = request_id
@@ -378,7 +381,7 @@ class FabricPlane:
                 if status in (429, 503)
                 else "error"
             )
-            rp.complete(key, label, t1, _time.time_ns())
+            rp.complete(key, label, t1, _time.monotonic_ns())
         if status == 200:
             # the OWNER's resolution pass already counted this response
             # (responses_total is where-the-answer-was-computed, so the
@@ -449,7 +452,7 @@ class FabricPlane:
                     rs.errors_total += 1
                     return web.json_response({"error": str(e)}, status=400)
             values = S.build_row_values(rs, payload)
-            arrival_ns = _time.time_ns()
+            arrival_ns = _time.monotonic_ns()
             reason = self._replica_unready(ir)
             if reason is None:
                 vals = dict(zip(rs.schema_columns, values))
@@ -464,7 +467,7 @@ class FabricPlane:
                 )
                 if res is not None:
                     body, spans = res
-                    t1 = _time.time_ns()
+                    t1 = _time.monotonic_ns()
                     lag = ir.replica.remote_lag_s(self.n_proc) or 0.0
                     headers = {
                         "X-Pathway-Fabric": f"replica:p{self.pid}",
@@ -475,7 +478,7 @@ class FabricPlane:
                     if rp is not None:
                         for name, s0, s1, attrs in spans:
                             rp.note_boundary(key, name, s0, s1, attrs)
-                        rp.complete(key, "ok", t1, _time.time_ns())
+                        rp.complete(key, "ok", t1, _time.monotonic_ns())
                     ir.local_answers += 1
                     rs.responses_total += 1
                     rs.latency.observe((t1 - arrival_ns) / 1e9)
@@ -608,13 +611,13 @@ class FabricPlane:
             if gated is not None:
                 status, body, hdrs = gated
                 return web.json_response(body, status=status, headers=hdrs or None)
-            t0 = _time.time_ns()
+            t0 = _time.monotonic_ns()
             key = request.rel_url.query.get(troute.key_column)
             if self.shardmap is not None:
                 status, body, headers = await self.serve_table_lookup(troute, key)
                 if status == 200:
                     rs.responses_total += 1
-                    rs.latency.observe((_time.time_ns() - t0) / 1e9)
+                    rs.latency.observe((_time.monotonic_ns() - t0) / 1e9)
                 else:
                     rs.errors_total += 1
                 return web.Response(
@@ -656,7 +659,7 @@ class FabricPlane:
                 self._resync(troute, wait=False)
             if status == 200:
                 rs.responses_total += 1
-                rs.latency.observe((_time.time_ns() - t0) / 1e9)
+                rs.latency.observe((_time.monotonic_ns() - t0) / 1e9)
             else:
                 rs.errors_total += 1
             return web.Response(
@@ -700,7 +703,7 @@ class FabricPlane:
 
         key = int(payload["key"])
         values = tuple(payload["values"])
-        arrival_ns = int(payload["arrival_ns"])
+        arrival_ns = _spans.mono_ns(int(payload["arrival_ns"]))
 
         def shed(reason: str):
             rs.shed_total += 1

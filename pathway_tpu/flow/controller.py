@@ -179,11 +179,8 @@ class AimdController:
         }
         self.decisions.append(decision)
         if tracer is not None and tracer.tick_span_id is not None:
-            now = _time.time_ns()
-            tracer.span(
+            tracer.event(
                 "flow/controller",
-                now,
-                now,
                 {
                     "pathway.flow.action": action,
                     "pathway.flow.target": self.target,

@@ -645,7 +645,11 @@ class PathwayConfig:
 
     @property
     def trace_buffer_spans(self) -> int:
-        return max(64, _env_int("PATHWAY_TRACE_BUFFER", 8192))
+        """Span ring size. The default holds a whole traced 50 s benchmark
+        window of either cell twice over (31,583 records in the retrieve
+        cell, 2,716 in ingest: PERF.md §6, PR 25) — a reader that finds
+        ``dropped`` > 0 returns nothing."""
+        return max(64, _env_int("PATHWAY_TRACE_BUFFER", 65536))
 
     @property
     def trace_rotate_mb(self) -> int:
@@ -741,13 +745,6 @@ class PathwayConfig:
         recompile-storm detector flags the callable on ``/status`` — a
         healthy bucketed pipeline keeps a small closed shape set."""
         return max(2, _env_int("PATHWAY_PROFILE_SHAPE_WARN", 12))
-
-    @property
-    def profile_peak_tflops(self) -> float:
-        """Per-chip peak TFLOP/s used to turn the rough per-launch FLOP
-        estimates into a live MFU gauge (e.g. 197 for v5e bf16). 0 (default)
-        reports achieved FLOP/s without an MFU ratio."""
-        return max(0.0, _env_float("PATHWAY_PROFILE_PEAK_TFLOPS", 0.0))
 
     # ---- index plane (serving-scale KNN) ------------------------------------
     @property

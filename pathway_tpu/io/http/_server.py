@@ -977,7 +977,7 @@ def rest_connector(
                 state.errors_total += 1
                 return web.json_response({"error": str(e)}, status=400)
         values = build_row_values(state, payload)
-        arrival_ns = _time_mod.time_ns()
+        arrival_ns = _time_mod.monotonic_ns()
         fut: asyncio.Future = asyncio.get_running_loop().create_future()
         loop = fut.get_loop()
         with state.lock:
@@ -1098,7 +1098,7 @@ def rest_connector(
                 return
             batch = collected[:]
             collected.clear()
-            now_ns = _time_mod.time_ns()
+            now_ns = _time_mod.monotonic_ns()
             resolved: list[tuple[tuple, int, dict]] = []
             with state.lock:
                 for key, row in batch:
@@ -1111,7 +1111,6 @@ def rest_connector(
             # one vectorized resolution pass per event loop, not a
             # call_soon_threadsafe per row
             by_loop: dict[Any, list] = {}
-            oldest_ns = now_ns
             retracts: list[tuple[int, tuple, int]] = []
             for (fut, loop, arrival_ns, values), key, row in resolved:
                 value = (
@@ -1119,7 +1118,6 @@ def rest_connector(
                 )
                 by_loop.setdefault(loop, []).append((fut, value))
                 state.latency.observe((now_ns - arrival_ns) / 1e9)
-                oldest_ns = min(oldest_ns, arrival_ns)
                 if state.delete_completed:
                     retracts.append((key, values, -1))
             for loop, items in by_loop.items():
@@ -1130,28 +1128,15 @@ def rest_connector(
             state.responses_total += len(resolved)
             state.batches_total += 1
             state.batched_rows_total += len(resolved)
-            from pathway_tpu import observability as _obs
             from pathway_tpu.observability import requests as _req_trace
 
             rp = _req_trace.current()
             if rp is not None:
                 # completion runs the tail-based keep decision per request;
                 # the respond span covers this resolution pass
-                done_ns = _time_mod.time_ns()
+                done_ns = _time_mod.monotonic_ns()
                 for _ent, key, _row in resolved:
                     rp.complete(key, "ok", now_ns, done_ns)
-            tracer = _obs.current()
-            if tracer is not None:
-                tracer.span(
-                    "serve/respond",
-                    oldest_ns,
-                    now_ns,
-                    {
-                        "pathway.route": route,
-                        "pathway.responses": len(resolved),
-                        "pathway.tick": time,
-                    },
-                )
             if retracts and state.node is not None:
                 # retract served query rows (delete_completed_queries): this
                 # is the server's own bookkeeping, bounded by the in-flight

@@ -8,7 +8,8 @@ the observability the ROADMAP's live-RAG serving target actually needs:
 - ``spans``    — Dapper-style head-sampled tick/operator/device/cluster spans,
   ring-buffered for ``/trace?since=`` and appended to a rotating OTLP-JSON
   file (``PATHWAY_TRACE=on``, ``PATHWAY_TRACE_SAMPLE``,
-  ``PATHWAY_TRACE_LIVE_FILE``);
+  ``PATHWAY_TRACE_LIVE_FILE``); also on, ring only, for exactly the ticks of
+  a JAX profiler session (:func:`tick_tracer`);
 - ``metrics``  — per-input watermarks, per-sink end-to-end latency histograms
   (log-2 buckets → Prometheus histograms on ``/metrics``), backlog gauges;
 - ``aggregate``— peers ship summaries to the coordinator on the heartbeat
@@ -17,7 +18,8 @@ the observability the ROADMAP's live-RAG serving target actually needs:
 Lifecycle: each runtime ``run()`` calls :func:`install_from_env` (next to the
 fault-plan install) and :func:`shutdown` in its run wrapper; ``current()`` is
 the hot-path accessor — **None when tracing is off**, so engine loops pay one
-``is None`` test.
+``is None`` test. The last recording's ring stays readable after the run
+(:func:`last_recording`).
 """
 
 from __future__ import annotations
@@ -53,11 +55,71 @@ from pathway_tpu.observability.spans import (
 )
 
 _tracer: Tracer | None = None
+#: the ring of the last tracer retired, readable until the next one retires
+_last_buffer: SpanBuffer | None = None
 
 
 def current() -> Tracer | None:
     """The installed live tracer, or None when tracing is off."""
     return _tracer
+
+
+def last_recording() -> SpanBuffer | None:
+    """The ring of the live tracer, else of the last one retired (the
+    readers of a benchmark or a test run after ``pw.run()`` returns)."""
+    return _tracer.buffer if _tracer is not None else _last_buffer
+
+
+def begin(name: str) -> tuple | None:
+    """Open a span on the live tracer (None when off or the tick is
+    unsampled): the one-line guard of the sites outside the engine loop."""
+    t = _tracer
+    return t.begin(name) if t is not None else None
+
+
+def end(tok: tuple, attrs: dict | None = None) -> None:
+    tok[0].end(tok, attrs)
+
+
+def _retire(emit_root: bool) -> None:
+    global _tracer, _last_buffer
+    tracer, _tracer = _tracer, None
+    if tracer is None:
+        return
+    _last_buffer = tracer.buffer
+    try:
+        tracer.close(emit_root=emit_root)
+    except Exception:
+        pass
+
+
+def tick_tracer(tracer: Tracer | None) -> Tracer | None:
+    """Once per tick, from every runtime's ``run_tick``: the tracer this tick
+    records on. With ``PATHWAY_TRACE`` off this is the ONE place that brings
+    a tracer up (ring only, no sink, every tick sampled) — at the first tick
+    that finds a JAX profiler session — and retires it at the first tick that
+    finds the session gone; without a session it costs one flag read."""
+    global _tracer
+    on = spans.profiler_session_active()
+    if tracer is None:
+        if not on:
+            return None
+        if _tracer is None:
+            from pathway_tpu.internals.config import get_pathway_config
+
+            cfg = get_pathway_config()
+            _tracer = Tracer(
+                trace_id=run_trace_id(),
+                process_id=cfg.process_id,
+                buffer=SpanBuffer(max_spans=cfg.trace_buffer_spans),
+                session=True,
+            )
+        tracer = _tracer
+    elif tracer.session and not on:
+        _retire(emit_root=False)
+        return None
+    tracer.annotate = on
+    return tracer
 
 
 def run_trace_id() -> str:
@@ -81,6 +143,7 @@ def install_from_env(runtime=None) -> Tracer | None:
     from pathway_tpu.internals.config import get_pathway_config
 
     metrics.reset()
+    spans.reanchor()
     # device profiling plane (compile/pad/memory accounting, flight recorder,
     # profiler windows) — on by default, independent of PATHWAY_TRACE
     device.install_from_env(runtime)
@@ -102,12 +165,7 @@ def install_from_env(runtime=None) -> Tracer | None:
     # bottleneck attribution) — on by default; off constructs no plane. After
     # health so the recorder can sample canary/alert state from step one.
     timeline.install_from_env(runtime)
-    if _tracer is not None:
-        try:
-            _tracer.close(emit_root=False)
-        except Exception:
-            pass
-        _tracer = None
+    _retire(emit_root=False)
     cfg = get_pathway_config()
     if cfg.trace_mode == "off":
         return None
@@ -127,21 +185,15 @@ def install_from_env(runtime=None) -> Tracer | None:
 
 
 def shutdown() -> None:
-    """Close the live tracer (flush + root span + file sink). Never raises —
-    runs in ``finally`` blocks next to connector/server teardown."""
-    global _tracer
+    """Close the live tracer (flush + root span + file sink; its ring stays
+    readable). Never raises — runs in ``finally`` blocks next to
+    connector/server teardown."""
     timeline.shutdown()
     health.shutdown()
     device.shutdown()
     audit.shutdown()
     requests.shutdown()
-    if _tracer is None:
-        return
-    try:
-        _tracer.close()
-    except Exception:
-        pass
-    _tracer = None
+    _retire(emit_root=True)
 
 
 __all__ = [
@@ -154,20 +206,24 @@ __all__ = [
     "alerts",
     "audit",
     "backlog_gauges",
+    "begin",
     "bottleneck",
     "current",
     "derive_trace_id",
     "device",
+    "end",
     "engine_phases",
     "health",
     "lineage",
     "input_watermarks",
     "install_from_env",
+    "last_recording",
     "metrics",
     "requests",
     "run_metrics",
     "run_trace_id",
     "shutdown",
     "spans",
+    "tick_tracer",
     "timeline",
 ]

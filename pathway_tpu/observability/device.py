@@ -18,18 +18,17 @@ negligible cost:
   (``PATHWAY_PROFILE_SHAPE_WARN``) on ``/status``.
 - **padding & waste accounting** — the microbatch dispatcher and the
   encoder/reranker length-bucketing report real vs padded rows and tokens per
-  UDF (``pathway_pad_rows_total{kind=real|pad}``, waste-ratio gauges) plus a
-  rough per-launch FLOP estimate (2 · params · tokens for transformer
-  forwards, 2 · capacity · dim per KNN probe) feeding live FLOP/s and — when
-  ``PATHWAY_PROFILE_PEAK_TFLOPS`` is set — MFU gauges.
+  UDF (``pathway_pad_rows_total{kind=real|pad}``, waste-ratio gauges). FLOPs
+  and peaks are the benchmark's to count (``chipbench/flops.py``).
 - **memory + time attribution** — components (KNN index shards, encoder /
   reranker params, microbatch buffers) register weakly and are summed into
   ``pathway_device_bytes{component=...}``; ``jax`` backend memory stats ride
   along when the platform exposes them (TPU/GPU — CPU returns none and the
-  gauge degrades gracefully). On trace-sampled ticks (or always under
-  ``PATHWAY_PROFILE=full``) traced dispatches measure dispatch-vs-
-  ``block_until_ready`` time, giving each sweep-node span a host/device
-  split.
+  gauge degrades gracefully). Under ``PATHWAY_PROFILE=full`` — and only
+  then: tracing adds no device sync — traced dispatches measure dispatch-vs-
+  ``block_until_ready`` time. :func:`put` and :func:`fetch` are the host↔device
+  crossings of the kernels' call sites, each a ``device/put`` / ``device/fetch``
+  span carrying its bytes while a tracer records.
 - **flight recorder** — bounded rings of recent ticks and device events
   (compiles, storms, launches, faults), dumped as a post-mortem JSON to
   ``PATHWAY_FLIGHT_DIR`` on ``terminate_on_error`` aborts,
@@ -54,6 +53,8 @@ import weakref
 from collections import deque
 from typing import Any, Callable
 
+import numpy as np
+
 from pathway_tpu.internals.config import get_pathway_config
 
 __all__ = [
@@ -61,7 +62,9 @@ __all__ = [
     "flight_dump",
     "flight_note",
     "install_from_env",
+    "fetch",
     "on_run_error",
+    "put",
     "register_memory",
     "request_profile",
     "stats",
@@ -95,13 +98,6 @@ def pop_label() -> None:
 def current_label() -> str | None:
     stack = getattr(_tls, "labels", None)
     return stack[-1] if stack else None
-
-
-def thread_device_wait_ns() -> int:
-    """This thread's cumulative traced device-wait — sweep spans diff THIS
-    (not the process-global counter) so concurrent worker threads cannot
-    attribute each other's dispatches to their own spans."""
-    return getattr(_tls, "dev_wait_ns", 0)
 
 
 def thread_cold_s() -> float:
@@ -177,7 +173,7 @@ class DeviceStats:
     """Per-process device profiling state.
 
     Compile/shape tracking is process-cumulative (the XLA compile cache it
-    mirrors is, too); pad/FLOP/time-split accounting resets per run via
+    mirrors is, too); pad/time-split accounting resets per run via
     :meth:`reset_run` so ``/metrics`` describes the current run.
     """
 
@@ -186,7 +182,6 @@ class DeviceStats:
         self.mode = "on"
         self.enabled = True
         self.shape_warn = 12
-        self.peak_tflops = 0.0
         # label -> [compiles, compile_seconds] from the jax.monitoring
         # listener (process-cumulative; falls back to cold-call counts when
         # the listener never fired)
@@ -208,16 +203,13 @@ class DeviceStats:
             self.mode = "on"
         self.enabled = self.mode != "off"
         self.shape_warn = cfg.profile_shape_warn
-        self.peak_tflops = cfg.profile_peak_tflops
 
     def reset_run(self) -> None:
         with self.lock:
-            self.started_ns = _time.time_ns()
             # label -> [real_rows, pad_rows, real_tokens, pad_tokens]
             self.pad: dict[str, list] = {}
             # name -> [host_ns, device_ns, samples]
             self.split: dict[str, list] = {}
-            self.flops: dict[str, float] = {}
             self.device_wait_ns = 0
 
     # -- compile / shape telemetry -------------------------------------------
@@ -249,7 +241,7 @@ class DeviceStats:
             flight_note("recompile_storm", callable=label, shapes=self.shape_warn)
             _storm_alert(label, self.shape_warn)
 
-    # -- padding / flops ------------------------------------------------------
+    # -- padding --------------------------------------------------------------
     def note_pad_rows(self, label: str, real: int, pad: int) -> None:
         with self.lock:
             ent = self.pad.setdefault(label, [0, 0, 0, 0])
@@ -262,39 +254,21 @@ class DeviceStats:
             ent[2] += real
             ent[3] += pad
 
-    def note_flops(self, label: str, flops: float) -> None:
-        with self.lock:
-            self.flops[label] = self.flops.get(label, 0.0) + float(flops)
-
     # -- host/device time split ----------------------------------------------
     def want_split(self) -> bool:
-        """Measure the dispatch-vs-device split on this call? ``full`` mode
-        always; ``on`` mode only inside a trace-sampled tick (the spans that
-        will carry the attribution exist exactly then)."""
-        if self.mode == "full":
-            return True
-        tracer = _current_tracer()
-        return tracer is not None and tracer.tick_span_id is not None
+        """Measure the dispatch-vs-device split on this call? Only in
+        ``full`` mode: it blocks after the launch, and a traced run must
+        overlap host and device exactly where an untraced one does."""
+        return self.mode == "full"
 
     def note_split(self, name: str, host_ns: int, device_ns: int) -> None:
-        """Per-dispatch split (traced_jit): also advances the global and the
-        per-thread device-wait counters (sweep spans diff the per-thread one)."""
+        """Per-dispatch split (traced_jit, ``full`` mode)."""
         with self.lock:
             ent = self.split.setdefault(name, [0, 0, 0])
             ent[0] += host_ns
             ent[1] += device_ns
             ent[2] += 1
             self.device_wait_ns += device_ns
-        _tls.dev_wait_ns = getattr(_tls, "dev_wait_ns", 0) + device_ns
-
-    def note_span_split(self, name: str, host_ns: int, device_ns: int) -> None:
-        """Per-sweep-span aggregation: the device part was already counted in
-        ``device_wait_ns`` by the dispatches inside the span."""
-        with self.lock:
-            ent = self.split.setdefault(name, [0, 0, 0])
-            ent[0] += host_ns
-            ent[1] += device_ns
-            ent[2] += 1
 
 
 _stats = DeviceStats()
@@ -304,10 +278,29 @@ def stats() -> DeviceStats:
     return _stats
 
 
-def _current_tracer():
+def put(x: Any, label: str, dtype: Any = None):
+    """``jnp.asarray(x, dtype)``: a host array crossing to the device — a
+    ``device/put`` span with its bytes while a tracer records."""
     from pathway_tpu import observability as _obs
 
-    return _obs.current()
+    tok = _obs.begin("device/put")
+    out = _jax_mod().numpy.asarray(x, dtype)
+    if tok is not None:
+        _obs.end(tok, {"pathway.label": label, "pathway.bytes": int(out.nbytes)})
+    return out
+
+
+def fetch(x: Any, label: str):
+    """``np.asarray(x)``: the host blocked until the device has the value,
+    then the copy down — a ``device/fetch`` span with its bytes while a tracer
+    records. Its duration is the sync; counting these spans counts syncs."""
+    from pathway_tpu import observability as _obs
+
+    tok = _obs.begin("device/fetch")
+    out = np.asarray(x)
+    if tok is not None:
+        _obs.end(tok, {"pathway.label": label, "pathway.bytes": int(out.nbytes)})
+    return out
 
 
 # ------------------------------------------------------------------ traced_jit
@@ -874,16 +867,12 @@ def status_summary(runtime: Any = None) -> dict[str, Any]:
         return {"enabled": False, "mode": "off"}
     callables = _callables_view()
     with st.lock:
-        flops = dict(st.flops)
         split = {k: list(v) for k, v in st.split.items()}
-        started_ns = st.started_ns
         compile_s = st.process_compile_s
-    elapsed_s = max(1e-9, (_time.time_ns() - started_ns) / 1e9)
     mem = memory_components()
     mb = _microbatch_buffer_bytes(runtime)
     if mb:
         mem["microbatch_buffers"] = mem.get("microbatch_buffers", 0) + mb
-    flops_total = sum(flops.values())
     out: dict[str, Any] = {
         "enabled": True,
         "mode": st.mode,
@@ -899,21 +888,12 @@ def status_summary(runtime: Any = None) -> dict[str, Any]:
             }
             for name, (h, d, n) in sorted(split.items())
         },
-        "flops": {
-            "by_label": {k: round(v, 1) for k, v in sorted(flops.items())},
-            "total": round(flops_total, 1),
-            "per_s": round(flops_total / elapsed_s, 1),
-        },
         "profiler": _profile_state(),
         "flight": {
             "events": len(_recorder.events),
             "dir": get_pathway_config().flight_dir,
         },
     }
-    if st.peak_tflops > 0:
-        out["flops"]["mfu"] = round(
-            flops_total / elapsed_s / (st.peak_tflops * 1e12), 6
-        )
     storms = [label for label, c in callables.items() if c["storm"]]
     if storms:
         out["warnings"] = [
@@ -1050,25 +1030,6 @@ def prometheus_lines(runtime: Any = None) -> list[str]:
         lines.append(
             f'pathway_device_bytes{{component="{esc(component)}"}} {n}'
         )
-    with st.lock:
-        flops_total = sum(st.flops.values())
-        started_ns = st.started_ns
-    if flops_total:
-        elapsed_s = max(1e-9, (_time.time_ns() - started_ns) / 1e9)
-        lines.append("# HELP pathway_device_flops_total Estimated device FLOPs launched this run")
-        lines.append("# TYPE pathway_device_flops_total counter")
-        lines.append(f"pathway_device_flops_total {round(flops_total, 1)}")
-        lines.append("# HELP pathway_device_flops_per_s Estimated achieved device FLOP/s this run")
-        lines.append("# TYPE pathway_device_flops_per_s gauge")
-        lines.append(
-            f"pathway_device_flops_per_s {round(flops_total / elapsed_s, 1)}"
-        )
-        if st.peak_tflops > 0:
-            lines.append("# HELP pathway_mfu Model FLOPs utilization vs PATHWAY_PROFILE_PEAK_TFLOPS")
-            lines.append("# TYPE pathway_mfu gauge")
-            lines.append(
-                f"pathway_mfu {round(flops_total / elapsed_s / (st.peak_tflops * 1e12), 6)}"
-            )
     # ---- tiered-index plane (hot HBM shard over host IVF cold tier) ---------
     # hot/cold device bytes already ride pathway_device_bytes via the
     # knn_hot/knn_cold memory components; these add serving-quality gauges

@@ -23,9 +23,15 @@ the Dapper-style request plane the serving tier needs:
   small deterministic always-keep hash slice
   (``PATHWAY_REQUEST_TRACE_KEEP``). Kept traces materialize as OTLP spans
   under a per-request trace id (derived from the request id, so every
-  process would derive the same), flushed to the r8 span buffer/file sink
-  when ``PATHWAY_TRACE`` is on, and queryable via the monitoring server's
-  ``/request?id=`` endpoint and the ``pathway_tpu trace <request_id>`` CLI.
+  process would derive the same), queryable via the monitoring server's
+  ``/request?id=`` endpoint and the ``pathway_tpu trace <request_id>`` CLI;
+- while a live tracer records (``PATHWAY_TRACE`` on, or a profiler session),
+  EVERY completed request lands in the r8 span buffer/file sink as a
+  ``serve/request`` span with its boundary children (``serve/admission``,
+  ``serve/coalesce``, ``serve/respond``); a kept one adds its engine stages.
+
+Stamps are ``time.monotonic_ns()`` (the span plane's one clock); Unix times
+appear only where OTLP is materialized.
 
 Overhead discipline: ``PATHWAY_REQUEST_TRACE=off`` installs **no plane at
 all** — every call site guards on a single ``is None`` test and zero rings
@@ -41,6 +47,8 @@ import threading
 import time as _time
 from collections import OrderedDict
 from typing import Any
+
+from pathway_tpu.observability.spans import unix_ns
 
 #: per-request bounded boundary-event list (admission/coalesce/respond plus
 #: shed/timeout markers) — requests cannot grow unbounded state
@@ -99,7 +107,7 @@ class _Req:
         self.request_id = request_id
         self.route = route
         self.arrival_ns = arrival_ns
-        self.push_ns = _time.time_ns()
+        self.push_ns = _time.monotonic_ns()
         self.first_tick: int | None = None
         self.first_tick_ns: int | None = None
         #: boundary events: (stage, t0_ns, t1_ns, attrs | None)
@@ -206,9 +214,9 @@ class RequestTracePlane:
     # ------------------------------------------------------------ engine side
     def note_tick(self, tick: int) -> None:
         """Engine tick start (called behind the ``hot`` check, engine thread
-        only): stamps the tick-start wall clock and resolves which tick first
-        drained each just-pushed request (its coalesce boundary)."""
-        now = _time.time_ns()
+        only): stamps the tick start and resolves which tick first drained
+        each just-pushed request (its coalesce boundary)."""
+        now = _time.monotonic_ns()
         self._cur_tick = tick
         if self.live:
             with self._lock:
@@ -333,7 +341,7 @@ class RequestTracePlane:
                 self.hot = self._sticky_hot
         if rec is None:
             return None
-        now = resolve_t1_ns if resolve_t1_ns is not None else _time.time_ns()
+        now = resolve_t1_ns if resolve_t1_ns is not None else _time.monotonic_ns()
         if resolve_t0_ns is not None and len(rec.events) < _REQ_EVENTS_MAX:
             rec.events.append(("serve/respond", resolve_t0_ns, now, None))
         duration_ms = (now - rec.arrival_ns) / 1e6
@@ -366,15 +374,32 @@ class RequestTracePlane:
             slow.append(exemplar)
             slow.sort(key=lambda e: -e["duration_ms"])
             del slow[_SLOWEST_MAX:]
+        from pathway_tpu import observability as _obs
+
+        tracer = _obs.current()
+        if tracer is not None and not (keep or tracer.active):
+            tracer = None  # head sampling: the tick that answered is unsampled
+        if not keep and tracer is None:
+            return None
+        spans = self._request_spans(rec, status, now, engine_events if keep else ())
+        if tracer is not None:
+            # next to the tick spans, under the per-request trace id, so a
+            # collector tailing the live file (or a reader of the ring) finds
+            # where every request waited
+            tid = derive_request_trace_id(rec.request_id)
+            for name, sid, parent, t0, t1, attrs in spans:
+                tracer.buffer.append(
+                    ("serve/request" if parent is None else name, sid, parent,
+                     t0, t1, attrs, tid, None)
+                )
         if not keep:
             return None
-        doc = self._materialize(rec, status, duration_ms, decomp, engine_events, now)
+        doc = self._materialize(rec, status, duration_ms, decomp, spans)
         with self._lock:
             self.kept[rec.request_id] = doc
             while len(self.kept) > self.kept_cap:
                 self.kept.popitem(last=False)
             self.kept_total += 1
-        self._flush_otlp(doc)
         return doc
 
     def _decompose(self, rec: _Req, now_ns: int) -> tuple[dict[str, float], list]:
@@ -417,16 +442,11 @@ class RequestTracePlane:
                 engine_events.append((tick, ev))
         return decomp, engine_events
 
-    def _materialize(
-        self,
-        rec: _Req,
-        status: str,
-        duration_ms: float,
-        decomp: dict[str, float],
-        engine_events: list,
-        now_ns: int,
-    ) -> dict:
-        trace_id = derive_request_trace_id(rec.request_id)
+    def _request_spans(
+        self, rec: _Req, status: str, now_ns: int, engine_events: Any
+    ) -> list[tuple]:
+        """``(name, span id, parent id, t0, t1, attrs)`` of one request: the
+        root, its boundary events, the coalesce wait, the engine stages."""
         root_id = _span_id(rec.request_id, 0)
         spans: list[tuple] = [
             (
@@ -471,12 +491,23 @@ class RequestTracePlane:
                 a.update({f"pathway.{k}": v for k, v in attrs.items()})
             spans.append((stage, _span_id(rec.request_id, i), root_id, t0, t1, a))
             i += 1
+        return spans
+
+    def _materialize(
+        self,
+        rec: _Req,
+        status: str,
+        duration_ms: float,
+        decomp: dict[str, float],
+        spans: list[tuple],
+    ) -> dict:
+        trace_id = derive_request_trace_id(rec.request_id)
         return {
             "request_id": rec.request_id,
             "trace_id": trace_id,
             "route": rec.route,
             "status": status,
-            "arrival_unix_ns": rec.arrival_ns,
+            "arrival_unix_ns": unix_ns(rec.arrival_ns),
             "duration_ms": round(duration_ms, 3),
             "first_tick": rec.first_tick,
             "decomposition_ms": {k: round(v, 3) for k, v in decomp.items()},
@@ -487,38 +518,22 @@ class RequestTracePlane:
                     **({"parentSpanId": pid_} if pid_ is not None else {}),
                     "name": name,
                     "kind": 1,
-                    "startTimeUnixNano": str(t0),
-                    "endTimeUnixNano": str(t1),
+                    "startTimeUnixNano": str(unix_ns(t0)),
+                    "endTimeUnixNano": str(unix_ns(t1)),
                     "attributes": [
                         _box_attr(k, v) for k, v in (attrs or {}).items()
                     ],
                 }
                 for name, sid, pid_, t0, t1, attrs in spans
             ],
-            "_records": spans,
         }
-
-    def _flush_otlp(self, doc: dict) -> None:
-        """Append the kept trace's spans to the r8 span buffer (ring +
-        rotating OTLP-JSON file sink) under the per-request trace id, so a
-        collector tailing the live file sees request traces stitched next to
-        the head-sampled tick spans."""
-        from pathway_tpu import observability as _obs
-
-        tracer = _obs.current()
-        if tracer is None:
-            return
-        tid = doc["trace_id"]
-        for name, sid, parent, t0, t1, attrs in doc["_records"]:
-            tracer.buffer.append((name, sid, parent, t0, t1, attrs, tid))
 
     # ---------------------------------------------------------------- reading
     def get_trace(self, request_id: str) -> dict:
         with self._lock:
             doc = self.kept.get(request_id)
             if doc is not None:
-                out = {k: v for k, v in doc.items() if k != "_records"}
-                return {"ok": True, "kept": True, **out}
+                return {"ok": True, "kept": True, **doc}
             rec = None
             for r in self.live.values():
                 if r.request_id == request_id:
@@ -531,7 +546,7 @@ class RequestTracePlane:
                 "in_flight": True,
                 "request_id": request_id,
                 "route": rec.route,
-                "elapsed_ms": round((_time.time_ns() - rec.arrival_ns) / 1e6, 3),
+                "elapsed_ms": round((_time.monotonic_ns() - rec.arrival_ns) / 1e6, 3),
                 "stage": self._stage_reached(rec),
             }
         with self._lock:
@@ -558,7 +573,7 @@ class RequestTracePlane:
         """The in-flight request table for flight-recorder dumps: which user
         queries were mid-flight (and how far they got) when the process
         died."""
-        now = _time.time_ns()
+        now = _time.monotonic_ns()
         with self._lock:
             recs = list(self.live.values())
             remote = dict(self.remote_live)

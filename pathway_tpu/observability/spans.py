@@ -14,10 +14,19 @@ OTLP-JSON file sink — one ``ExportTraceServiceRequest`` JSON document per
 line, the OTel collector file-exporter convention, loadable in Perfetto or
 otel-desktop-viewer.
 
+One clock: every site stamps ``time.monotonic_ns()`` — the clock a JAX
+profiler trace is mapped onto with one marker — and :func:`unix_ns` converts
+to Unix nanoseconds where OTLP is materialized. A span's parent is the span
+open around it on the same thread (the tick where none is); spans that were
+really open on a thread (``begin``/``end``) carry its id, so self time is a
+span's duration minus what its children cover.
+
 Overhead discipline (SnailTrail's "observe without perturbing"):
 
 - ``PATHWAY_TRACE=off`` (default) installs **no tracer at all** — hot loops
-  guard on a single ``is None`` check;
+  guard on a single ``is None`` check — until a JAX profiler session starts:
+  ``observability.tick_tracer`` then brings one up (ring only, every tick
+  sampled) for exactly the session's ticks;
 - head sampling (``PATHWAY_TRACE_SAMPLE``) decides per TICK with a
   deterministic hash of the tick number, so every process of a cluster
   samples the SAME ticks and their spans stitch under one trace id;
@@ -46,6 +55,26 @@ _MASK = (1 << 64) - 1
 #: background file-sink writer wake period, seconds — the live file trails
 #: the engine by at most this much
 _SINK_FLUSH_S = 0.25
+
+#: Unix-minus-monotonic nanoseconds: the ONE (time_ns, monotonic_ns) pair,
+#: retaken at every ``observability.install_from_env``
+_unix_offset_ns = _time.time_ns() - _time.monotonic_ns()
+
+
+def reanchor() -> None:
+    global _unix_offset_ns
+    _unix_offset_ns = _time.time_ns() - _time.monotonic_ns()
+
+
+def unix_ns(mono_ns: int) -> int:
+    """A ``monotonic_ns`` stamp as Unix nanoseconds (OTLP read side only)."""
+    return mono_ns + _unix_offset_ns
+
+
+def mono_ns(unix: int) -> int:
+    """Inverse of :func:`unix_ns`: a stamp another process shipped as Unix
+    nanoseconds, on this process's monotonic clock."""
+    return unix - _unix_offset_ns
 
 
 def _attr(key: str, value: Any) -> dict:
@@ -165,7 +194,8 @@ class SpanBuffer:
     tracer; identity by default) converts a ``(seq, record)`` pair to its
     OTLP span dict on the READ side — ``since()`` and the file sink's
     background writer thread, which drains new records every
-    ``_SINK_FLUSH_S`` seconds."""
+    ``_SINK_FLUSH_S`` seconds. ``dropped`` counts the records the ring
+    overwrote: a reader that needs a whole window checks it is 0."""
 
     def __init__(self, max_spans: int = 8192, sink: RotatingTraceSink | None = None):
         self.max_spans = max_spans
@@ -174,6 +204,7 @@ class SpanBuffer:
         # set by the owning tracer: (seq, record) batch -> one OTLP/JSON line;
         # None falls back to materialize + sink.write
         self.serialize_batch = None
+        self.dropped = 0
         self._lock = threading.Lock()
         self._ring: deque[tuple[int, Any]] = deque(maxlen=max_spans)
         self._seq = 0
@@ -189,9 +220,18 @@ class SpanBuffer:
     def append(self, record: Any) -> None:
         with self._lock:
             self._seq += 1
+            if len(self._ring) == self.max_spans:
+                self.dropped += 1
             self._ring.append((self._seq, record))
             if self.sink is not None:
                 self._pending_sink.append((self._seq, record))
+
+    def records(self) -> list:
+        """The ring's raw records, oldest first (monotonic stamps, parent
+        ids, threads — what a reader in this process computes self times and
+        gap covers from; stays readable after the tracer is retired)."""
+        with self._lock:
+            return [r for _q, r in self._ring]
 
     def since(self, seq: int, limit: int = 4096) -> tuple[list[dict], int]:
         """Spans recorded after cursor ``seq`` (oldest first) + the new
@@ -234,14 +274,18 @@ class SpanBuffer:
 
 
 class Tracer:
-    """Per-run live tracer. Installed only when ``PATHWAY_TRACE`` is on —
+    """Per-run live tracer. Installed when ``PATHWAY_TRACE`` is on, or by
+    ``observability.tick_tracer`` for the ticks of a JAX profiler session —
     every hot-path call site guards on ``tracer is not None`` first, so the
     off mode costs one attribute read + ``is None`` test.
 
-    Recording a span appends a compact ``(name, parent_id, start_ns, end_ns,
-    attrs)`` record; span ids derive deterministically from the record's ring
-    sequence number at materialization time, so the hot path never formats or
-    draws ids (``os.urandom`` costs tens of µs on some kernels)."""
+    A record is ``(name, span_id, parent_id, start_ns, end_ns, attrs,
+    trace_id, thread)``: stamps are ``monotonic_ns``; ids are ints (formatted
+    at materialization), ready hex strings, or None (derived from the ring
+    sequence number — leaf spans never draw an id); ``trace_id`` is None for
+    the run's own trace (the request plane's per-request traces stitch next
+    to it); ``thread`` is the thread a ``begin``/``end`` span was open on,
+    None for a span recorded from stamps."""
 
     def __init__(
         self,
@@ -250,11 +294,17 @@ class Tracer:
         process_id: int = 0,
         sample: float = 1.0,
         buffer: SpanBuffer | None = None,
+        session: bool = False,
     ):
         self.trace_id = trace_id
         self.root_span_id = derive_root_span_id(trace_id)
         self.process_id = process_id
         self.sample = sample
+        #: brought up by a profiler session, retired with it
+        self.session = session
+        #: a profiler session is on: ``begin`` also enters a TraceAnnotation,
+        #: so the tick loop shows above the device ops in the ``.xplane.pb``
+        self.annotate = False
         self.buffer = buffer if buffer is not None else SpanBuffer()
         self.buffer.materialize = self._materialize
         self.buffer.serialize_batch = self._serialize_batch
@@ -269,45 +319,54 @@ class Tracer:
         # span names and attribute keys come from a tiny fixed set — cache
         # their JSON-escaped forms across flush batches
         self._dumps_cache: dict[str, str] = {}
-        self.start_ns = _time.time_ns()
+        self.start_ns = _time.monotonic_ns()
+        #: the last ``begin_tick``'s sampling decision; it stands until the
+        #: next one, so the wait between two ticks follows the tick before it
+        self.active = False
         # current sampled tick's span id, or None between/for unsampled ticks;
         # read by child-span emitters on worker threads (a benign race: a span
         # landing exactly at a tick boundary parents to the nearer tick)
         self.tick_span_id: str | None = None
+        self._tick_id: int | None = None  # the same id as the ring holds it
         self.current_tick: int | None = None
         # (label, bucket) shapes already dispatched — first sight of a padded
         # shape is the process's XLA-compile proxy (fresh jit cache entry)
         self._seen_shapes: set = set()
-        # ONE urandom draw; explicit ids (tick spans need theirs up front for
-        # parenting) walk the 64-bit space from the random base, and implicit
-        # ids derive from (base ^ seq) at materialization
+        # ONE urandom draw; explicit ids (spans with children need theirs up
+        # front for parenting) walk the 64-bit space from the random base,
+        # and implicit ids derive from (base ^ seq) at materialization
         self._id_base = int.from_bytes(secrets.token_bytes(8), "big")
         self._id_counter = itertools.count(1)
+        # per-thread stack of open spans: [span id, TraceAnnotation | None,
+        # children recorded]
+        self._tls = threading.local()
 
-    def _next_span_id(self) -> str:
-        return f"{(self._id_base + next(self._id_counter)) & _MASK:016x}"
+    def _hex_id(self, span_id: int | str) -> str:
+        if isinstance(span_id, str):
+            return span_id
+        return f"{(self._id_base + span_id) & _MASK:016x}"
 
     def _seq_span_id(self, seq: int) -> str:
-        # disjoint from _next_span_id's range for any realistic run: explicit
-        # ids count up from base, seq-derived ids flip the top bit
+        # disjoint from _hex_id's range for any realistic run: explicit ids
+        # count up from base, seq-derived ids flip the top bit
         return f"{(self._id_base ^ (1 << 63) ^ seq) & _MASK:016x}"
 
-    # records: (name, span_id | None, parent_id | None, start_ns, end_ns, attrs)
-    # — optionally extended with a 7th element overriding the trace id (the
-    # request plane's per-request trace ids stitch next to the run trace)
     def _materialize(self, seq: int, rec: tuple) -> dict:
-        name, span_id, parent_id, start_ns, end_ns, attrs = rec[:6]
+        name, span_id, parent_id, start_ns, end_ns, attrs, trace_id, thread = rec
+        boxed = [_attr(k, v) for k, v in attrs.items()] if attrs else []
+        if thread is not None:
+            boxed.append(_attr("thread.id", thread))
         span = {
-            "traceId": rec[6] if len(rec) > 6 else self.trace_id,
-            "spanId": span_id if span_id is not None else self._seq_span_id(seq),
+            "traceId": trace_id or self.trace_id,
+            "spanId": self._hex_id(span_id) if span_id is not None else self._seq_span_id(seq),
             "name": name,
             "kind": 1,
-            "startTimeUnixNano": str(start_ns),
-            "endTimeUnixNano": str(end_ns),
-            "attributes": [_attr(k, v) for k, v in attrs.items()] if attrs else [],
+            "startTimeUnixNano": str(unix_ns(start_ns)),
+            "endTimeUnixNano": str(unix_ns(end_ns)),
+            "attributes": boxed,
         }
         if parent_id is not None:
-            span["parentSpanId"] = parent_id
+            span["parentSpanId"] = self._hex_id(parent_id)
         return span
 
     def _serialize_batch(self, batch: list[tuple[int, tuple]]) -> str:
@@ -325,29 +384,31 @@ class Tracer:
 
         parts = []
         for seq, rec in batch:
-            name, span_id, parent_id, start_ns, end_ns, attrs = rec[:6]
-            trace_id = rec[6] if len(rec) > 6 else self.trace_id
-            if span_id is None:
-                span_id = self._seq_span_id(seq)
+            name, span_id, parent_id, start_ns, end_ns, attrs, trace_id, thread = rec
+            span_id = self._hex_id(span_id) if span_id is not None else self._seq_span_id(seq)
             a_parts = []
-            if attrs:
-                for k, v in attrs.items():
-                    if v is True or v is False:
-                        box = '{"boolValue":true}' if v else '{"boolValue":false}'
-                    elif isinstance(v, int):
-                        box = f'{{"intValue":"{v}"}}'
-                    elif isinstance(v, float):
-                        box = f'{{"doubleValue":{v!r}}}'
-                    else:
-                        box = f'{{"stringValue":{dumps(str(v))}}}'
-                    a_parts.append(f'{{"key":{cdumps(k)},"value":{box}}}')
+            for k, v in (attrs or {}).items():
+                if v is True or v is False:
+                    box = '{"boolValue":true}' if v else '{"boolValue":false}'
+                elif isinstance(v, int):
+                    box = f'{{"intValue":"{v}"}}'
+                elif isinstance(v, float):
+                    box = f'{{"doubleValue":{v!r}}}'
+                else:
+                    box = f'{{"stringValue":{dumps(str(v))}}}'
+                a_parts.append(f'{{"key":{cdumps(k)},"value":{box}}}')
+            if thread is not None:
+                a_parts.append(f'{{"key":"thread.id","value":{{"intValue":"{thread}"}}}}')
             parent = (
-                f'"parentSpanId":"{parent_id}",' if parent_id is not None else ""
+                f'"parentSpanId":"{self._hex_id(parent_id)}",'
+                if parent_id is not None
+                else ""
             )
             parts.append(
-                f'{{"traceId":"{trace_id}","spanId":"{span_id}",{parent}'
+                f'{{"traceId":"{trace_id or self.trace_id}","spanId":"{span_id}",{parent}'
                 f'"name":{cdumps(name)},"kind":1,'
-                f'"startTimeUnixNano":"{start_ns}","endTimeUnixNano":"{end_ns}",'
+                f'"startTimeUnixNano":"{unix_ns(start_ns)}",'
+                f'"endTimeUnixNano":"{unix_ns(end_ns)}",'
                 f'"attributes":[{",".join(a_parts)}]}}'
             )
         return self._doc_prefix + ",".join(parts) + self._doc_suffix
@@ -356,47 +417,98 @@ class Tracer:
     def tick_sampled(self, tick: int) -> bool:
         return tick_hash_sampled(tick, self.sample)
 
+    # ----------------------------------------------------------- open spans
+    def _stack(self) -> list:
+        st = getattr(self._tls, "stack", None)
+        if st is None:
+            st = self._tls.stack = []
+        return st
+
+    def _unwind(self, st: list, depth: int) -> bool:
+        """Close the entries above ``depth`` (one, unless an exception left
+        deeper ones open) and say whether the entry at ``depth`` had a child
+        recorded under it."""
+        had_children = bool(st[depth][2]) if depth < len(st) else False
+        while len(st) > depth:
+            ann = st.pop()[1]
+            if ann is not None:
+                ann.__exit__(None, None, None)
+        return had_children
+
+    def begin(self, name: str) -> tuple | None:
+        """Open a span on this thread: returns the token for :meth:`end`, or
+        None on an unsampled tick (head sampling suppresses every child span
+        of the tick)."""
+        if not self.active:
+            return None
+        st = self._stack()
+        ann = None
+        if self.annotate:
+            ann = _trace_annotation()(name)
+            ann.__enter__()
+        span_id = next(self._id_counter)
+        st.append([span_id, ann, 0])
+        return (self, len(st) - 1, span_id, name, _time.monotonic_ns())
+
+    def end(self, tok: tuple, attrs: dict | None = None, keep: bool = True) -> None:
+        """Close the span ``tok`` opened and record it under the span open
+        around it on this thread (the tick where none is). ``keep`` False —
+        nothing happened under it — leaves no span, unless a child was
+        recorded, which would otherwise name a parent nobody can find."""
+        _self, depth, span_id, name, start_ns = tok
+        end_ns = _time.monotonic_ns()
+        st = self._stack()
+        if not (self._unwind(st, depth) or keep):
+            return
+        if st:
+            st[-1][2] += 1
+            parent = st[-1][0]
+        else:
+            parent = self._tick_id or self.root_span_id
+        self.buffer.append(
+            (name, span_id, parent, start_ns, end_ns, attrs, None, threading.get_ident())
+        )
+
     # ------------------------------------------------------------------ ticks
-    def begin_tick(self, tick: int) -> int | None:
-        """Start-of-tick hook: returns a wall-clock token when the tick is
+    def begin_tick(self, tick: int) -> tuple | None:
+        """Start-of-tick hook: returns the tick span's token when the tick is
         sampled (pass it back to ``end_tick``), else None — and the None also
         suppresses every child span of the tick (head sampling)."""
-        if not self.tick_sampled(tick):
-            self.tick_span_id = None
-            self.current_tick = None
-            return None
-        self.tick_span_id = self._next_span_id()
-        self.current_tick = tick
-        return _time.time_ns()
+        self.active = self.tick_sampled(tick)
+        self._unwind(self._stack(), 0)
+        tok = self.begin("tick")
+        self._tick_id = tok[2] if tok is not None else None
+        self.tick_span_id = self._hex_id(tok[2]) if tok is not None else None
+        self.current_tick = tick if tok is not None else None
+        return tok
 
-    def end_tick(self, tick: int, start_ns: int, **attrs: Any) -> None:
-        span_id = self.tick_span_id
-        if span_id is None:
+    def end_tick(self, tick: int, tok: tuple, worked: bool = True, **attrs: Any) -> None:
+        """Close the tick. A tick in which nothing was polled, swept or
+        flushed (``worked`` False) and no span was recorded leaves no span."""
+        if self.tick_span_id is None:
             return
-        self.tick_span_id = None
+        self.tick_span_id = self._tick_id = None  # its parent is the run root
         self.current_tick = None
         attrs["pathway.tick"] = tick
         attrs["pathway.process_id"] = self.process_id
-        self.buffer.append(
-            ("tick", span_id, self.root_span_id, start_ns, _time.time_ns(), attrs)
-        )
+        self.end(tok, attrs, keep=worked)
 
-    # ----------------------------------------------------------- child spans
-    def span(
-        self, name: str, start_ns: int, end_ns: int, attrs: dict | None = None, **kw: Any
-    ) -> None:
-        """Record one finished span under the current tick (or the run root
-        when none is active, e.g. a persistence commit between ticks). Pass
-        ``attrs`` as a dict — hot call sites avoid **kwargs repacking."""
-        if kw:
-            attrs = {**attrs, **kw} if attrs else kw
-        self.buffer.append(
-            (name, None, self.tick_span_id or self.root_span_id, start_ns, end_ns, attrs)
-        )
+    # ---------------------------------------------------- spans from stamps
+    def span(self, name: str, start_ns: int, end_ns: int, attrs: dict | None = None) -> None:
+        """Record one finished span from two ``monotonic_ns`` stamps, under
+        the span open on this thread, else the current tick, else the run
+        root (e.g. a persistence commit between ticks)."""
+        st = getattr(self._tls, "stack", None)
+        if st:
+            st[-1][2] += 1
+            parent = st[-1][0]
+        else:
+            parent = self._tick_id or self.root_span_id
+        self.buffer.append((name, None, parent, start_ns, end_ns, attrs, None, None))
 
-    def event(self, name: str, attrs: dict | None = None, **kw: Any) -> None:
-        now = _time.time_ns()
-        self.span(name, now, now, attrs, **kw)
+    def event(self, name: str, attrs: dict | None = None) -> None:
+        now = _time.monotonic_ns()
+        self.span(name, now, now, attrs)
 
     def first_shape(self, label: str, bucket: int) -> bool:
         """True exactly once per (udf label, padded bucket) — marks the
@@ -410,16 +522,71 @@ class Tracer:
     # ----------------------------------------------------------------- close
     def close(self, emit_root: bool = True) -> None:
         """Flush + close the sink; process 0 emits the shared run-root span
-        every process's tick spans already parent to."""
-        if emit_root and self.process_id == 0:
+        every process's tick spans already parent to. The ring stays
+        readable."""
+        if emit_root and self.process_id == 0 and not self.session:
             self.buffer.append(
                 (
                     "pathway.run",
                     self.root_span_id,
                     None,
                     self.start_ns,
-                    _time.time_ns(),
+                    _time.monotonic_ns(),
                     {"pathway.process_id": self.process_id},
+                    None,
+                    None,
                 )
             )
         self.buffer.close()
+
+
+_annotation_cls: Any = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or False where jax cannot be had."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation_cls = TraceAnnotation
+        except Exception:  # pragma: no cover - jax is baked into the image
+            _annotation_cls = False
+    return _annotation_cls
+
+
+def profiler_session_active() -> bool:
+    """True between ``jax.profiler.start_trace`` and ``stop_trace`` (host
+    tracer level >= 1): one flag read, ~80 ns."""
+    cls = _trace_annotation()
+    return bool(cls) and cls.is_enabled()
+
+
+# ------------------------------------------------------------- sweep steps
+# The ONE span block of a sweep step, shared by the three runtimes
+# (engine/graph.py, parallel/sharded.py, parallel/cluster.py): a span on the
+# live tracer and a stage event on the request plane, from the same stamps.
+
+
+def step_begin(tracer: Tracer | None, rp: Any, name: str) -> tuple:
+    """``tracer`` is None on an unsampled tick, ``rp`` None with no request
+    in flight; a caller with neither skips the call."""
+    return (tracer.begin(name) if tracer is not None else None, rp, name, _time.monotonic_ns())
+
+
+def step_end(tok: tuple, time: int, rows_in: int, rows_out: int, attrs: dict) -> None:
+    span, rp, name, start_ns = tok
+    if rp is not None and (rows_in or rows_out):
+        # a no-op visit (nothing drained, nothing emitted) touched no
+        # request's rows — don't spend the per-tick ring budget
+        rp.note_stage(time, name, start_ns, _time.monotonic_ns(), rows_in)
+    if span is not None:
+        attrs["pathway.rows_in"] = rows_in
+        attrs["pathway.rows_out"] = rows_out
+        span[0].end(span, attrs)
+
+
+def step_drop(tok: tuple) -> None:
+    if tok[0] is not None:
+        tok[0][0].end(tok[0], keep=False)

@@ -28,6 +28,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pathway_tpu.native import try_load as _try_load_native
+from pathway_tpu import observability as _obs
 from pathway_tpu.observability import device as _dev_prof
 
 # C tokenizer kernel (None -> pure-Python fallback, bit-identical)
@@ -536,7 +537,6 @@ class JaxSentenceEncoder:
                 self.params,
                 param_shardings(self.cfg, mesh),
             )
-        self._param_count: int | None = None
         # memory attribution: encoder weights show up as
         # pathway_device_bytes{component="encoder_params"} while this
         # instance lives (weakly registered — no lifetime coupling)
@@ -548,33 +548,22 @@ class JaxSentenceEncoder:
     def dimension(self) -> int:
         return self.cfg.d_model
 
-    def param_count(self) -> int:
-        if self._param_count is None:
-            self._param_count = int(
-                sum(int(np.prod(p.shape)) for p in jax.tree.leaves(self.params))
-            )
-        return self._param_count
-
     def param_bytes(self) -> int:
         return int(sum(p.nbytes for p in jax.tree.leaves(self.params)))
 
-    def _note_launch(self, ids, mask=None) -> None:
-        """Padding-waste + FLOP accounting for one encoder launch (rough
-        transformer-forward estimate: 2 · params · tokens — the BASELINE
-        bench formula — over the PADDED token grid the device actually
-        runs)."""
-        stats = _dev_prof.stats()
-        if not stats.enabled:
-            return
-        total = int(ids.shape[0]) * int(ids.shape[1])
+    def _note_launch(self, ids, mask=None) -> int:
+        """Padding-waste accounting for one encoder launch over the PADDED
+        token grid the device actually runs; returns the real tokens."""
         real = int(np.count_nonzero(np.asarray(mask if mask is not None else ids)))
-        stats.note_pad_tokens("encoder", real, total - real)
-        stats.note_flops("encoder", 2.0 * self.param_count() * total)
+        stats = _dev_prof.stats()
+        if stats.enabled:
+            stats.note_pad_tokens("encoder", real, int(ids.size) - real)
+        return real
 
     def encode_texts(self, texts: list[str]) -> np.ndarray:
         if not texts:
             return np.zeros((0, self.cfg.d_model), dtype=np.float32)
-        return np.asarray(self.encode_texts_device(texts))
+        return _dev_prof.fetch(self.encode_texts_device(texts), "encoder")
 
     def encode_texts_device(self, texts: list[str]) -> jax.Array:
         """Like ``encode_texts`` but returns the device array without syncing —
@@ -586,15 +575,27 @@ class JaxSentenceEncoder:
         whose slot 0 is [PAD]), only the (narrow-int) id array crosses to the
         device and the mask is re-derived there; otherwise the tokenizer's own
         mask is honored and shipped alongside."""
+        tok = _obs.begin("embed/tokenize")
         ids, mask = self.tokenizer(texts)
-        self._note_launch(ids, mask)
+        real = self._note_launch(ids, mask)
+        if tok is not None:
+            _obs.end(
+                tok,
+                {
+                    "pathway.rows": len(texts),
+                    "pathway.real_tokens": real,
+                    "pathway.padded_len": int(ids.shape[1]),
+                },
+            )
         if getattr(self.tokenizer, "pad_id_zero", False):
-            return encode_ids_jit(self.params, self.cfg, ids)
-        return encode_jit(self.params, self.cfg, jnp.asarray(ids, jnp.int32), mask)
+            return encode_ids_jit(self.params, self.cfg, _dev_prof.put(ids, "encoder.ids"))
+        return encode_jit(
+            self.params, self.cfg, _dev_prof.put(ids, "encoder.ids", jnp.int32), mask
+        )
 
     def encode_tokens(self, ids: np.ndarray, mask: np.ndarray) -> np.ndarray:
         self._note_launch(ids, mask)
-        return np.asarray(encode_jit(self.params, self.cfg, ids, mask))
+        return _dev_prof.fetch(encode_jit(self.params, self.cfg, ids, mask), "encoder")
 
     def encode_ids_device(self, ids: np.ndarray | jax.Array) -> jax.Array:
         """Pre-tokenized ids (pad id 0) → embeddings, fully on device."""

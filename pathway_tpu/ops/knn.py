@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from pathway_tpu import observability as _obs
 from pathway_tpu.observability import device as _dev_prof
 
 
@@ -498,6 +499,10 @@ class BruteForceKnnIndex:
         self._stage_host(key, vec)
 
     def _flush(self) -> None:
+        if not (self._pending_slots or self._pending_device or self._pending_invalidate):
+            return
+        tok = _obs.begin("index/scatter")
+        rows = len(self._pending_slots) + sum(len(p[0]) for p in self._pending_device)
         self._flush_host()
         self._flush_device()
         if self._pending_invalidate:
@@ -510,6 +515,8 @@ class BruteForceKnnIndex:
                     self._valid, jnp.asarray(dead, dtype=jnp.int32)
                 )
             self._pending_invalidate = []
+        if tok is not None:
+            _obs.end(tok, {"pathway.rows": rows})
 
     def _flush_host(self) -> None:
         if self._pending_slots:
@@ -544,15 +551,15 @@ class BruteForceKnnIndex:
             # ship f32 rows: _scatter_block computes norms from full precision
             # BEFORE casting to the index dtype, so host- and device-ingested
             # rows score identically on non-f32 indexes
-            self._apply_scatter(slot_arr, bits, jnp.asarray(stacked))
+            self._apply_scatter(slot_arr, bits, _dev_prof.put(stacked, "knn.rows"))
             self._pending_slots, self._pending_rows, self._pending_bits = [], [], []
 
     def _apply_scatter(self, slots_np: np.ndarray, bits_np: np.ndarray, rows) -> None:
         """Land one block: slots+bits cross as a single packed put, and the
         whole (vectors, norms, valid, bits) update is one fused dispatch with
         donated buffers (no HBM copy of the index matrix)."""
-        slots_bits = jnp.asarray(
-            np.stack([slots_np.astype(np.int32), bits_np.view(np.int32)])
+        slots_bits = _dev_prof.put(
+            np.stack([slots_np.astype(np.int32), bits_np.view(np.int32)]), "knn.slots"
         )
         self._vectors, self._norms_sq, self._valid, self._key_bits = _scatter_block(
             self._vectors, self._norms_sq, self._valid, self._key_bits,
@@ -572,7 +579,9 @@ class BruteForceKnnIndex:
             if q.ndim == 1:
                 q = q[None, :]
         else:
-            q = jnp.asarray(np.atleast_2d(np.asarray(queries, np.float32)), self.dtype)
+            q = _dev_prof.put(
+                np.atleast_2d(np.asarray(queries, np.float32)), "knn.queries", self.dtype
+            )
         if q.shape[-1] != self.dimension:
             raise ValueError(f"query dim {q.shape[-1]} != {self.dimension}")
         return q
@@ -586,11 +595,7 @@ class BruteForceKnnIndex:
         q = self._prep_queries(queries)
         stats = _dev_prof.stats()
         if stats.enabled:
-            # rough probe cost: one dot per (query, slot) pair over the PADDED
-            # capacity — the padded-vs-valid gap is exactly the pad waste
-            stats.note_flops(
-                "knn.search", 2.0 * int(q.shape[0]) * self.capacity * self.dimension
-            )
+            # the padded-vs-valid gap of the scan is exactly the pad waste
             stats.note_pad_rows("knn.search", len(self), self.capacity - len(self))
         return _search_kernel(
             self._vectors, self._norms_sq, self._valid, self._key_bits, q,
@@ -615,8 +620,8 @@ class BruteForceKnnIndex:
         """One packed device→host fetch when the f32 value-cast of ids stays
         exact (capacity < 2^24); two plain fetches otherwise."""
         if self.capacity < (1 << 24):
-            return _unpack_hits(np.asarray(_pack_hits(scores, slot_ids)))
-        return np.asarray(scores), np.asarray(slot_ids)
+            return _unpack_hits(_dev_prof.fetch(_pack_hits(scores, slot_ids), "knn.hits"))
+        return _dev_prof.fetch(scores, "knn.scores"), _dev_prof.fetch(slot_ids, "knn.ids")
 
 
 def sharded_search(

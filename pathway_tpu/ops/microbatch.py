@@ -96,7 +96,7 @@ class MicrobatchDispatcher:
         from pathway_tpu.observability import requests as _requests
 
         tracer = _obs.current() if self.label is not None else None
-        if tracer is not None and tracer.tick_span_id is None:
+        if tracer is not None and not tracer.active:
             # head sampling: an unsampled tick records NO spans — dispatches
             # included (same gate as MicrobatchApplyNode's launch span)
             tracer = None
@@ -131,9 +131,10 @@ class MicrobatchDispatcher:
                     import time as _t
 
                     inner0 = _dev.thread_cold_s()
-                    w0 = _t.time_ns()
+                    tok = tracer.begin("device/dispatch") if tracer is not None else None
+                    w0 = _t.monotonic_ns()
                     results = self.fn(padded)
-                    w1 = _t.time_ns()
+                    w1 = _t.monotonic_ns()
                     if rp is not None:
                         # pad share + cold-compile attribution ride the
                         # request flight path (the serving tier's "why was
@@ -161,7 +162,7 @@ class MicrobatchDispatcher:
                             b,
                             inner_s=_dev.thread_cold_s() - inner0,
                         )
-                    if tracer is not None:
+                    if tok is not None:
                         attrs = {
                             "pathway.udf": self.label,
                             "pathway.bucket": b,
@@ -170,7 +171,7 @@ class MicrobatchDispatcher:
                         }
                         if cold:
                             attrs["pathway.compile_ms"] = round((w1 - w0) / 1e6, 3)
-                        tracer.span("device/dispatch", w0, w1, attrs)
+                        tracer.end(tok, attrs)
                 else:
                     results = self.fn(padded)
             finally:

@@ -61,7 +61,6 @@ class JaxCrossEncoder:
         self.cfg = cfg or EncoderConfig(n_layers=4)
         self.params = init_reranker_params(self.cfg, jax.random.PRNGKey(seed))
         self.tokenizer = HashTokenizer(self.cfg.vocab_size, self.cfg.max_len)
-        self._param_count: int | None = None
         _dev_prof.register_memory(
             self,
             "reranker_params",
@@ -93,11 +92,6 @@ class JaxCrossEncoder:
             mask[i, : len(t)] = True
         stats = _dev_prof.stats()
         if stats.enabled:
-            if self._param_count is None:
-                self._param_count = int(
-                    sum(int(np.prod(p.shape)) for p in jax.tree.leaves(self.params))
-                )
             real = int(mask.sum())
             stats.note_pad_tokens("reranker", real, ids.size - real)
-            stats.note_flops("reranker", 2.0 * self._param_count * ids.size)
-        return np.asarray(score_jit(self.params, self.cfg, ids, mask))
+        return _dev_prof.fetch(score_jit(self.params, self.cfg, ids, mask), "reranker")

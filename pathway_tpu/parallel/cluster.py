@@ -36,6 +36,7 @@ from typing import Any
 
 import numpy as np
 
+from pathway_tpu import observability as _obs
 from pathway_tpu.engine.blocks import DeltaBatch
 from pathway_tpu.engine import fusion as _fusion
 from pathway_tpu.engine.graph import BROADCAST, END_OF_STREAM, SOLO, Node
@@ -46,6 +47,7 @@ from pathway_tpu.internals.trace import run_annotated
 from pathway_tpu.observability import audit as _audit
 from pathway_tpu.observability import engine_phases as _phases
 from pathway_tpu.observability import requests as _requests
+from pathway_tpu.observability import spans as _spans
 from pathway_tpu.parallel.mesh import shard_of_keys
 from pathway_tpu.resilience import faults as _faults
 
@@ -477,7 +479,7 @@ class ClusterRuntime:
         self._shardmap_prev = None
         # live tracing (observability): installed in run(), None when off
         self.tracer = None
-        self._trace_active = False
+        self._tr = None  # the tracer during a sampled tick
         # request-scoped tracing: the plane while a request is in flight this
         # tick, else None (see engine.graph.Scheduler)
         self._rp = None
@@ -621,61 +623,45 @@ class ClusterRuntime:
         return routed
 
     # ---------------------------------------------------------------- ticking
-    def _sweep_worker_legacy(self, lw: _LocalWorker, time: int) -> bool:
-        """The r14 per-worker sweep, verbatim (PATHWAY_FUSE=off)."""
+    def _run_node(self, lw: _LocalWorker, node: Node, inputs, time: int, aud) -> None:
+        """One node step on this local worker: process, span, route (the
+        caller drained ``inputs`` under the worker's lock)."""
+        rows_in = sum(len(b) for b in inputs if b is not None)
+        node.stats_rows_in += rows_in
+        tr, rp = self._tr, self._rp
+        tok = (
+            _spans.step_begin(tr, rp, f"sweep/{node.name}")
+            if tr is not None or rp is not None
+            else None
+        )
+        out = run_annotated(node, node.process, inputs, time)
+        if tok is not None:
+            _spans.step_end(
+                tok, time, rows_in, sum(len(b) for b in out if b is not None),
+                {"pathway.operator.id": node.node_index, "pathway.worker": lw.index},
+            )
+        if aud is not None:
+            aud.note_edge(node, inputs, out)
+        self._route(lw, node, out)
+
+    def _sweep_worker_legacy(self, lw: _LocalWorker, time: int, aud) -> bool:
+        """The r14 per-worker sweep (PATHWAY_FUSE=off)."""
         any_work = False
-        trace = self._trace_active
-        rp = self._rp
-        aud = _audit.current()
-        aud_note = aud is not None and aud.edge_sampled
         for node in lw.graph.nodes:
             with lw.lock:
                 if not node.has_pending():
                     continue
                 inputs = node.drain()
-            rows_in = sum(len(b) for b in inputs if b is not None)
-            node.stats_rows_in += rows_in
-            if trace or rp is not None:
-                from pathway_tpu.observability import device as _dev_prof
-
-                w0 = _time.time_ns()
-                dev0 = _dev_prof.thread_device_wait_ns() if trace else 0
-            out = run_annotated(node, node.process, inputs, time)
-            if trace or rp is not None:
-                w1 = _time.time_ns()
-                if rp is not None and (
-                    rows_in
-                    or any(b is not None and not b.is_empty for b in out)
-                ):
-                    # a no-op visit (nothing drained, nothing emitted) touched
-                    # no request's rows — don't spend the per-tick ring budget
-                    rp.note_stage(time, f"sweep/{node.name}", w0, w1, rows_in)
-            if trace:
-                dev_ns = _dev_prof.thread_device_wait_ns() - dev0
-                self.tracer.span(
-                    f"sweep/{node.name}",
-                    w0,
-                    w1,
-                    {
-                        "pathway.operator.id": node.node_index,
-                        "pathway.worker": lw.index,
-                        "pathway.rows_in": rows_in,
-                        "pathway.device_ms": round(dev_ns / 1e6, 3),
-                    },
-                )
-                if dev_ns:
-                    _dev_prof.stats().note_span_split(
-                        f"sweep/{node.name}", max(0, w1 - w0 - dev_ns), dev_ns
-                    )
-            if aud_note:
-                aud.note_edge(node, inputs, out)
-            self._route(lw, node, out)
+            self._run_node(lw, node, inputs, time, aud)
             any_work = True
         return any_work
 
     def _sweep_worker(self, lw: _LocalWorker, time: int) -> bool:
+        aud = _audit.current()
+        if aud is not None and not aud.edge_sampled:
+            aud = None
         if lw.plan is None:
-            return self._sweep_worker_legacy(lw, time)
+            return self._sweep_worker_legacy(lw, time, aud)
         with lw.lock:
             if not lw.dirty:
                 return False
@@ -683,10 +669,6 @@ class ClusterRuntime:
             lw.dirty.clear()
         lw.sweep_heap = heap
         any_work = False
-        trace = self._trace_active
-        rp = self._rp
-        aud = _audit.current()
-        aud_note = aud is not None and aud.edge_sampled
         by_pos = lw.plan.by_pos
         last = -1
         try:
@@ -696,9 +678,8 @@ class ClusterRuntime:
                     continue
                 last = pos
                 step = by_pos[pos]
-                chain = step.chain
-                if chain is not None:
-                    if self._run_chain(lw, chain, time, trace, aud if aud_note else None):
+                if step.chain is not None:
+                    if self._run_chain(lw, step.chain, time, aud):
                         any_work = True
                     continue
                 node = step.node
@@ -706,92 +687,40 @@ class ClusterRuntime:
                     if not node.has_pending():
                         continue
                     inputs = node.drain()
-                rows_in = sum(len(b) for b in inputs if b is not None)
-                node.stats_rows_in += rows_in
-                if trace or rp is not None:
-                    from pathway_tpu.observability import device as _dev_prof
-
-                    w0 = _time.time_ns()
-                    dev0 = _dev_prof.thread_device_wait_ns() if trace else 0
-                out = run_annotated(node, node.process, inputs, time)
-                if trace or rp is not None:
-                    w1 = _time.time_ns()
-                    if rp is not None and (
-                        rows_in
-                        or any(b is not None and not b.is_empty for b in out)
-                    ):
-                        # a no-op visit (nothing drained, nothing emitted) touched
-                        # no request's rows — don't spend the per-tick ring budget
-                        rp.note_stage(time, f"sweep/{node.name}", w0, w1, rows_in)
-                if trace:
-                    dev_ns = _dev_prof.thread_device_wait_ns() - dev0
-                    self.tracer.span(
-                        f"sweep/{node.name}",
-                        w0,
-                        w1,
-                        {
-                            "pathway.operator.id": node.node_index,
-                            "pathway.worker": lw.index,
-                            "pathway.rows_in": rows_in,
-                            "pathway.device_ms": round(dev_ns / 1e6, 3),
-                        },
-                    )
-                    if dev_ns:
-                        _dev_prof.stats().note_span_split(
-                            f"sweep/{node.name}", max(0, w1 - w0 - dev_ns), dev_ns
-                        )
-                if aud_note:
-                    aud.note_edge(node, inputs, out)
-                self._route(lw, node, out)
+                self._run_node(lw, node, inputs, time, aud)
                 any_work = True
         finally:
             lw.sweep_heap = None
         return any_work
 
-    def _run_chain(self, lw: _LocalWorker, chain, time: int, trace: bool, aud) -> bool:
-        """One fused-chain step (see Scheduler._run_chain: per-chain span,
-        device wait and traced-jit cold walls subtracted from host share)."""
-        from pathway_tpu.observability import device as _dev_prof
-
-        rp = self._rp
-        if trace or rp is not None:
-            w0 = _time.time_ns()
-            dev0 = _dev_prof.thread_device_wait_ns() if trace else 0
-            cold0 = _dev_prof.thread_cold_s() if trace else 0.0
+    def _run_chain(self, lw: _LocalWorker, chain, time: int, aud) -> bool:
+        """One fused-chain step (see Scheduler._run_chain)."""
+        tr, rp = self._tr, self._rp
+        tok = (
+            _spans.step_begin(tr, rp, f"sweep/chain{{{chain.label}}}")
+            if tr is not None or rp is not None
+            else None
+        )
         t0 = _time.perf_counter_ns()
-        tok = _phases.start()
+        ptok = _phases.start()
         try:
             out, processed, rows_in, rows_out = chain.execute(time, lw.lock, aud)
         finally:
-            _phases.stop(tok, "fused")
+            _phases.stop(ptok, "fused")
         if not processed:
+            if tok is not None:
+                _spans.step_drop(tok)
             return False
-        elapsed_ns = _time.perf_counter_ns() - t0
-        chain.tail.stats_time_ns += elapsed_ns
-        if rp is not None:
-            rp.note_stage(
-                time, f"sweep/chain{{{chain.label}}}", w0, _time.time_ns(), rows_in
+        chain.tail.stats_time_ns += _time.perf_counter_ns() - t0
+        if tok is not None:
+            _spans.step_end(
+                tok, time, rows_in, rows_out,
+                {
+                    "pathway.operator.id": chain.operator_ids(),
+                    "pathway.worker": lw.index,
+                    "pathway.chain.nodes": len(chain.members),
+                },
             )
-        if trace:
-            w1 = _time.time_ns()
-            dev_ns = _dev_prof.thread_device_wait_ns() - dev0
-            cold_ns = int((_dev_prof.thread_cold_s() - cold0) * 1e9)
-            name = f"sweep/chain{{{chain.label}}}"
-            attrs = {
-                "pathway.operator.id": chain.operator_ids(),
-                "pathway.worker": lw.index,
-                "pathway.chain.nodes": len(chain.members),
-                "pathway.rows_in": rows_in,
-                "pathway.rows_out": rows_out,
-                "pathway.device_ms": round(dev_ns / 1e6, 3),
-            }
-            if cold_ns:
-                attrs["pathway.compile_ms"] = round(cold_ns / 1e6, 3)
-            self.tracer.span(name, w0, w1, attrs)
-            if dev_ns:
-                _dev_prof.stats().note_span_split(
-                    name, max(0, elapsed_ns - dev_ns - cold_ns), dev_ns
-                )
         self._route(lw, chain.tail, out)
         return True
 
@@ -860,25 +789,17 @@ class ClusterRuntime:
                             d["__rt_bc__"] = bc
                     return d
 
-        if not self._trace_active:
-            if self.pid == 0:
-                decision = self.coord.barrier(report, decide)
-            else:
-                decision = self.client.barrier(report)
+        # sampled tick: record the barrier round as a child span — wait time
+        # at barriers IS the cluster's skew/critical-path signal (SnailTrail)
+        tr = self._tr
+        tok = tr.begin(f"cluster/barrier/{phase}") if tr is not None else None
+        if self.pid == 0:
+            decision = self.coord.barrier(report, decide)
         else:
-            # sampled tick: record the barrier round as a child span — wait
-            # time at barriers IS the cluster's skew/critical-path signal
-            # (SnailTrail)
-            w0 = _time.time_ns()
-            if self.pid == 0:
-                decision = self.coord.barrier(report, decide)
-            else:
-                decision = self.client.barrier(report)
-            self.tracer.span(
-                f"cluster/barrier/{phase}",
-                w0,
-                _time.time_ns(),
-                {"pathway.process_id": self.pid, "pathway.tick": self.current_time},
+            decision = self.client.barrier(report)
+        if tok is not None:
+            tr.end(
+                tok, {"pathway.process_id": self.pid, "pathway.tick": self.current_time}
             )
         if rp is not None and self.pid != 0 and isinstance(decision, dict):
             rp.wire_apply(decision.get("__rt_bc__"))
@@ -963,9 +884,9 @@ class ClusterRuntime:
         from pathway_tpu.observability import device as _dev_prof
 
         _dev_prof.tick_hook(time)
-        tracer = self.tracer
+        tracer = self.tracer = _obs.tick_tracer(self.tracer)
         tick_token = tracer.begin_tick(time) if tracer is not None else None
-        self._trace_active = tick_token is not None
+        self._tr = tracer if tick_token is not None else None
         rp = _requests.current()
         if rp is not None and (not rp.hot or time == END_OF_STREAM):
             rp = None
@@ -1035,7 +956,7 @@ class ClusterRuntime:
         for cb in self.on_tick_done:
             cb(time)
         if tick_token is not None:
-            self._trace_active = False
+            self._tr = None
             tracer.end_tick(time, tick_token)
 
     def _peer_flows(self) -> dict[int, dict]:
@@ -1049,7 +970,6 @@ class ClusterRuntime:
     def run(self, outputs: list[LogicalNode]):
         from pathway_tpu import elastic as _elastic
         from pathway_tpu import flow as _flow
-        from pathway_tpu import observability as _obs
 
         _faults.install_from_env()
         _obs.install_from_env(self)

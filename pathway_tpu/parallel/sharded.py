@@ -40,6 +40,7 @@ from typing import Any
 
 import numpy as np
 
+from pathway_tpu import observability as _obs
 from pathway_tpu.engine import fusion as _fusion
 from pathway_tpu.engine.blocks import DeltaBatch
 from pathway_tpu.engine.graph import BROADCAST, END_OF_STREAM, SOLO, EngineGraph, Node
@@ -48,6 +49,7 @@ from pathway_tpu.internals.trace import run_annotated
 from pathway_tpu.observability import audit as _audit
 from pathway_tpu.observability import engine_phases as _phases
 from pathway_tpu.observability import requests as _requests
+from pathway_tpu.observability import spans as _spans
 from pathway_tpu.parallel.mesh import shard_of_keys
 from pathway_tpu.resilience import faults as _faults
 
@@ -106,7 +108,7 @@ class ShardedRuntime:
         self.wakeup = TickWakeup()
         # live tracing (observability): installed in run(), None when off
         self.tracer = None
-        self._trace_active = False
+        self._tr = None  # the tracer during a sampled tick
         # request-scoped tracing: the plane while a request is in flight this
         # tick, else None (see engine.graph.Scheduler)
         self._rp = None
@@ -217,66 +219,48 @@ class ShardedRuntime:
         return routed
 
     # ---------------------------------------------------------------- ticking
-    def _sweep_worker_legacy(self, worker: _Worker, time: int) -> bool:
-        """The r14 per-worker sweep, verbatim (PATHWAY_FUSE=off)."""
-        import time as _t
+    def _run_node(self, worker: _Worker, node: Node, inputs, time: int, aud) -> bool:
+        """One node step on this worker: process, span, route (the caller
+        drained ``inputs`` under the worker's lock)."""
+        rows_in = sum(len(b) for b in inputs if b is not None)
+        node.stats_rows_in += rows_in
+        tr, rp = self._tr, self._rp
+        tok = (
+            _spans.step_begin(tr, rp, f"sweep/{node.name}")
+            if tr is not None or rp is not None
+            else None
+        )
+        out = run_annotated(node, node.process, inputs, time)
+        if tok is not None:
+            _spans.step_end(
+                tok, time, rows_in, sum(len(b) for b in out if b is not None),
+                {"pathway.operator.id": node.node_index, "pathway.worker": worker.index},
+            )
+        if aud is not None:
+            # per-edge cardinality counters (node instances are per-worker,
+            # so no cross-thread contention; read side sums by position)
+            aud.note_edge(node, inputs, out)
+        return self._route(worker, node, out)
 
+    def _sweep_worker_legacy(self, worker: _Worker, time: int, aud) -> bool:
+        """The r14 per-worker sweep (PATHWAY_FUSE=off)."""
         any_work = False
-        trace = self._trace_active
-        rp = self._rp
-        aud = _audit.current()
-        aud_note = aud is not None and aud.edge_sampled
         for node in worker.graph.nodes:
             with worker.lock:
                 if not node.has_pending():
                     continue
                 inputs = node.drain()
-            rows_in = sum(len(b) for b in inputs if b is not None)
-            node.stats_rows_in += rows_in
-            if trace or rp is not None:
-                from pathway_tpu.observability import device as _dev_prof
-
-                w0 = _t.time_ns()
-                dev0 = _dev_prof.thread_device_wait_ns() if trace else 0
-            out = run_annotated(node, node.process, inputs, time)
-            if trace or rp is not None:
-                w1 = _t.time_ns()
-                if rp is not None and (
-                    rows_in
-                    or any(b is not None and not b.is_empty for b in out)
-                ):
-                    # a no-op visit (nothing drained, nothing emitted) touched
-                    # no request's rows — don't spend the per-tick ring budget
-                    rp.note_stage(time, f"sweep/{node.name}", w0, w1, rows_in)
-            if trace:
-                dev_ns = _dev_prof.thread_device_wait_ns() - dev0
-                self.tracer.span(
-                    f"sweep/{node.name}",
-                    w0,
-                    w1,
-                    {
-                        "pathway.operator.id": node.node_index,
-                        "pathway.worker": worker.index,
-                        "pathway.rows_in": rows_in,
-                        "pathway.device_ms": round(dev_ns / 1e6, 3),
-                    },
-                )
-                if dev_ns:
-                    _dev_prof.stats().note_span_split(
-                        f"sweep/{node.name}", max(0, w1 - w0 - dev_ns), dev_ns
-                    )
-            if aud_note:
-                aud.note_edge(node, inputs, out)
-            if self._route(worker, node, out):
+            if self._run_node(worker, node, inputs, time, aud):
                 any_work = True
             any_work = any_work or any(b is not None for b in inputs)
         return any_work
 
     def _sweep_worker(self, worker: _Worker, time: int) -> bool:
-        import time as _t
-
+        aud = _audit.current()
+        if aud is not None and not aud.edge_sampled:
+            aud = None
         if worker.plan is None:
-            return self._sweep_worker_legacy(worker, time)
+            return self._sweep_worker_legacy(worker, time, aud)
         with worker.lock:
             if not worker.dirty:
                 return False
@@ -284,10 +268,6 @@ class ShardedRuntime:
             worker.dirty.clear()
         worker.sweep_heap = heap
         any_work = False
-        trace = self._trace_active
-        rp = self._rp
-        aud = _audit.current()
-        aud_note = aud is not None and aud.edge_sampled
         by_pos = worker.plan.by_pos
         last = -1
         try:
@@ -297,9 +277,8 @@ class ShardedRuntime:
                     continue
                 last = pos
                 step = by_pos[pos]
-                chain = step.chain
-                if chain is not None:
-                    if self._run_chain(worker, chain, time, trace, aud if aud_note else None):
+                if step.chain is not None:
+                    if self._run_chain(worker, step.chain, time, aud):
                         any_work = True
                     continue
                 node = step.node
@@ -307,100 +286,44 @@ class ShardedRuntime:
                     if not node.has_pending():
                         continue
                     inputs = node.drain()
-                rows_in = sum(len(b) for b in inputs if b is not None)
-                node.stats_rows_in += rows_in
-                if trace or rp is not None:
-                    from pathway_tpu.observability import device as _dev_prof
-
-                    w0 = _t.time_ns()
-                    dev0 = _dev_prof.thread_device_wait_ns() if trace else 0
-                out = run_annotated(node, node.process, inputs, time)
-                if trace or rp is not None:
-                    w1 = _t.time_ns()
-                    if rp is not None and (
-                        rows_in
-                        or any(b is not None and not b.is_empty for b in out)
-                    ):
-                        # a no-op visit (nothing drained, nothing emitted) touched
-                        # no request's rows — don't spend the per-tick ring budget
-                        rp.note_stage(time, f"sweep/{node.name}", w0, w1, rows_in)
-                if trace:
-                    dev_ns = _dev_prof.thread_device_wait_ns() - dev0
-                    self.tracer.span(
-                        f"sweep/{node.name}",
-                        w0,
-                        w1,
-                        {
-                            "pathway.operator.id": node.node_index,
-                            "pathway.worker": worker.index,
-                            "pathway.rows_in": rows_in,
-                            "pathway.device_ms": round(dev_ns / 1e6, 3),
-                        },
-                    )
-                    if dev_ns:
-                        _dev_prof.stats().note_span_split(
-                            f"sweep/{node.name}", max(0, w1 - w0 - dev_ns), dev_ns
-                        )
-                if aud_note:
-                    # per-edge cardinality counters (node instances are
-                    # per-worker, so no cross-thread contention; read side
-                    # sums by position)
-                    aud.note_edge(node, inputs, out)
-                self._route(worker, node, out)
+                self._run_node(worker, node, inputs, time, aud)
                 any_work = True
         finally:
             worker.sweep_heap = None
         return any_work
 
-    def _run_chain(self, worker: _Worker, chain, time: int, trace: bool, aud) -> bool:
-        """One fused-chain step on this worker (see Scheduler._run_chain:
-        per-chain span, device wait AND inner traced-jit cold walls
-        subtracted from the host share)."""
+    def _run_chain(self, worker: _Worker, chain, time: int, aud) -> bool:
+        """One fused-chain step on this worker (see Scheduler._run_chain)."""
         import time as _t
 
-        from pathway_tpu.observability import device as _dev_prof
-
-        rp = self._rp
-        if trace or rp is not None:
-            w0 = _t.time_ns()
-            dev0 = _dev_prof.thread_device_wait_ns() if trace else 0
-            cold0 = _dev_prof.thread_cold_s() if trace else 0.0
+        tr, rp = self._tr, self._rp
+        tok = (
+            _spans.step_begin(tr, rp, f"sweep/chain{{{chain.label}}}")
+            if tr is not None or rp is not None
+            else None
+        )
         t0 = _t.perf_counter_ns()
-        tok = _phases.start()
+        ptok = _phases.start()
         try:
             out, processed, rows_in, rows_out = chain.execute(
                 time, worker.lock, aud
             )
         finally:
-            _phases.stop(tok, "fused")
+            _phases.stop(ptok, "fused")
         if not processed:
+            if tok is not None:
+                _spans.step_drop(tok)
             return False
-        elapsed_ns = _t.perf_counter_ns() - t0
-        chain.tail.stats_time_ns += elapsed_ns
-        if rp is not None:
-            rp.note_stage(
-                time, f"sweep/chain{{{chain.label}}}", w0, _t.time_ns(), rows_in
+        chain.tail.stats_time_ns += _t.perf_counter_ns() - t0
+        if tok is not None:
+            _spans.step_end(
+                tok, time, rows_in, rows_out,
+                {
+                    "pathway.operator.id": chain.operator_ids(),
+                    "pathway.worker": worker.index,
+                    "pathway.chain.nodes": len(chain.members),
+                },
             )
-        if trace:
-            w1 = _t.time_ns()
-            dev_ns = _dev_prof.thread_device_wait_ns() - dev0
-            cold_ns = int((_dev_prof.thread_cold_s() - cold0) * 1e9)
-            name = f"sweep/chain{{{chain.label}}}"
-            attrs = {
-                "pathway.operator.id": chain.operator_ids(),
-                "pathway.worker": worker.index,
-                "pathway.chain.nodes": len(chain.members),
-                "pathway.rows_in": rows_in,
-                "pathway.rows_out": rows_out,
-                "pathway.device_ms": round(dev_ns / 1e6, 3),
-            }
-            if cold_ns:
-                attrs["pathway.compile_ms"] = round(cold_ns / 1e6, 3)
-            self.tracer.span(name, w0, w1, attrs)
-            if dev_ns:
-                _dev_prof.stats().note_span_split(
-                    name, max(0, elapsed_ns - dev_ns - cold_ns), dev_ns
-                )
         self._route(worker, chain.tail, out)
         return True
 
@@ -454,9 +377,9 @@ class ShardedRuntime:
         from pathway_tpu.observability import device as _dev_prof
 
         _dev_prof.tick_hook(time)
-        tracer = self.tracer
+        tracer = self.tracer = _obs.tick_tracer(self.tracer)
         tick_token = tracer.begin_tick(time) if tracer is not None else None
-        self._trace_active = tick_token is not None
+        self._tr = tracer if tick_token is not None else None
         rp = _requests.current()
         if rp is not None and (not rp.hot or time == END_OF_STREAM):
             rp = None
@@ -512,7 +435,7 @@ class ShardedRuntime:
         for cb in self.on_tick_done:
             cb(time)
         if tick_token is not None:
-            self._trace_active = False
+            self._tr = None
             tracer.end_tick(time, tick_token)
 
     # ---------------------------------------------------------------- run loop
@@ -520,7 +443,6 @@ class ShardedRuntime:
         import time as _time
 
         from pathway_tpu import flow as _flow
-        from pathway_tpu import observability as _obs
 
         _faults.install_from_env()  # fault plan resets per run (as in Runtime)
         _obs.install_from_env(self)

@@ -522,7 +522,7 @@ class _EpochLog:
         if tracer is not None:
             tracer.event(
                 "persist/epoch_commit",
-                **{
+                {
                     "pathway.epoch": self.epoch,
                     "pathway.tick": tick,
                     "pathway.n_inputs": len(offsets),

@@ -26,6 +26,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from pathway_tpu import observability as _obs
 from pathway_tpu.engine.blocks import DeltaBatch
 from pathway_tpu.engine.graph import Node
 from pathway_tpu.internals.keys import tie_order, tie_order_u64
@@ -88,6 +89,7 @@ class VectorBackend(IndexBackend):
             return [[] for _ in items]
         kmax = max(ks, default=0)
         fetch = overfetch(kmax, n_live)
+        tok = _obs.begin("index/search")
         batch = np.stack([np.asarray(q, dtype=np.float32) for q in items])
         raw = self.index.search(batch, fetch)
         out = []
@@ -99,6 +101,15 @@ class VectorBackend(IndexBackend):
                 if flt(self.metadata.get(key)):
                     picked.append((key, float(score)))
             out.append(picked)
+        if tok is not None:
+            _obs.end(
+                tok,
+                {
+                    "pathway.queries": len(items),
+                    "pathway.fetch": fetch,
+                    "pathway.live_rows": n_live,
+                },
+            )
         return out
 
 
@@ -357,6 +368,8 @@ class ExternalIndexNode(Node):
                 "persistent storage (was the aux prefix deleted externally?)"
             )
         self.backend = _pickle.loads(raw)
+        tok = _obs.begin("index/add")
+        replayed = 0
         for name in chunks["deltas"]:
             ops_raw = store.get_chunk(name)
             if ops_raw is None:
@@ -367,8 +380,11 @@ class ExternalIndexNode(Node):
             for op in _pickle.loads(ops_raw):
                 if op[0] == "a":
                     self.backend.add(op[1], op[2], op[3])
+                    replayed += 1
                 else:
                     self.backend.remove(op[1])
+        if tok is not None:
+            _obs.end(tok, {"pathway.rows": replayed})
         # resume the chunk chain where the snapshot left it
         self._snap_base = chunks["base"]
         self._snap_deltas = list(chunks["deltas"])
@@ -440,6 +456,7 @@ class ExternalIndexNode(Node):
                     if fops is not None:
                         fops.append(("r", key))
                     self.backend.remove(key)
+            tok = _obs.begin("index/add")
             for i in range(len(docs)):
                 if docs.diffs[i] > 0:
                     key = int(docs.keys[i])
@@ -458,6 +475,9 @@ class ExternalIndexNode(Node):
                             )
                         )
                     self.backend.add(key, item, meta)
+            if tok is not None:
+                added = int((docs.diffs > 0).sum())
+                tok[0].end(tok, {"pathway.rows": added}, keep=added > 0)
             docs_changed = len(docs) > 0
             self._snap_mutations += len(docs)
         if fops:
@@ -506,10 +526,10 @@ class ExternalIndexNode(Node):
             if rp is not None and rp.hot:
                 import time as _t
 
-                w0 = _t.time_ns()
+                w0 = _t.monotonic_ns()
                 replies = self._answer(to_answer)
                 rp.note_stage(
-                    None, "index/search", w0, _t.time_ns(), len(to_answer)
+                    None, "index/search", w0, _t.monotonic_ns(), len(to_answer)
                 )
             else:
                 replies = self._answer(to_answer)

@@ -40,7 +40,7 @@ class _RT:
 
 @pytest.fixture(autouse=True)
 def _fresh_device_plane(monkeypatch):
-    """Per-run device state reset (pad/flops/split/flight), default knobs."""
+    """Per-run device state reset (pad/split/flight), default knobs."""
     for k in (
         "PATHWAY_PROFILE",
         "PATHWAY_PROFILE_DIR",
@@ -151,8 +151,6 @@ def test_encoder_token_pad_and_flops_accounting():
     assert pad["real_tokens"] > 0
     assert pad["pad_tokens"] > 0  # length bucketing always pads some
     assert 0 < pad["token_waste_ratio"] < 1
-    assert s["flops"]["by_label"]["encoder"] > 0
-    assert s["flops"]["per_s"] > 0
     # memory attribution: encoder params registered while the object lives
     mem = s["memory"]["components"]
     assert mem.get("encoder_params", 0) > 0
@@ -167,7 +165,6 @@ def test_knn_memory_and_flops_attribution():
     ix.search(np.zeros((2, 16), np.float32), k=3)
     s = device.status_summary()
     assert s["memory"]["components"].get("knn_index", 0) >= ix.device_bytes()
-    assert s["flops"]["by_label"]["knn.search"] > 0
     pad = s["pad"]["knn.search"]
     assert pad["real_rows"] == 10 and pad["pad_rows"] == ix.capacity - 10
     text = prometheus_text(_RT())
@@ -249,7 +246,7 @@ def test_run_status_has_device_section_and_metric_families():
     stats = run_stats(rt)
     dev = stats["device"]
     assert dev["enabled"] and dev["mode"] == "on"
-    for key in ("callables", "pad", "memory", "time_split", "flops", "flight"):
+    for key in ("callables", "pad", "memory", "time_split", "flight"):
         assert key in dev
     text = prometheus_text(rt)
     assert "pathway_jit_compiles_total" in text

@@ -186,7 +186,7 @@ def test_tail_sampling_catches_injected_delay_thread(monkeypatch):
 
         spans, _ = _obs.current().buffer.since(0, limit=100000)
         out["request_span_tids"] = {
-            s["traceId"] for s in spans if s["name"] == "request"
+            s["traceId"] for s in spans if s["name"] == "serve/request"
         }
         _stop_run()
 
@@ -387,9 +387,9 @@ def test_flight_dump_names_inflight_requests(tmp_path, monkeypatch):
         import time as _t
 
         key = 12345
-        plane.begin(key, "/v1/retrieve", _t.time_ns())
+        plane.begin(key, "/v1/retrieve", _t.monotonic_ns())
         plane.note_tick(7)
-        w = _t.time_ns()
+        w = _t.monotonic_ns()
         plane.note_stage(7, "index/search", w, w + 1000, rows=1)
         from pathway_tpu.observability import device as device_mod
 

@@ -191,8 +191,8 @@ def test_all_emitted_span_kinds_round_trip(tmp_path, monkeypatch):
     assert "serve/respond" in names, names
     assert "audit/violation" in names
     # request-plane spans (7-tuple records with per-request trace ids)
-    assert "request" in names and "serve/admission" in names, names
-    req_spans = [s for s in spans if s["name"] == "request"]
+    assert "serve/request" in names and "serve/admission" in names, names
+    req_spans = [s for s in spans if s["name"] == "serve/request"]
     tick_spans = [s for s in spans if s["name"] == "tick"]
     assert req_spans and tick_spans
     # request spans carry their own (per-request) trace ids, tick spans the
@@ -211,7 +211,7 @@ def test_all_emitted_span_kinds_round_trip(tmp_path, monkeypatch):
     assert sink_spans
     sink_names = {s["name"] for s in sink_spans}
     assert "tick" in sink_names
-    assert "request" in sink_names, "request spans missing from the file sink"
+    assert "serve/request" in sink_names, "request spans missing from the file sink"
 
 
 def test_request_plane_span_record_shapes():
@@ -221,7 +221,7 @@ def test_request_plane_span_record_shapes():
 
     plane = req_mod.RequestTracePlane(get_pathway_config())
     plane.slow_ms = 0.0  # keep unconditionally
-    now = time.time_ns()
+    now = time.monotonic_ns()
     key = 7777
     plane.begin(key, "/v1/retrieve", now)
     plane.note_tick(3)
@@ -256,7 +256,7 @@ def test_replica_span_record_shapes():
 
     plane = req_mod.RequestTracePlane(get_pathway_config())
     plane.slow_ms = 0.0  # keep unconditionally
-    now = time.time_ns()
+    now = time.monotonic_ns()
     key = 8888
     plane.begin(key, "/v1/retrieve", now)
     plane.note_boundary(key, "replica/embed", now, now + 4_000, None)
