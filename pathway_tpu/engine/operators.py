@@ -627,12 +627,12 @@ class MicrobatchUdfSpec:
 
     __slots__ = (
         "name", "args_program", "fn", "kw_names", "propagate_none",
-        "min_bucket", "deterministic",
+        "min_bucket", "deterministic", "length_of",
     )
 
     def __init__(
         self, name, args_program, fn, kw_names, propagate_none,
-        min_bucket=8, deterministic=False,
+        min_bucket=8, deterministic=False, length_of=None,
     ):
         self.name = name
         #: batch -> (list of positional arg arrays, list of kwarg arrays)
@@ -642,6 +642,9 @@ class MicrobatchUdfSpec:
         self.propagate_none = propagate_none
         self.min_bucket = min_bucket
         self.deterministic = deterministic
+        #: the UDF's declared per-row length estimate (called with one row's
+        #: arguments) or None: orders the rows of a multi-launch flush
+        self.length_of = length_of
 
 
 def _launch_udf_batch(spec: MicrobatchUdfSpec, items: list) -> list:
@@ -852,11 +855,16 @@ class MicrobatchApplyNode(Node):
         for j, spec in enumerate(self.udf_specs):
             need = [(i, all_cells[i][j]) for i in range(n) if all_cells[i][j][0] == "args"]
             if need:
+                length_of = None
+                if spec.length_of is not None:
+                    def length_of(item, s=spec):
+                        return s.length_of(*item[0], **dict(zip(s.kw_names, item[1])))
                 d = MicrobatchDispatcher(
                     lambda items, s=spec: _launch_udf_batch(s, items),
                     max_batch=max_batch,
                     min_bucket=spec.min_bucket,
                     label=spec.name,
+                    length_of=length_of,
                 )
                 results = d.map([(cell[1], cell[2]) for _, cell in need])
                 for (i, _), rv in zip(need, results):

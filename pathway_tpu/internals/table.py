@@ -760,6 +760,7 @@ def _microbatch_factory(
                 propagate_none=e.propagate_none,
                 min_bucket=int(getattr(udf, "microbatch_min_bucket", 8)),
                 deterministic=bool(e.deterministic),
+                length_of=getattr(udf, "microbatch_length", None),
             )
         )
     max_batch = max(1, min(

@@ -214,10 +214,15 @@ class UDF:
 
     #: microbatch knobs honored for ``is_batched`` subclasses (see
     #: ``engine.operators.MicrobatchApplyNode``): device launch chunk
-    #: (``None`` = the PATHWAY_MICROBATCH_MAX_BATCH default) and the smallest
-    #: padded bucket the jitted callee should ever see
+    #: (``None`` = the PATHWAY_MICROBATCH_MAX_BATCH default), the smallest
+    #: padded bucket the jitted callee should ever see, and a cheap estimate
+    #: of one row's length, called with the row's arguments (``None`` = rows
+    #: launch in arrival order; declared, a flush of several launches is cut
+    #: from the rows sorted by it, so a callee that pads a launch to its
+    #: longest row pads less — results and their order do not change)
     microbatch_max_batch: int | None = None
     microbatch_min_bucket: int = 8
+    microbatch_length: Callable[..., int] | None = None
 
     def __init__(
         self,
@@ -300,7 +305,8 @@ class UDF:
                 deterministic=self.deterministic,
             )
             # the microbatch planner reads per-UDF knobs off the expression
-            # (microbatch_max_batch / microbatch_min_bucket class attrs)
+            # (microbatch_max_batch / microbatch_min_bucket / microbatch_length
+            # class attrs)
             e.udf = self
             return e
         return expr_mod.ApplyExpression(

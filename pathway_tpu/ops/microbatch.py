@@ -6,10 +6,14 @@ for XLA (SURVEY §3.4, §7.1.5): each jit call has fixed dispatch overhead and a
 compile per shape. This dispatcher instead:
 
 1. buffers rows per (udf, logical time window),
-2. pads each flush to the next power-of-two *bucket* (so the jitted callee sees a
+2. cuts a flush into launches of at most ``max_batch`` rows — from the rows in
+   arrival order, or, when the UDF declares a per-row length and the flush holds
+   more than one launch, from the rows stable-sorted by that length, so short
+   rows launch with short rows and a launch pads to ITS longest, not the flush's,
+3. pads each launch to the next power-of-two *bucket* (so the jitted callee sees a
    small closed set of shapes → compile cache hits),
-3. invokes the batch function once per bucket,
-4. un-pads and scatters results back in row order.
+4. invokes the batch function once per launch,
+5. un-pads and scatters results back in submit order.
 
 Works for any callee that maps ``list[values] -> list[results]``; TPU model UDFs
 (embedder/reranker) provide a ``batch_fn`` operating on the padded arrays directly.
@@ -53,6 +57,11 @@ class MicrobatchDispatcher:
     ``fn`` is called as ``fn(items: list) -> Sequence`` where ``len(items)`` is
     always a bucket size; entries beyond the real row count are ``pad_item``
     repeats whose results are discarded.
+
+    ``length_of`` is the UDF's declared per-row length estimate (``item -> int``,
+    e.g. a text's word count for an encoder that pads a launch to its longest
+    row). It only orders rows inside a flush of more than one launch; a poor
+    estimate costs padding, never correctness.
     """
 
     def __init__(
@@ -62,6 +71,7 @@ class MicrobatchDispatcher:
         min_bucket: int = _MIN_BUCKET,
         pad_item: Any = None,
         label: str | None = None,
+        length_of: Callable[[Any], int] | None = None,
     ):
         if max_batch is None:
             # align the default launch chunk with the knob (it was a hardcoded
@@ -77,6 +87,7 @@ class MicrobatchDispatcher:
         # span label for the live trace plane (e.g. the UDF name); dispatch
         # spans are suppressed when unset or tracing is off
         self.label = label
+        self.length_of = length_of
         self._items: list = []
 
     def __len__(self) -> int:
@@ -90,7 +101,12 @@ class MicrobatchDispatcher:
         order. ``only_full=True`` launches only complete ``max_batch`` chunks
         (zero padding waste) and leaves the remainder buffered — the cross-tick
         accumulation mode: the engine keeps feeding rows and flushes the tail
-        on its autocommit deadline."""
+        on its autocommit deadline.
+
+        With a declared ``length_of``, a flush of more than one launch cuts
+        its launches from the rows stable-sorted by length (the partial chunk,
+        if any, is the one at the long end); a flush of one launch, and a
+        dispatcher without ``length_of``, launch in arrival order."""
         from pathway_tpu import observability as _obs
         from pathway_tpu.observability import device as _dev
         from pathway_tpu.observability import requests as _requests
@@ -107,6 +123,14 @@ class MicrobatchDispatcher:
             rp = None
         stats = _dev.stats()
         profiled = stats.enabled
+        take = len(self._items)
+        if only_full:
+            take -= take % self.max_batch
+        order = None
+        if self.length_of is not None and take > self.max_batch:
+            lengths = [self.length_of(it) for it in self._items[:take]]
+            order = sorted(range(take), key=lengths.__getitem__)
+            self._items[:take] = [self._items[i] for i in order]
         out: list = []
         while self._items and (not only_full or len(self._items) >= self.max_batch):
             chunk = self._items[: self.max_batch]
@@ -182,6 +206,11 @@ class MicrobatchDispatcher:
                     f"microbatch fn returned {len(results)} results for batch of {b}"
                 )
             out.extend(results[:n])
+        if order is not None:
+            by_submit: list = [None] * take
+            for i, r in zip(order, out):
+                by_submit[i] = r
+            out = by_submit
         return out
 
     def map(self, items: list) -> list:

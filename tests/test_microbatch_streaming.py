@@ -326,3 +326,70 @@ def test_static_run_flushes_at_its_single_tick():
     out = node.on_frontier(0)
     assert calls == [8]
     assert out[0].time == 0
+
+
+# ------------------------------------------------ the ingest cell's own lengths
+
+
+class _TS(pw.Schema):
+    k: int = pw.column_definition(primary_key=True)
+    text: str
+
+
+class _RecordingTokenizer:
+    """The embedder's tokenizer, noting the shape of every launch."""
+
+    def __init__(self, tok):
+        self.tok = tok
+        self.pad_id_zero = tok.pad_id_zero
+        self.shapes: list[tuple] = []
+
+    def __call__(self, texts):
+        ids, mask = self.tok(texts)
+        self.shapes.append(ids.shape)
+        return ids, mask
+
+
+@pytest.mark.parametrize("blocks_per_tick", [8, 1])
+def test_ingest_cell_lengths_pad_share(monkeypatch, blocks_per_tick):
+    """A count of work on ``minilm-l6.ingest-backfill``'s documents: eight
+    blocks of 512 arriving in one tick are one flush of eight launches, cut
+    from the length-sorted rows; arriving a block a tick, every flush is one
+    launch in arrival order and pads to the block's 240-word document."""
+    import json
+    import os
+
+    from chipbench import corpus
+    from pathway_tpu.observability import device
+    from pathway_tpu.ops.encoder import EncoderConfig
+    from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
+
+    monkeypatch.setenv("PATHWAY_MICROBATCH", "auto")
+    here = os.path.dirname(os.path.abspath(corpus.__file__))
+    with open(os.path.join(here, "configs", "live-rag-minilm-l6.json"), encoding="utf-8") as f:
+        lengths = json.load(f)["documents"]
+    texts = corpus.docs(7, 0, 8, lengths)
+    emb = SentenceTransformerEmbedder(
+        EncoderConfig(vocab_size=30522, d_model=16, n_heads=1, n_layers=1, d_ff=32)
+    )
+    tok = emb._encoder.tokenizer = _RecordingTokenizer(emb._encoder.tokenizer)
+    tick = lambda i: i // (corpus.BLOCK * blocks_per_tick)  # noqa: E731
+    t = pw.debug.table_from_rows(
+        _TS, [(i, x, tick(i), 1) for i, x in enumerate(texts)], is_stream=True
+    )
+    got = keyed_rows_of(t.select(t.k, v=emb(t.text)))
+    assert len(got) == len(texts)
+    # utils._norm gives an embedding as ("ndarray", shape, values)
+    assert all(row[1][1] == (16,) and np.isfinite(row[1][2]).all() for row in got.values())
+
+    # the run's own counters: a run resets them when it starts
+    _rows, _pad_rows, real, pad = device.stats().pad["encoder"]
+    assert real == sum(len(x.split()) + 1 for x in texts)
+    share = 100.0 * pad / (real + pad)
+    assert [rows for rows, _ in tok.shapes] == [corpus.BLOCK] * 8
+    if blocks_per_tick == 8:
+        assert {L for _, L in tok.shapes} == {32, 64, 128, 256}
+        assert share < 45.0
+    else:
+        assert {L for _, L in tok.shapes} == {256}
+        assert 74.0 < share < 75.0

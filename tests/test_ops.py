@@ -137,6 +137,105 @@ def test_microbatch_bucketing():
     assert calls == [64, 64]  # 64 + pad(36→64)
 
 
+def _mixed_texts(n: int) -> list[str]:
+    """n texts of 1-30 words in an order that is neither sorted nor periodic in
+    the chunk size: every chunk of arrival order holds short and long ones."""
+    words = [1 + (i * 37) % 30 for i in range(n)]
+    return [" ".join(f"w{i}x{j}" for j in range(m)) for i, m in enumerate(words)]
+
+
+def _words(text: str) -> int:
+    return text.count(" ")
+
+
+_SORT_CASES = {
+    # name: (rows in the flush, max_batch) -> launches; the last holds a partial chunk
+    "one_chunk": (16, 16),
+    "two_chunks": (32, 16),
+    "eight_chunks": (128, 16),
+    "partial_tail": (40, 16),
+}
+
+
+@pytest.mark.parametrize("what", ["labels", "order_seen", "encoder", "undeclared", "poison", "only_full"])
+@pytest.mark.parametrize("case", list(_SORT_CASES))
+def test_length_sorted_flush(case, what):
+    """A dispatcher whose UDF declares a length cuts a flush of several
+    launches from the length-sorted rows and still answers in submit order; a
+    flush of one launch, and a dispatcher that declares nothing, launch in
+    arrival order."""
+    n, max_batch = _SORT_CASES[case]
+    texts = _mixed_texts(n)
+    seen: list[list[str]] = []
+
+    def fn(items):
+        seen.append(list(items))
+        return [f"<{t}>" for t in items]
+
+    def chunks(items):
+        return [items[lo : lo + max_batch] for lo in range(0, len(items), max_batch)]
+
+    def real_rows():
+        # what each launch saw, without its repeat-last padding
+        return [b[:m] for b, m in zip(seen, [len(c) for c in chunks(texts)])]
+
+    if what == "labels":
+        # a length-independent batch function: exactly the unsorted dispatch's results
+        got = MicrobatchDispatcher(fn, max_batch=max_batch, length_of=_words).map(texts)
+        plain = MicrobatchDispatcher(fn, max_batch=max_batch).map(texts)
+        assert got == plain == [f"<{t}>" for t in texts]
+    elif what == "order_seen":
+        MicrobatchDispatcher(fn, max_batch=max_batch, length_of=_words).map(texts)
+        if n <= max_batch:
+            assert real_rows() == [texts]  # one launch: arrival order, untouched
+        else:
+            by_len = sorted(texts, key=_words)  # stable, as the dispatcher's
+            assert real_rows() == chunks(by_len)
+            # launches run from short to long: a partial chunk is the long end
+            longest = [max(map(_words, b)) for b in real_rows()]
+            assert longest == sorted(longest) and longest[0] < longest[-1]
+    elif what == "encoder":
+        enc = JaxSentenceEncoder(SMALL, seed=0)
+        embed = lambda items: list(enc.encode_texts(items))  # noqa: E731
+        got = MicrobatchDispatcher(embed, max_batch=max_batch, length_of=_words).map(texts)
+        plain = MicrobatchDispatcher(embed, max_batch=max_batch).map(texts)
+        np.testing.assert_allclose(np.stack(got), np.stack(plain), atol=1e-5, rtol=0)
+    elif what == "undeclared":
+        MicrobatchDispatcher(fn, max_batch=max_batch).map(texts)
+        assert real_rows() == chunks(texts)  # arrival order, whatever the flush size
+    elif what == "poison":
+        # the engine's batch function: a failing launch retries row by row, so a
+        # bad row of a sorted chunk poisons its own output and no other's
+        from pathway_tpu.engine.operators import MicrobatchUdfSpec, _launch_udf_batch
+        from pathway_tpu.internals.errors import ERROR
+
+        bad = texts[n // 2]
+
+        def udf(xs):
+            if bad in xs:
+                raise ValueError("bad row")
+            return [f"<{t}>" for t in xs]
+
+        spec = MicrobatchUdfSpec("y", None, udf, [], False, length_of=_words)
+        d = MicrobatchDispatcher(
+            lambda items: _launch_udf_batch(spec, items), max_batch=max_batch,
+            length_of=lambda item: spec.length_of(*item[0]),
+        )
+        got = d.map([((t,), ()) for t in texts])
+        assert [g is ERROR for g in got] == [t == bad for t in texts]
+        assert [g for g in got if g is not ERROR] == [f"<{t}>" for t in texts if t != bad]
+    else:
+        # only_full launches the full chunks of the rows submitted first and
+        # keeps the rest buffered in arrival order, sorted or not
+        d = MicrobatchDispatcher(fn, max_batch=max_batch, length_of=_words)
+        for t in texts:
+            d.submit(t)
+        full = n - n % max_batch
+        assert d.flush(only_full=True) == [f"<{t}>" for t in texts[:full]]
+        assert sorted(t for b in seen for t in b) == sorted(texts[:full])
+        assert d.flush() == [f"<{t}>" for t in texts[full:]]
+
+
 def test_pad_ragged_2d():
     rows = [np.array([1, 2, 3]), np.array([4])]
     ids, mask = pad_ragged_2d(rows)
