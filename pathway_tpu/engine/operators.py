@@ -38,6 +38,7 @@ from pathway_tpu.observability import engine_phases as _phases
 from pathway_tpu.observability import lineage as _lineage
 from pathway_tpu.engine.graph import END_OF_STREAM, SOLO, Node
 from pathway_tpu.engine.reducers_impl import ReducerImpl
+from pathway_tpu.internals.json import Json
 from pathway_tpu.internals.keys import combine_keys, row_keys, splitmix64
 
 # ---------------------------------------------------------------------------- inputs
@@ -574,46 +575,29 @@ class FlattenNode(Node):
         batch = inputs[0]
         if batch is None:
             return []
-        keys_out: list[int] = []
-        diffs_out: list[int] = []
+        # the one per-row step: gather each row's items and note where they end
         flat_vals: list[Any] = []
-        other_idx: list[int] = []
-        col = batch.data[self.flatten_col]
-        for i in range(len(batch)):
-            seq = col[i]
-            if seq is None:
-                continue
-            if isinstance(seq, np.ndarray):
-                items = list(seq)
-            elif isinstance(seq, (tuple, list, str, bytes)):
-                items = list(seq)
-            else:
-                from pathway_tpu.internals.json import Json
-
-                items = list(seq.value) if isinstance(seq, Json) else list(seq)
-            for j, item in enumerate(items):
-                keys_out.append(int(combine_keys(
-                    np.asarray([batch.keys[i]], dtype=np.uint64),
-                    splitmix64(np.asarray([j], dtype=np.uint64)),
-                )[0]))
-                diffs_out.append(int(batch.diffs[i]))
-                flat_vals.append(item)
-                other_idx.append(i)
-        data = {self.flatten_col: make_column(flat_vals, np.dtype(object))}
-        idx = np.asarray(other_idx, dtype=np.int64)
+        ends: list[int] = []
+        for seq in batch.data[self.flatten_col]:
+            if seq is not None:
+                flat_vals.extend(seq.value if isinstance(seq, Json) else seq)
+            ends.append(len(flat_vals))
+        # the rest per block: an item of row i gets hash(key_i, j), j its
+        # position within the row (row-major, item order)
+        ends_arr = np.asarray(ends, dtype=np.int64)
+        counts = np.diff(ends_arr, prepend=0)
+        idx = np.repeat(np.arange(len(counts), dtype=np.int64), counts)
+        j = np.arange(len(flat_vals), dtype=np.int64) - (ends_arr - counts)[idx]
+        keys_out = combine_keys(batch.keys[idx], splitmix64(j.astype(np.uint64)))
         lin = _lineage.current()
         if lin is not None and len(idx):
-            lin.record_edge(
-                self, np.asarray(keys_out, dtype=np.uint64), batch.keys[idx]
-            )
+            lin.record_edge(self, keys_out, batch.keys[idx])
+        data = {self.flatten_col: make_column(flat_vals, np.dtype(object))}
         for c in self.other_cols:
             data[c] = batch.data[c][idx]
         return [
             DeltaBatch(
-                np.asarray(keys_out, dtype=np.uint64),
-                np.asarray(diffs_out, dtype=np.int64),
-                data,
-                time,
+                keys_out, batch.diffs[idx].astype(np.int64, copy=False), data, time
             )
         ]
 
@@ -1597,6 +1581,13 @@ class GroupByNode(Node):
                 grouped.append(impl.grouped_partials(arrays, diffs, order, starts))
             else:
                 grouped.append(None)
+        # the other reducers prepare the tick's rows once; each group then
+        # folds its own rows of that block
+        blocks = [
+            None if spec[1].semigroup else spec[1].block_rows(arrays, diffs)
+            for spec, arrays in zip(self.reducer_specs, spec_arrays)
+        ]
+        order_list = order.tolist()
         first_rows = order[starts] if n_groups else order
         group_val_lists = [column_to_list(arr[first_rows]) for arr in group_arrays]
         gk_list = gk_sorted[starts].tolist() if n_groups else []
@@ -1630,18 +1621,10 @@ class GroupByNode(Node):
                     partial = impl.batch_partial(cols_slice, diffs[idx], slice(None))
                     st["acc"][r] = impl.merge_partial(st["acc"][r], partial)
                 else:
-                    for i in order[s:e]:
-                        st["acc"][r] = (
-                            impl.update(
-                                st["acc"][r],
-                                tuple(arr[i] for arr in arrays),
-                                int(diffs[i]),
-                                time,
-                                self._seq,
-                            )
-                            or st["acc"][r]
-                        )
-                        self._seq += 1
+                    st["acc"][r] = impl.fold_rows(
+                        st["acc"][r], blocks[r], order_list[s:e], time, self._seq
+                    )
+                    self._seq += int(e - s)
             # emit — except the None-id group: mid-tick join padding may put rows
             # there transiently; if they persist, they are dropped from output
             # (reference: error-keyed rows go to the error log, not results)

@@ -33,6 +33,27 @@ class ReducerImpl:
     def extract(self, state: Any) -> Any:
         raise NotImplementedError
 
+    # non-semigroup: a tick's rows are prepared once (``block_rows``), then each
+    # group folds its own rows of that block (``fold_rows``)
+    def block_rows(self, arrays: list[np.ndarray], diffs: np.ndarray) -> Any:
+        """What ``fold_rows`` needs of one tick's batch, built once for all its
+        groups."""
+        return arrays, diffs
+
+    def fold_rows(self, state: Any, block: Any, rows: list[int], time: int, seq: int) -> Any:
+        """Fold one group's ``rows`` (indices into the block, arrival order)
+        into ``state``; row k carries sequence number ``seq + k``. The default is
+        ``update`` row by row."""
+        arrays, diffs = block
+        for k, i in enumerate(rows):
+            state = (
+                self.update(
+                    state, tuple(arr[i] for arr in arrays), int(diffs[i]), time, seq + k
+                )
+                or state
+            )
+        return state
+
     # semigroup only: partial over a slice of column arrays, then merge
     def batch_partial(self, cols: list[np.ndarray], diffs: np.ndarray, sl: slice) -> Any:
         raise NotImplementedError
@@ -168,26 +189,61 @@ class _MultisetState:
         self.total = 0
 
 
+#: exact scalar types whose equality (with the type) implies equal canonical
+#: bytes, so one encoding serves every equal value of a block; containers are
+#: left out: (1, 2) == (1.0, 2) but they encode apart
+_MEMO_TYPES = frozenset(
+    {int, float, str, bytes, bool, type(None)}
+    | {t for t in np.sctypeDict.values() if np.dtype(t).kind in "biufMmUS"}
+)
+
+
+def _encode_block(values: list[tuple]) -> list[bytes]:
+    """``_canonical_bytes`` of every value tuple, computed once per distinct
+    tuple of plain scalars."""
+    memo: dict[tuple, bytes] = {}
+    out = []
+    for v in values:
+        types = tuple(map(type, v))
+        if _MEMO_TYPES.issuperset(types):
+            key = (types, v)
+            ck = memo.get(key)
+            if ck is None:
+                ck = memo[key] = _canonical_bytes(v)
+        else:
+            ck = _canonical_bytes(v)
+        out.append(ck)
+    return out
+
+
 class MultisetReducer(ReducerImpl):
     """Base for reducers re-extracted from a value multiset."""
 
     def make(self):
         return _MultisetState()
 
-    def _key_values(self, values: tuple):
-        return values
+    def block_rows(self, arrays, diffs):
+        # list(arr) keeps numpy scalars, as arr[i] gives them
+        values = list(zip(*(list(arr) for arr in arrays)))
+        return values, _encode_block(values), diffs.tolist()
 
-    def update(self, state: _MultisetState, values, diff, time, seq):
-        v = self._key_values(values)
-        ck = _canonical_bytes(v)
-        ent = state.items.get(ck)
-        if ent is None:
-            ent = [v, 0, (time, seq)]
-            state.items[ck] = ent
-        ent[1] += diff
-        if ent[1] == 0:
-            del state.items[ck]
-        state.total += diff
+    def fold_rows(self, state: _MultisetState, block, rows, time, seq):
+        # row by row, because the order of arrival is part of the state: an
+        # entry that empties and refills within a tick takes the refilling
+        # row's (time, seq) and moves to the end of ``items``
+        values, cks, diffs = block
+        items = state.items
+        total = 0
+        for k, i in enumerate(rows):
+            ck, diff = cks[i], diffs[i]
+            ent = items.get(ck)
+            if ent is None:
+                ent = items[ck] = [values[i], 0, (time, seq + k)]
+            ent[1] += diff
+            if ent[1] == 0:
+                del items[ck]
+            total += diff
+        state.total += total
         return state
 
 

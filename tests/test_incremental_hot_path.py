@@ -737,3 +737,64 @@ def test_multimap_insert_only_arrangement_stays_bounded():
     q_idx, rks, _ = mm2.match(np.arange(4, dtype=np.uint64))
     assert len(rks) == n_frag * 4
     assert len(mm2.segments) == 1
+
+
+# --------------------------------------------- block work is done per block
+# A count of work, not a timing: the per-row paths PR 29 took out of the
+# store's chain steps cannot come back unnoticed.
+
+_BLOCK_DOCS = 4096
+
+
+def _counting(monkeypatch, module, name):
+    calls = []
+    fn = getattr(module, name)
+
+    def counted(*args, **kwargs):
+        calls.append(1)
+        return fn(*args, **kwargs)
+
+    monkeypatch.setattr(module, name, counted)
+    return calls
+
+
+def test_flatten_hashes_a_block_in_a_constant_number_of_calls(monkeypatch):
+    from pathway_tpu.internals import keys
+
+    chunks = np.empty(_BLOCK_DOCS, dtype=object)
+    for i in range(_BLOCK_DOCS):
+        chunks[i] = [(f"text {i}.{j}", {}) for j in range(1 + i % 3)]
+    batch = DeltaBatch(
+        keys.splitmix64(np.arange(_BLOCK_DOCS, dtype=np.uint64)),
+        np.ones(_BLOCK_DOCS, dtype=np.int64),
+        {"chunks": chunks, "meta": np.arange(_BLOCK_DOCS)},
+        0,
+    )
+    in_ops = _counting(monkeypatch, ops, "splitmix64")
+    in_keys = _counting(monkeypatch, keys, "splitmix64")
+    (out,) = ops.FlattenNode("chunks", ["meta"]).process([batch], 0)
+    assert len(out) == sum(1 + i % 3 for i in range(_BLOCK_DOCS))
+    assert len(in_ops) + len(in_keys) <= 4
+
+
+def test_groupby_encodes_each_distinct_value_of_a_block_once(monkeypatch):
+    from pathway_tpu.engine import reducers_impl
+
+    distinct = [-0.5, 0, 1, 2.5, 3, 7, 11, 13]
+    t = pw.debug.table_from_rows(
+        pw.schema_from_types(g=int, v=float, s=str),
+        [(i % 3, distinct[i % 8], f"s{i % 5}") for i in range(_BLOCK_DOCS)],
+    )
+    encoded = _counting(monkeypatch, reducers_impl, "_canonical_bytes")
+    r = t.groupby(t.g).reduce(
+        t.g,
+        lo=pw.reducers.min(t.v),
+        hi=pw.reducers.max(t.v),
+        some=pw.reducers.any(t.s),
+        all=pw.reducers.sorted_tuple(t.s),
+    )
+    rows = rows_of(r)
+    assert {row[:3] for row in rows} == {(g, -0.5, 13.0) for g in range(3)}
+    assert sum(len(row[4]) for row in rows) == _BLOCK_DOCS
+    # one tick, four reducers: 8 + 8 distinct numbers, 5 + 5 distinct strings
+    assert 0 < len(encoded) <= 26
