@@ -13,13 +13,10 @@ attributes it:
   protocol: a deep stateless transform chain (filters / arithmetic maps /
   projections — the row-microbatch shape of RAG preprocessing pipelines)
   driven by pre-columnar delta blocks at 64/256/1024 rows per tick,
-  PATHWAY_FUSE=on vs off interleaved best-of-``REPS``. ``off`` is the
-  verbatim r14 engine (full-scan sweep, one dispatch per node), so the A/B
-  measures the whole-tick fused dispatch win; outputs are asserted
-  byte-identical in-bench, and a quiescent-tick rate (empty ticks — the
-  no-op sweep short-circuit) rides along. Gate: ``small_tick_speedup_64``
-  must stay >= the committed BENCH value minus ``GATE_SPEEDUP_DROP`` under
-  ``BENCH_MODE=1`` (noisy-host downgrade as below).
+  best-of-``REPS`` ticks per second, and a quiescent-tick rate (empty ticks
+  — the no-op sweep short-circuit). The fused-vs-``PATHWAY_FUSE=off`` A/B
+  and its gate went with the second sweep (PR 30): there is one loop left
+  to time.
 - ``python benchmarks/engine_bench.py --full [N]`` — the r11 protocol:
   interleaved best-of-``REPS`` static (one load) vs incremental (the same
   rows over ``N_TIMES`` logical timestamps), a per-phase tick breakdown of
@@ -107,7 +104,6 @@ def run(n: int = 1_000_000, n_times: int = 1) -> dict:
 # ------------------------------------------------------------- small ticks (r15)
 
 SMALL_TICKS = 300
-GATE_SPEEDUP_DROP = 1.0  # allowed drop in small_tick_speedup_64 vs committed
 
 
 def _small_tick_pipeline(blocks):
@@ -282,78 +278,19 @@ def small_ticks(
     reps: int = REPS,
     out_path: str | None = None,
 ) -> dict:
-    """Fused-vs-unfused A/B at small tick sizes, interleaved best-of-reps,
-    byte-identity asserted in-bench; plus the quiescent (empty) tick rate."""
+    """Tick rate at small tick sizes, best-of-reps; plus the quiescent
+    (empty) tick rate."""
     results: dict = {"bench": "engine_small_ticks", "n_ticks": n_ticks, "reps": reps}
-    all_rates: dict[tuple, list[float]] = {}
+    spread = 1.0
     for rpt in rows_per_tick:
-        best = {"on": 9e9, "off": 9e9}
-        outs: dict[str, dict] = {}
-        for _ in range(reps):
-            for mode in ("on", "off"):
-                os.environ["PATHWAY_FUSE"] = mode
-                try:
-                    dt, out = _small_tick_run(rpt, n_ticks)
-                finally:
-                    os.environ.pop("PATHWAY_FUSE", None)
-                best[mode] = min(best[mode], dt)
-                outs[mode] = out
-                all_rates.setdefault((rpt, mode), []).append(n_ticks / dt)
-        identical = outs["on"] == outs["off"]
-        if not identical:
-            results["gate_ok"] = False
-            print(json.dumps(results))
-            print(
-                f"GATE FAILURE: fused output differs from unfused at {rpt}-row ticks",
-                file=sys.stderr,
-            )
-            sys.exit(1)
-        speedup = round(best["off"] / best["on"], 2)
-        results[f"small_tick_fused_ticks_per_s_{rpt}"] = round(n_ticks / best["on"], 1)
-        results[f"small_tick_unfused_ticks_per_s_{rpt}"] = round(
-            n_ticks / best["off"], 1
-        )
-        results[f"small_tick_speedup_{rpt}"] = speedup
-    # quiescent ticks: nothing arrives — the r15 sweep short-circuit vs the
-    # r14 full per-node scan + all-node frontier walk
-    for mode in ("on", "off"):
-        os.environ["PATHWAY_FUSE"] = mode
-        try:
-            best_q = min(_small_tick_run(0, 2000)[0] for _ in range(3))
-        finally:
-            os.environ.pop("PATHWAY_FUSE", None)
-        results[f"quiescent_ticks_per_s_{mode}"] = round(2000 / best_q, 1)
-    results["quiescent_speedup"] = round(
-        results["quiescent_ticks_per_s_on"] / results["quiescent_ticks_per_s_off"], 2
-    )
-
-    spread = max(
-        max(v) / max(min(v), 1e-9) for v in all_rates.values() if v
-    )
-    noisy = spread > 1.6
+        times = [_small_tick_run(rpt, n_ticks)[0] for _ in range(reps)]
+        results[f"small_tick_ticks_per_s_{rpt}"] = round(n_ticks / min(times), 1)
+        spread = max(spread, max(times) / max(min(times), 1e-9))
+    # quiescent ticks: nothing arrives — the sweep short-circuit
+    best_q = min(_small_tick_run(0, 2000)[0] for _ in range(3))
+    results["quiescent_ticks_per_s"] = round(2000 / best_q, 1)
     results["rep_spread_max"] = round(spread, 2)
-    results["noisy_host"] = noisy
-    results["outputs_byte_identical"] = True
-
-    gate_ok = True
-    prev = _last_committed_metric("small_tick_speedup_64", exclude=out_path)
-    if prev is not None:
-        prev_v, prev_file = prev
-        results["gate_baseline_speedup_64"] = prev_v
-        results["gate_baseline_file"] = prev_file
-        if results["small_tick_speedup_64"] < prev_v - GATE_SPEEDUP_DROP:
-            gate_ok = False
-            msg = (
-                f"small_tick_speedup_64 regressed: "
-                f"{results['small_tick_speedup_64']} vs {prev_v} in {prev_file}"
-            )
-            if os.environ.get("BENCH_MODE") == "1" and not noisy:
-                results["gate_ok"] = False
-                print(json.dumps(results))
-                print(f"GATE FAILURE: {msg}", file=sys.stderr)
-                sys.exit(1)
-            print(f"WARNING: {msg}", file=sys.stderr)
-    results["gate_ok"] = gate_ok
+    results["noisy_host"] = spread > 1.6
     return results
 
 

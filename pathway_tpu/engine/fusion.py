@@ -1,7 +1,7 @@
 """Chain-fusion pass: whole-tick compiled dataflow (ROADMAP #3).
 
 Before r15 every operator in a tick launched separately from Python —
-``Scheduler._sweep`` walked nodes one at a time, and at small (64–1k row)
+the sweep walked nodes one at a time, and at small (64–1k row)
 ticks the per-node dispatch (drain / stats / route / accept bookkeeping plus
 the O(all nodes) quiescence scans) dominated the tick budget. This module
 inverts the execution model: **chains become the unit of dispatch**.
@@ -32,7 +32,8 @@ shape set stays closed under row-count churn, and per-chain compile
 telemetry rides the r10 ``traced_jit`` machinery under the
 ``engine.fused_chain/*`` labels.
 
-``PATHWAY_FUSE=off`` restores the one-node-per-step sweep exactly.
+A plan with no chains (every node its own step) is the reference the
+differential tests hold the fused stream to: ``build_plan(..., fuse=False)``.
 
 The plan also precomputes which nodes actually override ``poll`` /
 ``on_frontier`` / ``on_tick_complete`` so the tick loops visit only those —
@@ -743,21 +744,19 @@ class Plan:
         self.chains = chains
 
 
-def build_plan(graph, exchange_aware: bool, transient: bool = False) -> Plan | None:
-    """Compute the sweep plan for ``graph``, or **None** when
-    ``PATHWAY_FUSE=off`` — the escape hatch disables the whole r15
-    execution model (chains, dirty-step scheduling, hook visit lists) and
-    the runtimes fall back to their r14 full-scan loops verbatim.
+def build_plan(
+    graph, exchange_aware: bool, transient: bool = False, fuse: bool = True
+) -> Plan:
+    """Compute the sweep plan for ``graph``: chains, dirty-step positions
+    and the hook visit lists.
     ``exchange_aware=True`` (sharded/cluster runtimes) restricts interior
     links to exchange-free consumers — fusing across an exchange would move
     rows off the worker the unfused routing would have placed them on.
     ``transient=True`` (short-lived inner graphs rebuilt per use, e.g.
     iterate's fixed-point body) pins the segments' jax tier off — a fresh
-    ``jax.jit`` per rebuild would re-trace per tick."""
-    from pathway_tpu.internals.config import get_pathway_config
-
-    if get_pathway_config().fuse != "on":
-        return None
+    ``jax.jit`` per rebuild would re-trace per tick.
+    ``fuse=False`` is for tests only: no chains, every node its own step —
+    the reference the fused delta stream must equal byte for byte."""
     plan = Plan(graph)
     chains: list[FusedChain] = []
     nodes = graph.nodes
@@ -767,7 +766,7 @@ def build_plan(graph, exchange_aware: bool, transient: bool = False) -> Plan | N
             key = (ci, port)
             in_count[key] = in_count.get(key, 0) + 1
     assigned = [False] * len(nodes)
-    for h in range(len(nodes)):
+    for h in range(len(nodes) if fuse else 0):
         if assigned[h] or not _chain_member_ok(nodes[h]):
             continue
         chain = [h]

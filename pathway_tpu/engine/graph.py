@@ -13,8 +13,9 @@ inputs" consistency model.
 from __future__ import annotations
 
 import heapq
+import threading
 import time as _time
-from typing import Callable
+from typing import Any, Callable
 
 import numpy as np
 
@@ -199,18 +200,87 @@ class EngineGraph:
         return node
 
 
-class Scheduler:
-    """Drives the engine graph tick by tick.
+class Worker:
+    """One engine graph as the tick loop drives it: its sweep plan
+    (``engine/fusion.py``), the dirty step positions and the active sweep's
+    heap. ``lock`` is None where one thread owns the graph (``Scheduler``);
+    the multi-worker runtimes give every worker one, and it guards the
+    accepts, marks and drains that cross threads (sibling workers, the peer
+    links' readers)."""
 
-    r15: the sweep is PLAN-driven (``engine/fusion.py``). Fused chains
-    execute as single steps, idle nodes are never visited — routing marks
-    the consumer's step dirty, and a sweep drains the dirty set in
-    topological order (edges only point forward, so one drain reaches
-    quiescence). The tick's poll/frontier/complete loops visit only nodes
-    that actually override those hooks."""
+    __slots__ = ("index", "graph", "plan", "lock", "dirty", "heap", "span_attrs")
 
-    def __init__(self, graph: EngineGraph, transient: bool = False):
+    def __init__(self, index: int, graph: EngineGraph, plan, lock=None):
+        self.index = index
         self.graph = graph
+        self.plan = plan
+        self.lock = lock
+        #: dirty step positions (guarded by ``lock`` where there is one)
+        self.dirty: set[int] = set()
+        #: the active sweep's heap — only the sweeping thread touches it
+        self.heap: list[int] | None = None
+        self.span_attrs = {} if lock is None else {"pathway.worker": index}
+
+    def mark(self, node_index: int) -> None:
+        """Mark the step that owns ``node_index`` dirty. The caller holds
+        ``lock`` where there is one."""
+        self.dirty.add(self.plan.pos_of[node_index])
+
+    def deliver(self, node_index: int, port: int, batch: DeltaBatch) -> None:
+        """Accept a batch that another thread routed here."""
+        with self.lock:
+            self.graph.nodes[node_index].accept(port, batch)
+            self.dirty.add(self.plan.pos_of[node_index])
+
+    def take_dirty(self) -> list[int] | None:
+        """The dirty positions in topological order, cleared; None when the
+        worker is quiescent (an O(1) check)."""
+        if self.lock is None:
+            return self._pop_dirty()
+        with self.lock:
+            return self._pop_dirty()
+
+    def _pop_dirty(self) -> list[int] | None:
+        dirty = self.dirty
+        if not dirty:
+            return None
+        heap = sorted(dirty)
+        dirty.clear()
+        return heap
+
+    def take_inputs(self, node: Node) -> list[DeltaBatch | None] | None:
+        """Drain ``node``'s pending input, or None when it has none."""
+        lock = self.lock
+        if lock is None:
+            return node.drain() if node.has_pending() else None
+        with lock:
+            return node.drain() if node.has_pending() else None
+
+
+class TickLoop:
+    """The tick loop, once, for the three runtimes: poll, sweep the dirty
+    steps to quiescence, frontier rounds, ``on_tick_complete``,
+    ``on_tick_done``.
+
+    The sweep is PLAN-driven (``engine/fusion.py``): fused chains execute as
+    single steps and idle nodes are never visited — routing marks the
+    consumer's step dirty, and a sweep drains the dirty set in topological
+    order (edges only point forward, so one drain reaches quiescence). The
+    poll/frontier/complete loops visit only nodes that override those hooks.
+
+    A runtime supplies what truly differs, by overriding: where a routed
+    batch goes (``_route``), which sources a worker polls (``_pollers``),
+    when a round of sweeps is over (``_round`` / ``_settle``) and when a
+    frontier round is (``_frontier_round``). The defaults are the
+    single-process answers: accept and mark, every source, a loop."""
+
+    #: a short-lived inner graph (iterate's body): keeps its ticks out of the
+    #: span ring and the request plane
+    transient = False
+    #: the process id a fault plan's ``corrupt_polled`` entries are keyed by
+    pid = 0
+
+    def __init__(self) -> None:
         self.current_time = 0
         self.on_tick_done: list[Callable[[int], None]] = []
         # live tracing (observability plane): None when PATHWAY_TRACE=off and
@@ -218,49 +288,49 @@ class Scheduler:
         # tick — the hot loops below pay exactly one is-not-None test per guard
         self.tracer = None
         self._tr = None
-        self.transient = transient
         # request-scoped tracing (observability/requests.py): the installed
         # plane while a request is in flight this tick, else None — sweep
         # steps pay one is-None test
         self._rp = None
-        from pathway_tpu.engine import fusion as _fusion
+        #: the workers this loop drives, in index order (set at build)
+        self._workers: list[Worker] = []
 
-        # transient = a short-lived inner graph rebuilt per use (iterate's
-        # fixed-point runner): chain fusion still applies, but the jitted
-        # segment tier is disabled — a fresh jax.jit per rebuild would
-        # re-trace its kernel every tick
-        self.plan = _fusion.build_plan(graph, exchange_aware=False, transient=transient)
-        # dirty step positions; during a sweep, forward marks go straight
-        # onto the active heap (all edges point forward, so a marked step is
-        # always still ahead of the cursor)
-        self._dirty: set[int] = set()
-        self._heap: list[int] | None = None
-
-    def _mark(self, pos: int) -> None:
-        h = self._heap
-        if h is not None:
-            heapq.heappush(h, pos)
+    # ---------------------------------------------------------------- routing
+    def _accept_local(self, worker: Worker, ci: int, port: int, batch: DeltaBatch) -> None:
+        """Same-worker accept from the worker's own thread: a mid-sweep mark
+        goes straight onto the active heap (all edges point forward, so the
+        marked step is still ahead of the cursor and runs in this sweep)."""
+        worker.graph.nodes[ci].accept(port, batch)
+        pos = worker.plan.pos_of[ci]
+        heap = worker.heap
+        if heap is not None:
+            heapq.heappush(heap, pos)
+        elif worker.lock is None:
+            worker.dirty.add(pos)
         else:
-            self._dirty.add(pos)
+            with worker.lock:
+                worker.dirty.add(pos)
 
-    def _route(self, producer: Node, batches: list[DeltaBatch]) -> bool:
+    def _route(self, worker: Worker, producer: Node, batches: list[DeltaBatch]) -> bool:
+        """Hand ``producer``'s emissions to its consumers; True if any row
+        went anywhere."""
         routed = False
-        consumers = self.graph.edges.get(producer.node_index, [])
-        plan = self.plan
+        consumers = worker.graph.edges.get(producer.node_index, ())
         for batch in batches:
             if batch is None or batch.is_empty:
                 continue
             producer.stats_rows_out += len(batch)
             for ci, port in consumers:
-                self.graph.nodes[ci].accept(port, batch)
-                if plan is not None:
-                    self._mark(plan.pos_of[ci])
+                self._accept_local(worker, ci, port, batch)
                 routed = True
         return routed
 
-    def _run_node(self, node: Node, time: int, aud) -> None:
+    # ------------------------------------------------------------------ sweep
+    def _run_node(self, worker: Worker, node: Node, time: int, aud) -> bool:
         """One node step: drain, process, span, route."""
-        inputs = node.drain()
+        inputs = worker.take_inputs(node)
+        if inputs is None:
+            return False
         rows_in = sum(len(b) for b in inputs if b is not None)
         node.stats_rows_in += rows_in
         tr, rp = self._tr, self._rp
@@ -275,60 +345,16 @@ class Scheduler:
         if tok is not None:
             _spans.step_end(
                 tok, time, rows_in, sum(len(b) for b in out if b is not None),
-                {"pathway.operator.id": node.node_index},
+                {"pathway.operator.id": node.node_index, **worker.span_attrs},
             )
         if aud is not None:
-            # audit plane: per-edge cardinality/selectivity counters
+            # audit plane: per-edge cardinality/selectivity counters (node
+            # instances are per-worker; the read side sums by position)
             aud.note_edge(node, inputs, out)
-        self._route(node, out)
+        self._route(worker, node, out)
+        return True
 
-    def _sweep_legacy(self, time: int, aud) -> bool:
-        """The r14 sweep: one full topo scan, one node per step. Active under
-        ``PATHWAY_FUSE=off`` (plan is None)."""
-        any_work = False
-        for node in self.graph.nodes:
-            if node.has_pending():
-                self._run_node(node, time, aud)
-                any_work = True
-        return any_work
-
-    def _sweep(self, time: int) -> bool:
-        """Drain the dirty steps in topo order; returns True if any step did
-        work. Quiescence check is O(1): an empty dirty set."""
-        aud = _audit.current()
-        # edge cardinality recording rides the audit plane's deterministic
-        # tick sample — unsampled ticks pay only this flag read
-        if aud is not None and not aud.edge_sampled:
-            aud = None
-        if self.plan is None:
-            return self._sweep_legacy(time, aud)
-        dirty = self._dirty
-        if not dirty:
-            return False
-        heap = sorted(dirty)
-        dirty.clear()
-        self._heap = heap
-        any_work = False
-        by_pos = self.plan.by_pos
-        last = -1
-        try:
-            while heap:
-                pos = heapq.heappop(heap)
-                if pos == last:
-                    continue  # duplicate marks collapse (ascending pops)
-                last = pos
-                step = by_pos[pos]
-                if step.chain is not None:
-                    if self._run_chain(step.chain, time, aud):
-                        any_work = True
-                elif step.node.has_pending():
-                    self._run_node(step.node, time, aud)
-                    any_work = True
-        finally:
-            self._heap = None
-        return any_work
-
-    def _run_chain(self, chain, time: int, aud) -> bool:
+    def _run_chain(self, worker: Worker, chain, time: int, aud) -> bool:
         """One fused-chain step: drain, hand off member to member, route the
         tail. The span is per CHAIN."""
         tr, rp = self._tr, self._rp
@@ -340,7 +366,7 @@ class Scheduler:
         t0 = _time.perf_counter_ns()
         ptok = _phases.start()
         try:
-            out, processed, rows_in, rows_out = chain.execute(time, None, aud)
+            out, processed, rows_in, rows_out = chain.execute(time, worker.lock, aud)
         finally:
             _phases.stop(ptok, "fused")
         if not processed:
@@ -353,12 +379,109 @@ class Scheduler:
                 tok, time, rows_in, rows_out,
                 {
                     "pathway.operator.id": chain.operator_ids(),
+                    **worker.span_attrs,
                     "pathway.chain.nodes": len(chain.members),
                 },
             )
-        self._route(chain.tail, out)
+        self._route(worker, chain.tail, out)
         return True
 
+    def _sweep(self, worker: Worker, time: int) -> bool:
+        """Drain ``worker``'s dirty steps in topo order; True if any step did
+        work."""
+        heap = worker.take_dirty()
+        if heap is None:
+            return False
+        aud = _audit.current()
+        # edge cardinality recording rides the audit plane's deterministic
+        # tick sample — unsampled ticks pay only this flag read
+        if aud is not None and not aud.edge_sampled:
+            aud = None
+        worker.heap = heap
+        any_work = False
+        by_pos = worker.plan.by_pos
+        last = -1
+        try:
+            while heap:
+                pos = heapq.heappop(heap)
+                if pos == last:
+                    continue  # duplicate marks collapse (ascending pops)
+                last = pos
+                step = by_pos[pos]
+                if step.chain is not None:
+                    if self._run_chain(worker, step.chain, time, aud):
+                        any_work = True
+                elif self._run_node(worker, step.node, time, aud):
+                    any_work = True
+        finally:
+            worker.heap = None
+        return any_work
+
+    def _sweep_all(self, time: int) -> bool:
+        """One sweep of every worker, concurrently where there are several.
+        A worker's exception (e.g. terminate_on_error aborting a batch) is
+        re-raised here, so the run fails loudly instead of dropping that
+        worker's batch."""
+        workers = self._workers
+        if len(workers) == 1:
+            return self._sweep(workers[0], time)
+        results: list[Any] = [False] * len(workers)
+
+        def target(i: int, w: Worker) -> None:
+            try:
+                results[i] = self._sweep(w, time)
+            except BaseException as e:  # noqa: BLE001 — transported to caller
+                results[i] = e
+
+        threads = [
+            threading.Thread(target=target, args=(i, w)) for i, w in enumerate(workers)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        for r in results:
+            if isinstance(r, BaseException):
+                raise r
+        return any(results)
+
+    # ----------------------------------------------------------------- rounds
+    def _pollers(self, worker: Worker) -> list[Node]:
+        """The sources ``worker`` polls this tick."""
+        return worker.plan.pollers
+
+    def _round(self, time: int) -> bool:
+        """One round of sweeps; True if it did any work."""
+        return self._sweep_all(time)
+
+    def _settle(self, time: int) -> bool:
+        """Rounds until nothing is pending anywhere; True if any did work."""
+        worked = False
+        while self._round(time):
+            worked = True
+        return worked
+
+    def _frontier_round(self, time: int) -> bool:
+        """Notify the frontier nodes in topo order (only nodes that override
+        ``on_frontier`` are visited); True if an emission re-entered the
+        tick, which then settles and goes round again."""
+        tr = self._tr
+        progressed = False
+        for worker in self._workers:
+            for node in worker.plan.frontier_nodes:
+                tok = tr.begin(f"frontier/{node.name}") if tr is not None else None
+                out = _run_annotated(node, node.on_frontier, time)
+                if tok is not None:
+                    tr.end(
+                        tok,
+                        {"pathway.rows_out": sum(len(b) for b in out), **worker.span_attrs},
+                        keep=bool(out),
+                    )
+                if self._route(worker, node, out):
+                    progressed = True
+        return progressed
+
+    # ------------------------------------------------------------------- tick
     def run_tick(self, time: int) -> None:
         """Process everything pending at logical ``time`` to quiescence, then
         advance the frontier past it."""
@@ -367,8 +490,7 @@ class Scheduler:
         # recorder's tick ring (two global reads when profiling is off)
         _device_prof.tick_hook(time)
         # span plane: one flag read brings a tracer up for the ticks of a
-        # profiler session (transient inner graphs — iterate bodies — keep
-        # their own tick numbering out of the ring)
+        # profiler session
         tracer = None
         if not self.transient:
             tracer = self.tracer = _obs.tick_tracer(self.tracer)
@@ -385,48 +507,38 @@ class Scheduler:
         aud = _audit.current()
         if aud is not None:
             aud.begin_tick(time)
-        plan = self.plan
         worked = False
-        pollers = self.graph.nodes if plan is None else plan.pollers
-        for node in pollers:
-            tok = tr.begin(f"tick/poll/{node.name}") if tr is not None else None
-            polled = _run_annotated(node, node.poll, time)
-            if polled:
-                worked = True
-                # fault plan (flip_diff/drop_retract) corrupts BEFORE the
-                # audit monitors observe — the tripwire sees exactly what the
-                # engine will
-                polled = _faults.corrupt_polled(0, time, polled)
-                if aud is not None:
-                    aud.observe_input(node, polled, time)
-            if tok is not None:
-                tr.end(tok, {"pathway.rows": sum(len(b) for b in polled)}, keep=bool(polled))
-            self._route(node, polled)
-        while self._sweep(time):
-            worked = True
-        # frontier phase: notify in topo order; emissions re-enter the same
-        # tick (only nodes that override on_frontier are visited)
-        frontier = self.graph.nodes if plan is None else plan.frontier_nodes
-        progressed = True
-        while progressed:
-            progressed = False
-            for node in frontier:
-                tok = tr.begin(f"frontier/{node.name}") if tr is not None else None
-                out = _run_annotated(node, node.on_frontier, time)
+        for worker in self._workers:
+            for node in self._pollers(worker):
+                tok = tr.begin(f"tick/poll/{node.name}") if tr is not None else None
+                polled = _run_annotated(node, node.poll, time)
+                if polled:
+                    worked = True
+                    # fault plan (flip_diff/drop_retract) corrupts BEFORE the
+                    # audit monitors observe — the tripwire sees exactly what
+                    # the engine will
+                    polled = _faults.corrupt_polled(self.pid, time, polled)
+                    if aud is not None:
+                        aud.observe_input(node, polled, time)
                 if tok is not None:
-                    tr.end(tok, {"pathway.rows_out": sum(len(b) for b in out)}, keep=bool(out))
-                if self._route(node, out):
-                    progressed = True
-            if progressed:
-                worked = True
-                while self._sweep(time):
-                    pass
+                    tr.end(
+                        tok,
+                        {"pathway.rows": sum(len(b) for b in polled), **worker.span_attrs},
+                        keep=bool(polled),
+                    )
+                self._route(worker, node, polled)
+        if self._settle(time):
+            worked = True
+        # frontier phase: emissions re-enter the same tick
+        while self._frontier_round(time):
+            worked = True
+            self._settle(time)
         if tr is not None and not worked:
             tr = None  # an idle tick: its wait is its whole record
-        complete = self.graph.nodes if plan is None else plan.tick_complete_nodes
         tok = tr.begin("tick/complete") if tr is not None else None
-        for node in complete:
-            _run_annotated(node, node.on_tick_complete, time)
+        for worker in self._workers:
+            for node in worker.plan.tick_complete_nodes:
+                _run_annotated(node, node.on_tick_complete, time)
         if tok is not None:
             tr.end(tok)
             tok = tr.begin("tick/done")
@@ -437,6 +549,23 @@ class Scheduler:
         if tick_tok is not None:
             self._tr = None
             tracer.end_tick(time, tick_tok, worked)
+
+
+class Scheduler(TickLoop):
+    """The single-process runtime's loop: one worker, no lock, no thread."""
+
+    def __init__(self, graph: EngineGraph, transient: bool = False):
+        super().__init__()
+        self.graph = graph
+        self.transient = transient
+        from pathway_tpu.engine import fusion as _fusion
+
+        # transient = a short-lived inner graph rebuilt per use (iterate's
+        # fixed-point runner): chain fusion still applies, but the jitted
+        # segment tier is disabled — a fresh jax.jit per rebuild would
+        # re-trace its kernel every tick
+        self.plan = _fusion.build_plan(graph, exchange_aware=False, transient=transient)
+        self._workers = [Worker(0, graph, self.plan)]
 
     def close(self) -> None:
         """Input exhausted: flush temporal buffers and fire end callbacks."""

@@ -226,22 +226,6 @@ class PathwayConfig:
         return _env_bool("PATHWAY_ENGINE_PHASES", False)
 
     @property
-    def fuse(self) -> str:
-        """Chain fusion (``engine/fusion.py``): lower maximal
-        single-consumer operator chains into one sweep step per chain —
-        batches hand off member to member in-process instead of paying the
-        per-node drain/route/accept dispatch, and runs of expression members
-        collapse into one composed block program. ``off`` restores the
-        one-node-per-step r14 sweep byte-for-byte. Default ``on``
-        (BENCH_r15: the small-tick dispatch win)."""
-        mode = os.environ.get("PATHWAY_FUSE", "on").strip().lower()
-        if mode in ("on", "1", "true"):
-            return "on"
-        if mode in ("off", "0", "false"):
-            return "off"
-        raise ValueError(f"PATHWAY_FUSE must be off/on, got {mode!r}")
-
-    @property
     def fuse_jax(self) -> str:
         """Jitted fused-chain kernels: lower a composed expression segment
         (whitelisted numeric filter/map chain) into ONE buffer-donating XLA
@@ -1199,7 +1183,6 @@ class PathwayConfig:
                 "device_exchange_fused",
                 "arrange_device_cache",
                 "arrange_donate",
-                "fuse",
                 "fuse_jax",
                 "fuse_jax_min_rows",
             )

@@ -33,7 +33,7 @@ MIN_SCORE = 0.05
 _STAGE_KNOBS = {
     "serve": "raise PATHWAY_SERVE_MAX_INFLIGHT or add doors (/scale)",
     "coalesce": "tune PATHWAY_SERVE_COALESCE_MS / PATHWAY_SERVE_COALESCE_ROWS",
-    "sweep": "profile the UDF / enable PATHWAY_FUSE whole-tick compilation",
+    "sweep": "profile the UDF of the step that holds the time (the tick's sweep/... spans name it)",
     "microbatch": "raise the microbatch window (serve_tick) so launches batch wider",
     "index": "check index tiering (PATHWAY_INDEX_HOT_ROWS) and replica serving",
     "respond": "raise PATHWAY_SERVE_COALESCE_ROWS so responses batch wider",
@@ -43,7 +43,7 @@ _STAGE_KNOBS = {
 _PHASE_KNOBS = {
     "kernel": "lower PATHWAY_FUSE_JAX_MIN_ROWS so more runs hit the jitted tier",
     "exchange": "enable PATHWAY_DEVICE_EXCHANGE_FUSED / check shard skew",
-    "consolidate": "enable PATHWAY_FUSE so chains consolidate once per tick",
+    "consolidate": "find the chain that holds the time (sweep/chain{...} spans); wider ticks consolidate less often",
     "rehash": "pre-sort inputs or raise tick size (fewer key-store compactions)",
     "probe": "raise tick size: probe cost amortizes over wider ticks",
     "groupby": "check group cardinality (PATHWAY_AUDIT cardinality gauges)",

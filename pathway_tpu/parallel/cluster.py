@@ -34,24 +34,16 @@ import threading
 import time as _time
 from typing import Any
 
-import numpy as np
-
 from pathway_tpu import observability as _obs
 from pathway_tpu.engine.blocks import DeltaBatch
 from pathway_tpu.engine import fusion as _fusion
-from pathway_tpu.engine.graph import BROADCAST, END_OF_STREAM, SOLO, Node
+from pathway_tpu.engine.graph import END_OF_STREAM, Node, Worker
 from pathway_tpu.internals.config import get_pathway_config
 from pathway_tpu.internals.errors import OtherWorkerError
 from pathway_tpu.internals.logical import BuildContext, LogicalNode
-from pathway_tpu.internals.trace import run_annotated
-from pathway_tpu.observability import audit as _audit
-from pathway_tpu.observability import engine_phases as _phases
 from pathway_tpu.observability import requests as _requests
-from pathway_tpu.observability import spans as _spans
-from pathway_tpu.parallel.mesh import shard_of_keys
+from pathway_tpu.parallel.sharded import ExchangeLoop
 from pathway_tpu.resilience import faults as _faults
-
-import heapq
 
 
 def cluster_env() -> tuple[int, int, int, int]:
@@ -415,26 +407,7 @@ class _CoordinatorClient:
             pass
 
 
-class _LocalWorker:
-    def __init__(self, global_index: int, graph):
-        self.index = global_index
-        self.graph = graph
-        self.lock = threading.Lock()
-        # fused-chain sweep plan (exchange-aware: see parallel/sharded.py)
-        self.plan = _fusion.build_plan(graph, exchange_aware=True)
-        #: dirty step positions, guarded by ``lock`` (marks arrive from peer
-        #: link reader threads and sibling worker threads)
-        self.dirty: set[int] = set()
-        #: the active sweep's forward-insertion heap (own thread only)
-        self.sweep_heap: list[int] | None = None
-
-    def mark_dirty_locked(self, node_index: int) -> None:
-        # no-op in legacy (PATHWAY_FUSE=off) mode: the full scan finds work
-        if self.plan is not None:
-            self.dirty.add(self.plan.pos_of[node_index])
-
-
-class ClusterRuntime:
+class ClusterRuntime(ExchangeLoop):
     """Sharded runtime spanning multiple processes.
 
     Worker ``w``'s graph exists only on its owning process; routing resolves the
@@ -448,6 +421,7 @@ class ClusterRuntime:
         monitoring_level: Any = None,
         autocommit_duration_ms: int | None = 20,
     ):
+        super().__init__()
         threads, processes, pid, first_port = cluster_env()
         self.threads = threads
         self.n_proc = processes
@@ -458,14 +432,13 @@ class ClusterRuntime:
         self.monitoring_level = monitoring_level
         self.connectors: list[Any] = []
         self.persistence: Any = None
-        self.on_tick_done: list[Any] = []
         self._stop_requested = False
+        self._skip_poll = False  # this tick is a drop_poll fault's
         # elasticity plane (PATHWAY_ELASTIC): set when the continuation
         # barrier broadcast carries a rescale decision — the pod quiesces to
         # one final committed epoch and exits with the rescale status
         self._rescale_decision: dict | None = None
         self.streaming = False  # set after build (see engine.runtime.Runtime)
-        self.current_time = 0
         # arrival-driven tick scheduling: the coordinator (pid 0) owns the
         # inter-tick sleep, so REST wakeups there drive the whole pod
         from pathway_tpu.engine.runtime import TickWakeup
@@ -477,13 +450,7 @@ class ClusterRuntime:
         # version rides the membership version).
         self.shardmap = None
         self._shardmap_prev = None
-        # live tracing (observability): installed in run(), None when off
-        self.tracer = None
-        self._tr = None  # the tracer during a sampled tick
-        # request-scoped tracing: the plane while a request is in flight this
-        # tick, else None (see engine.graph.Scheduler)
-        self._rp = None
-        self.local_workers: dict[int, _LocalWorker] = {}
+        self.local_workers: dict[int, Worker] = {}
         # set once every local worker graph exists: a faster peer's first
         # blocks can arrive while this process is still building
         self._built = threading.Event()
@@ -551,203 +518,26 @@ class ClusterRuntime:
                 self._ctx0 = ctx
             self._ctx_local = ctx  # any local context (non-0 processes have no
             # global worker 0; persistence reads only the graph shape from it)
-            self.local_workers[w] = _LocalWorker(w, ctx.graph)
+            # exchange-aware plan (see parallel/sharded.py); the lock guards
+            # marks from peer-link readers and sibling worker threads
+            plan = _fusion.build_plan(ctx.graph, exchange_aware=True)
+            self.local_workers[w] = Worker(w, ctx.graph, plan, threading.Lock())
+        self._workers = [self.local_workers[w] for w in my_workers]
         self._built.set()
 
     # ---------------------------------------------------------------- routing
     def _on_remote_block(self, worker: int, node_index: int, port: int, batch: DeltaBatch) -> None:
         self._built.wait()
-        lw = self.local_workers[worker]
-        with lw.lock:
-            lw.graph.nodes[node_index].accept(port, batch)
-            lw.mark_dirty_locked(node_index)
+        self.local_workers[worker].deliver(node_index, port, batch)
 
     def _deliver(self, worker: int, node_index: int, port: int, batch: DeltaBatch) -> None:
         owner = self.owner_of(worker)
         if owner == self.pid:
-            lw = self.local_workers[worker]
-            with lw.lock:
-                lw.graph.nodes[node_index].accept(port, batch)
-                lw.mark_dirty_locked(node_index)
+            self.local_workers[worker].deliver(node_index, port, batch)
         else:
             self.links.send_block(owner, worker, node_index, port, batch)
 
-    def _accept_local(self, lw: _LocalWorker, ci: int, port: int, batch) -> None:
-        """Same-worker accept from the worker's own thread (see
-        parallel/sharded.py: a mid-sweep mark rides the active heap)."""
-        lw.graph.nodes[ci].accept(port, batch)
-        if lw.plan is None:
-            return  # legacy mode: the full scan finds it
-        h = lw.sweep_heap
-        if h is not None:
-            heapq.heappush(h, lw.plan.pos_of[ci])
-        else:
-            with lw.lock:
-                lw.mark_dirty_locked(ci)
-
-    def _route(self, lw: _LocalWorker, producer: Node, batches: list[DeltaBatch]) -> bool:
-        routed = False
-        consumers = lw.graph.edges.get(producer.node_index, [])
-        for batch in batches:
-            if batch is None or batch.is_empty:
-                continue
-            producer.stats_rows_out += len(batch)
-            for ci, port in consumers:
-                consumer = lw.graph.nodes[ci]
-                key_fn = consumer.exchange_key(port)
-                if key_fn is None:
-                    self._accept_local(lw, ci, port, batch)
-                elif key_fn == SOLO:
-                    self._deliver(0, ci, port, batch)
-                elif key_fn == BROADCAST:
-                    for w_idx in range(self.n_workers):
-                        self._deliver(w_idx, ci, port, batch)
-                else:
-                    route_keys = np.asarray(key_fn(batch), dtype=np.uint64)
-                    if (
-                        self.device_plane is not None
-                        and self.device_plane.should_stage(batch)
-                    ):
-                        self.device_plane.stage(
-                            ci, port, lw.index, route_keys, batch
-                        )
-                        routed = True
-                        continue
-                    shards = shard_of_keys(
-                        route_keys, self.n_workers, shard_map=self.shardmap
-                    )
-                    for w_idx in np.unique(shards):
-                        piece = batch.take(np.flatnonzero(shards == w_idx))
-                        self._deliver(int(w_idx), ci, port, piece)
-                routed = True
-        return routed
-
     # ---------------------------------------------------------------- ticking
-    def _run_node(self, lw: _LocalWorker, node: Node, inputs, time: int, aud) -> None:
-        """One node step on this local worker: process, span, route (the
-        caller drained ``inputs`` under the worker's lock)."""
-        rows_in = sum(len(b) for b in inputs if b is not None)
-        node.stats_rows_in += rows_in
-        tr, rp = self._tr, self._rp
-        tok = (
-            _spans.step_begin(tr, rp, f"sweep/{node.name}")
-            if tr is not None or rp is not None
-            else None
-        )
-        out = run_annotated(node, node.process, inputs, time)
-        if tok is not None:
-            _spans.step_end(
-                tok, time, rows_in, sum(len(b) for b in out if b is not None),
-                {"pathway.operator.id": node.node_index, "pathway.worker": lw.index},
-            )
-        if aud is not None:
-            aud.note_edge(node, inputs, out)
-        self._route(lw, node, out)
-
-    def _sweep_worker_legacy(self, lw: _LocalWorker, time: int, aud) -> bool:
-        """The r14 per-worker sweep (PATHWAY_FUSE=off)."""
-        any_work = False
-        for node in lw.graph.nodes:
-            with lw.lock:
-                if not node.has_pending():
-                    continue
-                inputs = node.drain()
-            self._run_node(lw, node, inputs, time, aud)
-            any_work = True
-        return any_work
-
-    def _sweep_worker(self, lw: _LocalWorker, time: int) -> bool:
-        aud = _audit.current()
-        if aud is not None and not aud.edge_sampled:
-            aud = None
-        if lw.plan is None:
-            return self._sweep_worker_legacy(lw, time, aud)
-        with lw.lock:
-            if not lw.dirty:
-                return False
-            heap = sorted(lw.dirty)
-            lw.dirty.clear()
-        lw.sweep_heap = heap
-        any_work = False
-        by_pos = lw.plan.by_pos
-        last = -1
-        try:
-            while heap:
-                pos = heapq.heappop(heap)
-                if pos == last:
-                    continue
-                last = pos
-                step = by_pos[pos]
-                if step.chain is not None:
-                    if self._run_chain(lw, step.chain, time, aud):
-                        any_work = True
-                    continue
-                node = step.node
-                with lw.lock:
-                    if not node.has_pending():
-                        continue
-                    inputs = node.drain()
-                self._run_node(lw, node, inputs, time, aud)
-                any_work = True
-        finally:
-            lw.sweep_heap = None
-        return any_work
-
-    def _run_chain(self, lw: _LocalWorker, chain, time: int, aud) -> bool:
-        """One fused-chain step (see Scheduler._run_chain)."""
-        tr, rp = self._tr, self._rp
-        tok = (
-            _spans.step_begin(tr, rp, f"sweep/chain{{{chain.label}}}")
-            if tr is not None or rp is not None
-            else None
-        )
-        t0 = _time.perf_counter_ns()
-        ptok = _phases.start()
-        try:
-            out, processed, rows_in, rows_out = chain.execute(time, lw.lock, aud)
-        finally:
-            _phases.stop(ptok, "fused")
-        if not processed:
-            if tok is not None:
-                _spans.step_drop(tok)
-            return False
-        chain.tail.stats_time_ns += _time.perf_counter_ns() - t0
-        if tok is not None:
-            _spans.step_end(
-                tok, time, rows_in, rows_out,
-                {
-                    "pathway.operator.id": chain.operator_ids(),
-                    "pathway.worker": lw.index,
-                    "pathway.chain.nodes": len(chain.members),
-                },
-            )
-        self._route(lw, chain.tail, out)
-        return True
-
-    def _sweep_all_local(self, time: int) -> bool:
-        workers = list(self.local_workers.values())
-        if len(workers) == 1:
-            did = False
-            while self._sweep_worker(workers[0], time):
-                did = True
-            return did
-        did_any = False
-        while True:
-            results = [False] * len(workers)
-            threads = []
-            for i, lw in enumerate(workers):
-                def target(i=i, lw=lw):
-                    results[i] = self._sweep_worker(lw, time)
-
-                t = threading.Thread(target=target)
-                t.start()
-                threads.append(t)
-            for t in threads:
-                t.join()
-            if not any(results):
-                return did_any
-            did_any = True
-
     def _barrier(self, report: Any, decide) -> Any:
         _faults.before_barrier(self.pid, self.current_time)
         phase = report[0] if isinstance(report, tuple) and report else "barrier"
@@ -805,12 +595,15 @@ class ClusterRuntime:
             rp.wire_apply(decision.get("__rt_bc__"))
         return decision
 
-    def _round_until_quiescent(self, time: int, phase: str) -> None:
+    def _settle(self, time: int) -> bool:
         """Sweep-report rounds until globally quiescent (no work anywhere and
-        all in-flight messages delivered)."""
+        all in-flight messages delivered); True if this process did work."""
+        worked = False
         while True:
             self.links.check_error()
-            did = self._sweep_all_local(time)
+            did = False
+            while self._sweep_all(time):
+                did = True
             if self.device_plane is not None and self.device_plane.flush(
                 self._deliver, time
             ):
@@ -820,14 +613,13 @@ class ClusterRuntime:
             # sweep and here is visible either as sent>recv or as pending.
             # Pending nodes are re-marked dirty (idempotent) so the plan
             # sweep can never strand a buffered block.
-            pending = False
-            for lw in self.local_workers.values():
+            for lw in self._workers:
                 for node in lw.graph.nodes:
                     if node.has_pending():
-                        pending = True
+                        did = True
                         with lw.lock:
-                            lw.mark_dirty_locked(node.node_index)
-            report = (phase, did or pending, sent, received)
+                            lw.mark(node.node_index)
+            worked = worked or did
 
             def decide(reports):
                 any_work = any(r[1] for r in reports)
@@ -835,9 +627,8 @@ class ClusterRuntime:
                 total_recv = sum(r[3] for r in reports)
                 return {"again": any_work or total_sent != total_recv}
 
-            decision = self._barrier(report, decide)
-            if not decision["again"]:
-                return
+            if not self._barrier(("sweep", did, sent, received), decide)["again"]:
+                return worked
 
     def _sync_watermarks(self) -> None:
         """Cross-process watermark gossip (the reference's frontier broadcast
@@ -879,85 +670,37 @@ class ClusterRuntime:
                     if node._shared.tick_max is None or tm > node._shared.tick_max:
                         node._shared.tick_max = tm
 
-    def run_tick(self, time: int, skip_poll: bool = False) -> None:
-        self.current_time = time
-        from pathway_tpu.observability import device as _dev_prof
-
-        _dev_prof.tick_hook(time)
-        tracer = self.tracer = _obs.tick_tracer(self.tracer)
-        tick_token = tracer.begin_tick(time) if tracer is not None else None
-        self._tr = tracer if tick_token is not None else None
-        rp = _requests.current()
-        if rp is not None and (not rp.hot or time == END_OF_STREAM):
-            rp = None
-        self._rp = rp
-        if rp is not None:
-            rp.note_tick(time)
-        if self.hb_client is not None:
-            self.hb_client.tick = time
+    def _pollers(self, lw: Worker) -> list[Node]:
         # non-partitioned sources poll on global worker 0 only; partitioned
         # sources (local_source, r5) poll on every owning worker — including
-        # workers hosted by peer processes. ``skip_poll`` is the drop_poll
-        # fault-injection point: buffered events stay upstream for this tick.
-        aud = _audit.current()
-        if aud is not None:
-            aud.begin_tick(time)
+        # workers hosted by peer processes — and so do fabric_ingest nodes:
+        # zero-hop doors push REST rows into THIS process's copy of the route
+        # input node. ``_skip_poll`` is the drop_poll fault-injection point:
+        # buffered events stay upstream for this tick.
+        if self._skip_poll:
+            return []
+        if lw.index == 0:
+            return lw.plan.pollers
+        return [
+            n
+            for n in lw.plan.pollers
+            if getattr(n, "local_source", False) or getattr(n, "fabric_ingest", False)
+        ]
 
-        def _polled(node):
-            polled = run_annotated(node, node.poll, time)
-            if polled:
-                # corruption faults (flip_diff/drop_retract) apply before the
-                # audit monitors observe, keyed by THIS process id
-                polled = _faults.corrupt_polled(self.pid, time, polled)
-                if aud is not None:
-                    aud.observe_input(node, polled, time)
-            return polled
+    def _frontier_round(self, time: int) -> bool:
+        self._sync_watermarks()
+        progressed = super()._frontier_round(time)
 
-        def _nodes(lw, kind):
-            if lw.plan is None:
-                return lw.graph.nodes
-            return getattr(lw.plan, kind)
+        def decide(reports):
+            return {"again": any(r[1] for r in reports)}
 
-        if not skip_poll and 0 in self.local_workers:
-            lw0 = self.local_workers[0]
-            for node in _nodes(lw0, "pollers"):
-                self._route(lw0, node, _polled(node))
-        if not skip_poll:
-            for gi, lw in self.local_workers.items():
-                if gi == 0:
-                    continue
-                for node in _nodes(lw, "pollers"):
-                    if getattr(node, "local_source", False) or getattr(
-                        node, "fabric_ingest", False
-                    ):
-                        # fabric_ingest: zero-hop doors push REST rows into
-                        # THIS process's copy of the route input node, so
-                        # peers must poll it like a partitioned source
-                        self._route(lw, node, _polled(node))
-        self._round_until_quiescent(time, "sweep")
-        while True:
-            self._sync_watermarks()
-            progressed = False
-            for lw in self.local_workers.values():
-                for node in _nodes(lw, "frontier_nodes"):
-                    if self._route(lw, node, run_annotated(node, node.on_frontier, time)):
-                        progressed = True
+        return self._barrier(("frontier", progressed, 0, 0), decide)["again"]
 
-            def decide(reports):
-                return {"again": any(r[1] for r in reports)}
-
-            decision = self._barrier(("frontier", progressed, 0, 0), decide)
-            if not decision["again"]:
-                break
-            self._round_until_quiescent(time, "sweep")
-        for lw in self.local_workers.values():
-            for node in _nodes(lw, "tick_complete_nodes"):
-                run_annotated(node, node.on_tick_complete, time)
-        for cb in self.on_tick_done:
-            cb(time)
-        if tick_token is not None:
-            self._tr = None
-            tracer.end_tick(time, tick_token)
+    def run_tick(self, time: int, skip_poll: bool = False) -> None:
+        self._skip_poll = skip_poll
+        if self.hb_client is not None:
+            self.hb_client.tick = time
+        super().run_tick(time)
 
     def _peer_flows(self) -> dict[int, dict]:
         """pid → flow-plane gate summary from each peer's heartbeats (empty

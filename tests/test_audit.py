@@ -9,7 +9,6 @@ from __future__ import annotations
 import glob
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -24,6 +23,7 @@ from pathway_tpu.internals.monitoring import prometheus_text, run_stats
 from pathway_tpu.internals.parse_graph import G
 from pathway_tpu.internals.run import current_runtime
 from pathway_tpu.observability import audit as audit_mod
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -260,24 +260,6 @@ _CLUSTER_SCRIPT = textwrap.dedent(
 )
 
 
-def _free_port_base(n: int) -> int:
-    for base in range(24100, 60000, 103):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 def test_flip_diff_detected_on_2proc_cluster(tmp_path):
     script = tmp_path / "pipeline.py"
     script.write_text(_CLUSTER_SCRIPT)
@@ -288,7 +270,7 @@ def test_flip_diff_detected_on_2proc_cluster(tmp_path):
         PATHWAY_PROCESSES="2",
         PATHWAY_THREADS="1",
         PATHWAY_PROCESS_ID="0",
-        PATHWAY_FIRST_PORT=str(_free_port_base(3)),
+        PATHWAY_FIRST_PORT=str(free_port_base(3)),
         PATHWAY_BARRIER_TIMEOUT="45",
         PATHWAY_AUDIT="on",
         PATHWAY_FAULT_PLAN="flip_diff:proc=0,tick=2",

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -31,6 +30,7 @@ from pathway_tpu.delivery import read_committed
 from pathway_tpu.io._pg_fake import FakePostgres
 from pathway_tpu.io.kafka import MockKafkaBroker
 from pathway_tpu.resilience.supervisor import Supervisor
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -216,24 +216,6 @@ def _base_env(extra: dict[str, str]) -> dict[str, str]:
     return env
 
 
-def _free_port_base(n: int) -> int:
-    base = 28700
-    while True:
-        try:
-            socks = []
-            for i in range(n):
-                s = socket.socket()
-                s.bind(("127.0.0.1", base + i))
-                socks.append(s)
-            for s in socks:
-                s.close()
-            return base
-        except OSError:
-            for s in socks:
-                s.close()
-            base += n + 3
-
-
 # ----------------------------------------------------------- observations --
 def _kafka_net(broker_path: str) -> tuple[dict[str, tuple[int, int]], dict]:
     """Committed-read consumer view folded to net state: what a downstream
@@ -324,7 +306,7 @@ def test_cdc_roundtrip_survives_kill(tmp_path, truth, point):
         [sys.executable, script],
         processes=1,
         threads=1,
-        first_port=_free_port_base(1),
+        first_port=free_port_base(1),
         max_restarts=3,
         backoff_s=0.05,
         env=_base_env(env),
@@ -341,7 +323,7 @@ def test_cdc_roundtrip_survives_kill(tmp_path, truth, point):
 
 # ------------------------------------------------------------ rescale leg --
 def _run_cluster(script: str, n: int, env_extra: dict[str, str]) -> None:
-    base = _free_port_base(n)
+    base = free_port_base(n)
     procs = []
     for pid in range(n):
         env = _base_env(env_extra)

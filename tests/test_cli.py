@@ -8,7 +8,8 @@ import subprocess
 import sys
 import textwrap
 
-from test_cluster import _PIPELINE, _free_port_base
+from conftest import free_port_base
+from test_cluster import _PIPELINE
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -33,7 +34,7 @@ def test_spawn_multiprocess_matches_solo(tmp_path):
     assert r.returncode == 0, r.stdout + r.stderr
     dist = str(tmp_path / "dist")
     r = _cli(
-        ["spawn", "-t", "2", "-n", "2", "--first-port", str(_free_port_base(2)),
+        ["spawn", "-t", "2", "-n", "2", "--first-port", str(free_port_base(2)),
          sys.executable, str(script), dist],
     )
     assert r.returncode == 0, r.stdout + r.stderr

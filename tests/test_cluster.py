@@ -6,12 +6,12 @@ ports with a per-test port dispenser; ``cli.py:167`` spawn semantics)."""
 from __future__ import annotations
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
 
 import pytest
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -61,25 +61,6 @@ _PIPELINE = textwrap.dedent(
 )
 
 
-def _free_port_base(n: int) -> int:
-    """Reserve a base port such that base..base+n are free right now."""
-    for base in range(23000, 60000, 101):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 def _run_cluster(script_path: str, out: str, *, processes: int, threads: int, timeout=120):
     env = dict(os.environ)
     env.update(
@@ -92,7 +73,7 @@ def _run_cluster(script_path: str, out: str, *, processes: int, threads: int, ti
     if processes > 1:
         # the cluster occupies [first_port, first_port + processes + 1]
         # (coordinator, peer links, heartbeat monitor)
-        env["PATHWAY_FIRST_PORT"] = str(_free_port_base(processes + 1))
+        env["PATHWAY_FIRST_PORT"] = str(free_port_base(processes + 1))
     procs = []
     for pid in range(processes):
         penv = dict(env, PATHWAY_PROCESS_ID=str(pid))
@@ -165,7 +146,7 @@ def test_cluster_dead_peer_raises_other_worker_error(pipeline_script, tmp_path):
         PATHWAY_PROCESSES="2",
         PATHWAY_THREADS="1",
         PATHWAY_PROCESS_ID="0",
-        PATHWAY_FIRST_PORT=str(_free_port_base(3)),
+        PATHWAY_FIRST_PORT=str(free_port_base(3)),
         PATHWAY_BARRIER_TIMEOUT="3",
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO,

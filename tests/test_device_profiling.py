@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -29,6 +28,7 @@ from pathway_tpu.internals.monitoring import (
 from pathway_tpu.internals.parse_graph import G
 from pathway_tpu.observability import device
 from pathway_tpu.ops.microbatch import MicrobatchDispatcher
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -413,24 +413,6 @@ def test_heartbeat_summary_merges_across_peers():
 # ------------------------------------------------- cluster flight dump (slow)
 
 
-def _free_port_base(n: int) -> int:
-    for base in range(24700, 60000, 107):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 _STREAMING_PIPELINE = textwrap.dedent(
     """
     import time
@@ -472,7 +454,7 @@ def test_flight_dump_names_failed_proc_and_tick_on_cluster_kill(tmp_path):
         JAX_PLATFORMS="cpu",
         PATHWAY_PROCESSES="2",
         PATHWAY_THREADS="1",
-        PATHWAY_FIRST_PORT=str(_free_port_base(3)),
+        PATHWAY_FIRST_PORT=str(free_port_base(3)),
         PATHWAY_BARRIER_TIMEOUT="60",
         PATHWAY_FAULT_PLAN="kill:proc=1,tick=10",
         PATHWAY_FLIGHT_DIR=str(flight),

@@ -36,6 +36,7 @@ from pathway_tpu.internals.config import get_pathway_config
 from pathway_tpu.internals.parse_graph import G
 from pathway_tpu.persistence.backends import FileBackend, MemoryBackend
 from pathway_tpu.resilience import Supervisor, heartbeat, supervisor as supervisor_mod
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -844,24 +845,6 @@ def test_elastic_off_still_refuses_worker_count_change(monkeypatch):
 # --------------------------------------------------- slow: cluster join/drain
 
 
-def _free_port_base(n: int) -> int:
-    for base in range(27400, 60000, 113):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 _RAG_PIPELINE = textwrap.dedent(
     """
     import os
@@ -996,7 +979,7 @@ def test_elastic_join_and_drain_zero_loss(tmp_path):
         [sys.executable, str(script), out],
         processes=2,
         threads=1,
-        first_port=_free_port_base(5),
+        first_port=free_port_base(5),
         max_restarts=1,
         backoff_s=0.2,
         env=env,
@@ -1020,7 +1003,7 @@ def test_elastic_join_and_drain_zero_loss(tmp_path):
         [sys.executable, str(script), out_c],
         processes=2,
         threads=1,
-        first_port=_free_port_base(5),
+        first_port=free_port_base(5),
         max_restarts=0,
         backoff_s=0.2,
         env=env_c,
@@ -1159,7 +1142,7 @@ def test_autoscale_flood_joins_then_idle_drains(tmp_path):
         [sys.executable, str(script), str(tmp_path / "out")],
         processes=2,
         threads=1,
-        first_port=_free_port_base(5),
+        first_port=free_port_base(5),
         max_restarts=1,
         backoff_s=0.2,
         env=env,

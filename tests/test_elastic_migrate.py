@@ -20,7 +20,6 @@ from __future__ import annotations
 import json
 import os
 import pickle
-import socket
 import subprocess
 import sys
 import textwrap
@@ -33,29 +32,12 @@ from pathway_tpu.elastic import adopt_orphan_suffixes
 from pathway_tpu.elastic.reshard import _read_log_suffix
 from pathway_tpu.internals import telemetry
 from pathway_tpu.persistence.backends import FileBackend, MemoryBackend
+from conftest import free_port_base
 
 REPO = str(Path(__file__).resolve().parent.parent)
 
 
 # ------------------------------------------------------------ cluster harness
-
-
-def _free_port_base(n: int) -> int:
-    for base in range(28400, 60000, 127):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
 
 
 _MIGRATE_SCRIPT = textwrap.dedent(
@@ -159,7 +141,7 @@ def _run_session(script, n_proc, store, rows, expected_total, timeout=150):
         PATHWAY_PROCESSES=str(n_proc),
         PATHWAY_THREADS="1",
         PATHWAY_BARRIER_TIMEOUT="60",
-        PATHWAY_FIRST_PORT=str(_free_port_base(2 * n_proc + 2)),
+        PATHWAY_FIRST_PORT=str(free_port_base(2 * n_proc + 2)),
         PATHWAY_ELASTIC="manual",
         PATHWAY_SHARDMAP="on",
         PATHWAY_PERSISTENT_STORAGE=str(store),

@@ -25,6 +25,7 @@ import urllib.error
 import urllib.request
 
 import pytest
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -35,26 +36,6 @@ def _free_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
-
-
-def _free_port_base(n: int) -> int:
-    """A run of n+1 consecutive free ports (cluster barrier/links/heartbeat/
-    fabric bands)."""
-    for base in range(24000, 60000, 131):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
 
 
 def _wait_ready(port: int, timeout: float = 40.0) -> None:
@@ -185,7 +166,7 @@ def test_replica_store_apply_lag_and_snapshot():
 def test_fabric_transport_rpc_and_cast():
     from pathway_tpu.fabric.transport import FabricNode, FabricUnavailable
 
-    first_port = _free_port_base(7)
+    first_port = free_port_base(7)
     n0 = FabricNode(0, 2, first_port)
     n1 = FabricNode(1, 2, first_port)
     got_casts: list = []
@@ -457,7 +438,7 @@ def _run_cluster(script_path, http_port, n_proc, extra_env, timeout=180, first_p
         PATHWAY_THREADS="1",
         PATHWAY_BARRIER_TIMEOUT="60",
         PATHWAY_FIRST_PORT=str(
-            first_port if first_port is not None else _free_port_base(2 * n_proc + 2)
+            first_port if first_port is not None else free_port_base(2 * n_proc + 2)
         ),
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO,
@@ -508,7 +489,7 @@ def test_fabric_three_process_byte_identity_and_trace_stitch(tmp_path):
     script.write_text(_RETRIEVE_SCRIPT)
     # one contiguous block: monitoring ports first, cluster bands after —
     # two independent scans would find the SAME free range and collide
-    block = _free_port_base(4 + 9)
+    block = free_port_base(4 + 9)
     mon_base = block
     http_port = _free_port()
     fabric = _run_cluster(
@@ -665,7 +646,7 @@ def test_fabric_replica_staleness_bound_under_churn(tmp_path):
     pathway_fabric_replica_lag_seconds on its own /metrics."""
     script = tmp_path / "replica.py"
     script.write_text(_REPLICA_SCRIPT)
-    block = _free_port_base(3 + 7)  # monitoring ports + cluster bands, disjoint
+    block = free_port_base(3 + 7)  # monitoring ports + cluster bands, disjoint
     mon_base = block
     result = _run_cluster(
         script,
@@ -739,7 +720,7 @@ def test_fabric_front_door_sigkill_supervisor_reforms(tmp_path):
     script.write_text(_SUPERVISED_SCRIPT)
     stop_file = tmp_path / "stop"
     http_port = _free_port()
-    first_port = _free_port_base(6)
+    first_port = free_port_base(6)
     env = dict(os.environ)
     env.update(
         PATHWAY_FABRIC="on",

@@ -16,7 +16,8 @@ from __future__ import annotations
 import json
 import textwrap
 
-from tests.test_fabric import _free_port, _free_port_base, _run_cluster
+from conftest import free_port_base
+from tests.test_fabric import _free_port, _run_cluster
 
 _ECHO_SCRIPT = textwrap.dedent(
     """
@@ -102,7 +103,7 @@ def test_shardmap_zero_hop_three_doors_byte_identity(tmp_path):
     byte-identical bodies, owner-stamped headers, zero forwards pod-wide."""
     script = tmp_path / "echo.py"
     script.write_text(_ECHO_SCRIPT)
-    block = _free_port_base(4 + 9)
+    block = free_port_base(4 + 9)
     mon_base = block
     fabric = _run_cluster(
         script,

@@ -42,6 +42,7 @@ import pytest
 import pathway_tpu as pw
 from pathway_tpu.observability import alerts as alerts_mod
 from pathway_tpu.observability import health as health_mod
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -74,24 +75,6 @@ def _free_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
-
-
-def _free_port_base(n: int) -> int:
-    for base in range(29100, 60000, 149):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
 
 
 def _wait_ready(port: int, timeout: float = 40.0) -> None:
@@ -991,7 +974,7 @@ def _spawn_cluster(script_path, argv_tail, n_proc, extra_env, timeout=240,
         PATHWAY_THREADS="1",
         PATHWAY_BARRIER_TIMEOUT="60",
         PATHWAY_FIRST_PORT=str(
-            first_port if first_port is not None else _free_port_base(2 * n_proc + 2)
+            first_port if first_port is not None else free_port_base(2 * n_proc + 2)
         ),
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO,
@@ -1044,7 +1027,7 @@ def test_cluster_replica_gap_flips_readyz_to_syncing_and_back(tmp_path):
     and the coordinator /status rolls every door's state up pod-wide."""
     script = tmp_path / "gap_cluster.py"
     script.write_text(_GAP_CLUSTER_SCRIPT)
-    block = _free_port_base(3 + 7)
+    block = free_port_base(3 + 7)
     mon_base = block
     http_port = _free_port()
     procs, outputs = _spawn_cluster(
@@ -1200,7 +1183,7 @@ def test_cluster_scale_drains_every_door_before_pause(tmp_path):
     broker.create_topic("words", partitions=2)
     for i in range(8):
         broker.produce("words", f"w{i}", partition=i % 2)
-    block = _free_port_base(3 + 7)
+    block = free_port_base(3 + 7)
     mon_base = block
     http_port = _free_port()
     pstore = str(tmp_path / "pstore")
@@ -1310,7 +1293,7 @@ def test_sigkill_supervisor_relaunch_reenters_starting(tmp_path):
     script.write_text(_SUPERVISED_HEALTH_SCRIPT)
     stop_file = tmp_path / "stop"
     http_port = _free_port()
-    block = _free_port_base(3 + 7)
+    block = free_port_base(3 + 7)
     mon_base = block
     env = dict(os.environ)
     env.update(

@@ -434,30 +434,34 @@ def _net_csv(text: str) -> dict:
 
 
 def _run_cluster_with_mode(
-    script: str, out: str, mode: str, processes: int, extra_env: dict | None = None
+    script: str,
+    out: str,
+    mode: str,
+    processes: int,
+    script_args: tuple = (),
+    threads: int = 1,
 ):
     import subprocess
 
-    from test_cluster import REPO, _free_port_base
+    from conftest import free_port_base
+    from test_cluster import REPO
 
     env = dict(os.environ)
     env.update(
         PATHWAY_PROCESSES=str(processes),
-        PATHWAY_THREADS="1",
+        PATHWAY_THREADS=str(threads),
         PATHWAY_BARRIER_TIMEOUT="45",
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO,
     )
-    if extra_env:
-        env.update(extra_env)
     if processes > 1:
-        env["PATHWAY_FIRST_PORT"] = str(_free_port_base(processes + 1))
+        env["PATHWAY_FIRST_PORT"] = str(free_port_base(processes + 1))
     procs = []
     for pid in range(processes):
         penv = dict(env, PATHWAY_PROCESS_ID=str(pid))
         procs.append(
             subprocess.Popen(
-                [sys.executable, script, out, mode],
+                [sys.executable, script, out, mode, *script_args],
                 env=penv,
                 stdout=subprocess.PIPE,
                 stderr=subprocess.STDOUT,
@@ -470,36 +474,51 @@ def _run_cluster_with_mode(
 
 
 # ------------------------------------------------- r15 fused tick kernels
+# The reference the fused stream is held to is the SAME loop over a plan
+# with no chains (every node its own step): ``build_plan(..., fuse=False)``,
+# an argument only these tests pass.
 
 
-def _deltas_with_fuse(monkeypatch, fuse: str, incremental: bool = True):
+def _no_chains(monkeypatch):
+    """Every plan built from here on has no chains (undone at test exit, or
+    by the ``monkeypatch.context()`` the caller holds)."""
+    import functools
+
+    from pathway_tpu.engine import fusion
+
+    monkeypatch.setattr(
+        fusion, "build_plan", functools.partial(fusion.build_plan, fuse=False)
+    )
+
+
+def _deltas_with_fuse(monkeypatch, fuse: bool, incremental: bool = True):
     from utils import deltas_of
 
-    monkeypatch.setenv("PATHWAY_FUSE", fuse)
-    try:
+    with monkeypatch.context() as m:
+        if not fuse:
+            _no_chains(m)
         return deltas_of(_identity_pipeline(incremental=incremental))
-    finally:
-        monkeypatch.delenv("PATHWAY_FUSE", raising=False)
 
 
 def test_fused_vs_unfused_byte_identity_thread(monkeypatch):
     """The r15 acceptance bar on the thread runtime: the RAW per-tick delta
     stream (not just the net state) of the benched filter+join+groupby
-    pipeline with retractions is byte-identical with chains fused vs the
-    verbatim r14 sweep, for both the incremental and static runs."""
+    pipeline with retractions is byte-identical with chains fused vs one
+    node per step, for both the incremental and static runs."""
     for incremental in (True, False):
-        fused = _deltas_with_fuse(monkeypatch, "on", incremental)
-        legacy = _deltas_with_fuse(monkeypatch, "off", incremental)
-        assert fused == legacy
+        fused = _deltas_with_fuse(monkeypatch, True, incremental)
+        unfused = _deltas_with_fuse(monkeypatch, False, incremental)
+        assert fused == unfused
 
 
 def test_fused_vs_unfused_byte_identity_sharded_2_workers(monkeypatch):
     from pathway_tpu.internals.logical import LogicalNode
     from pathway_tpu.parallel.sharded import ShardedRuntime
 
-    def run_sharded(fuse: str):
-        monkeypatch.setenv("PATHWAY_FUSE", fuse)
-        try:
+    def run_sharded(fuse: bool):
+        with monkeypatch.context() as m:
+            if not fuse:
+                _no_chains(m)
             table = _identity_pipeline(incremental=True)
             cols = table.column_names()
             holder = {}
@@ -513,25 +532,36 @@ def test_fused_vs_unfused_byte_identity_sharded_2_workers(monkeypatch):
             rt = ShardedRuntime(n_workers=2, autocommit_duration_ms=5)
             rt.run([lnode])
             return dict(holder["n"].current)
-        finally:
-            monkeypatch.delenv("PATHWAY_FUSE", raising=False)
 
-    assert run_sharded("on") == run_sharded("off")
+    assert run_sharded(True) == run_sharded(False)
+
+
+#: the child script's own switch: ``nochains`` as its third argument builds
+#: every plan without chains
+_NO_CHAINS_PRELUDE = textwrap.dedent(
+    """
+    import functools
+    import sys
+
+    from pathway_tpu.engine import fusion
+
+    if sys.argv[3:] == ["nochains"]:
+        fusion.build_plan = functools.partial(fusion.build_plan, fuse=False)
+    """
+)
 
 
 def test_fused_vs_unfused_byte_identical_2proc_cluster(tmp_path):
     """2-proc cluster: the written update stream must be byte-for-byte
-    identical with PATHWAY_FUSE=on vs off."""
+    identical with chains fused vs one node per step."""
     script = tmp_path / "pipeline.py"
-    script.write_text(_CLUSTER_PIPELINE)
+    script.write_text(_NO_CHAINS_PRELUDE + _CLUSTER_PIPELINE)
     outs = {}
-    for fuse in ("on", "off"):
-        out = str(tmp_path / f"fuse_{fuse}")
-        _run_cluster_with_mode(
-            str(script), out, "incremental", 2, extra_env={"PATHWAY_FUSE": fuse}
-        )
-        outs[fuse] = open(out + ".csv").read()
-    assert outs["on"] == outs["off"]
+    for chains in ("chains", "nochains"):
+        out = str(tmp_path / f"fuse_{chains}")
+        _run_cluster_with_mode(str(script), out, "incremental", 2, (chains,))
+        outs[chains] = open(out + ".csv").read()
+    assert outs["chains"] == outs["nochains"]
 
 
 def test_fused_chain_embed_knn_rerank_byte_identity(monkeypatch):
@@ -542,9 +572,10 @@ def test_fused_chain_embed_knn_rerank_byte_identity(monkeypatch):
     from pathway_tpu.xpacks.llm.rerankers import EncoderReranker
     from pathway_tpu.internals.parse_graph import G
 
-    def run(fuse: str):
-        monkeypatch.setenv("PATHWAY_FUSE", fuse)
-        try:
+    def run(fuse: bool):
+        with monkeypatch.context() as m:
+            if not fuse:
+                _no_chains(m)
             G.clear()
             emb = FakeEmbedder(dimension=12)
             docs = [f"document number {i} about topic {i % 3}" for i in range(12)]
@@ -575,22 +606,19 @@ def test_fused_chain_embed_knn_rerank_byte_identity(monkeypatch):
             )
             pw.run(monitoring_level="none")
             return stream
-        finally:
-            monkeypatch.delenv("PATHWAY_FUSE", raising=False)
 
-    fused = run("on")
-    legacy = run("off")
-    assert fused and fused == legacy
+    fused = run(True)
+    unfused = run(False)
+    assert fused and fused == unfused
 
 
 def test_fused_chain_smoke(monkeypatch):
-    """Tier-1-speed smoke: with PATHWAY_FUSE=on explicitly, the benched
-    pipeline builds a real multi-node chain with a composed expression
-    segment, fused ticks execute its compiled register program, and the
-    answer is right — fusion cannot silently rot behind the default."""
+    """Tier-1-speed smoke: the benched pipeline builds a real multi-node
+    chain with a composed expression segment, fused ticks execute its
+    compiled register program, and the answer is right — fusion cannot
+    silently rot behind the default."""
     from pathway_tpu.engine import fusion
 
-    monkeypatch.setenv("PATHWAY_FUSE", "on")
     built: list = []
     ran: list = []
     orig_plan = fusion.build_plan
@@ -617,16 +645,105 @@ def test_fused_chain_smoke(monkeypatch):
     s2 = s.select(k=s.k, d=s.d, e=s.d + 1)
     g = s2.groupby(s2.k).reduce(s2.k, s=pw.reducers.sum(s2.e))
     got = rows_of(g)
-    assert built and built[-1] is not None, "PATHWAY_FUSE=on must build a plan"
     chains = built[-1].chains
     assert chains, "benched pipeline must fuse at least one chain"
     assert any(len(c.members) >= 3 for c in chains)
     segs = [u[1] for c in chains for u in c.units if u[0] == "seg"]
     assert segs, "filter+select+select must collapse into a ComposedSegment"
     assert ran, "fused ticks must execute the compiled register program"
-    # and the answer matches the legacy engine
-    monkeypatch.setenv("PATHWAY_FUSE", "off")
+    # and the answer matches the plan with no chains
+    monkeypatch.setattr(fusion, "build_plan", orig_plan)
+    _no_chains(monkeypatch)
+    n_fused = len(ran)
     assert got == rows_of(g)
+    assert len(ran) == n_fused, "a plan with no chains ran a composed segment"
+
+
+# ------------------------------------------- one tick loop, three runtimes
+# The loop is written once (engine/graph.py): every runtime records the same
+# phases of a tick and delivers the same delta stream.
+
+_PHASES_PIPELINE = textwrap.dedent(
+    """
+    import sys
+
+    import pathway_tpu as pw
+
+    out = sys.argv[1]
+    t = pw.debug.table_from_rows(
+        pw.schema_from_types(k=int, t=int),
+        [(i % 4, i, i // 5, 1) for i in range(40)],
+        is_stream=True,
+    )
+    b = t._buffer(pw.this.t + 5, pw.this.t)  # releases rows at the frontier
+    f = b.filter(b.t % 7 != 0)
+    g = f.groupby(f.k).reduce(f.k, s=pw.reducers.sum(f.t), c=pw.reducers.count())
+    pw.io.fs.write(g, out + ".csv", format="csv")
+    pw.run()
+    """
+)
+
+
+def _phases_run(tmp_path, monkeypatch, name: str, threads: int, processes: int):
+    """(phase span names, written update stream) of one run of the pipeline.
+    A chain's span stands for its members, so it is expanded into them: the
+    exchange-aware plans of the multi-worker runtimes cut chains shorter."""
+    import json
+    import re
+
+    script = tmp_path / "phases.py"
+    script.write_text(_PHASES_PIPELINE)
+    out = str(tmp_path / name)
+    monkeypatch.setenv("PATHWAY_TRACE", "on")
+    monkeypatch.setenv("PATHWAY_TRACE_LIVE_FILE", out + ".spans")
+    _run_cluster_with_mode(str(script), out, "incremental", processes, threads=threads)
+    paths = (
+        [f"{out}.spans.p{pid}" for pid in range(processes)]
+        if processes > 1
+        else [out + ".spans"]
+    )
+    names = set()
+    for path in paths:
+        with open(path) as fh:
+            for line in fh:
+                for sp in json.loads(line)["resourceSpans"][0]["scopeSpans"][0]["spans"]:
+                    n = sp["name"].replace(out, "OUT")  # the sink's name
+                    chain = re.fullmatch(r"sweep/chain\{(.*)\}", n)
+                    if chain:
+                        names.update(f"sweep/{m}" for m in chain.group(1).split("+"))
+                    # tick/wait belongs to the RUN loop, which is still
+                    # written three times (ROADMAP C1b)
+                    elif n != "tick/wait" and (
+                        n == "tick" or n.startswith(("tick/", "sweep/", "frontier/"))
+                    ):
+                        names.add(n)
+    return names, open(out + ".csv").read()
+
+
+@pytest.fixture(scope="module")
+def _phases_single(tmp_path_factory):
+    mp = pytest.MonkeyPatch()
+    try:
+        return _phases_run(tmp_path_factory.mktemp("phases"), mp, "single", 1, 1)
+    finally:
+        mp.undo()
+
+
+@pytest.mark.parametrize(
+    "threads,processes",
+    [(1, 1), (2, 1), (1, 2)],
+    ids=["single", "sharded-2-workers", "cluster-2-procs"],
+)
+def test_every_runtime_records_the_same_tick_phases(
+    tmp_path, monkeypatch, _phases_single, threads, processes
+):
+    want_names, want_stream = _phases_single
+    families = {n if n == "tick" else n[: n.rindex("/") + 1] for n in want_names}
+    assert families >= {"tick", "tick/poll/", "sweep/", "frontier/"}, want_names
+    assert {"tick/complete", "tick/done", "frontier/buffer"} <= want_names
+    names, stream = _phases_run(tmp_path, monkeypatch, "again", threads, processes)
+    assert names == want_names
+    assert stream == want_stream and stream.count("\n") > 8
 
 
 def test_fused_chain_jit_shape_set_closed_under_churn(monkeypatch):
@@ -636,7 +753,6 @@ def test_fused_chain_jit_shape_set_closed_under_churn(monkeypatch):
     from pathway_tpu.engine.jax_kernels import _bucket
     from pathway_tpu.observability import device as device_mod
 
-    monkeypatch.setenv("PATHWAY_FUSE", "on")
     monkeypatch.setenv("PATHWAY_FUSE_JAX", "on")
     rng = np.random.default_rng(23)
     sizes = [int(rng.integers(1, 900)) for _ in range(50)]

@@ -28,6 +28,7 @@ import urllib.request
 
 import numpy as np
 import pytest
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -38,26 +39,6 @@ def _free_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
-
-
-def _free_port_base(n: int) -> int:
-    """A run of n+1 consecutive free ports (cluster barrier/links/heartbeat/
-    fabric bands)."""
-    for base in range(24000, 60000, 137):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
 
 
 def _wait_ready(port: int, timeout: float = 40.0) -> None:
@@ -572,7 +553,7 @@ def _run_cluster(script_path, http_port, n_proc, extra_env, timeout=240, first_p
         PATHWAY_THREADS="1",
         PATHWAY_BARRIER_TIMEOUT="60",
         PATHWAY_FIRST_PORT=str(
-            first_port if first_port is not None else _free_port_base(2 * n_proc + 2)
+            first_port if first_port is not None else free_port_base(2 * n_proc + 2)
         ),
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO,
@@ -622,7 +603,7 @@ def test_replica_three_door_byte_identity_under_churn(tmp_path):
     script.write_text(_RETRIEVE_CLUSTER_SCRIPT)
     # one contiguous block: monitoring ports first, cluster bands after —
     # two independent scans would find the SAME free range and collide
-    block = _free_port_base(4 + 9)
+    block = free_port_base(4 + 9)
     mon_base = block
     result = _run_cluster(
         script,
@@ -722,7 +703,7 @@ def test_replica_door_sigkill_supervisor_resyncs_and_reserves(tmp_path):
     script.write_text(_SUPERVISED_REPLICA_SCRIPT)
     stop_file = tmp_path / "stop"
     http_port = _free_port()
-    first_port = _free_port_base(6)
+    first_port = free_port_base(6)
     env = dict(os.environ)
     env.update(
         PATHWAY_FABRIC="on",

@@ -35,6 +35,7 @@ from pathway_tpu.observability.spans import (
     derive_trace_id,
     tick_hash_sampled,
 )
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -212,7 +213,9 @@ def test_head_sampling_drops_ticks(monkeypatch):
 def test_trace_endpoint_serves_live_spans(monkeypatch):
     monkeypatch.setenv("PATHWAY_TRACE", "on")
     monkeypatch.setenv("PATHWAY_MONITORING_HTTP_PORT", "20611")
-    _pipeline(_slow_stream(n=80, pause_every=10, pause=0.03))
+    # the stream outlasts the probe's two fetches on a loaded host too (the
+    # server goes down with the run: a fetch after that is a reset connection)
+    _pipeline(_slow_stream(n=80, pause_every=10, pause=0.1))
     got = {}
 
     def probe():
@@ -602,24 +605,6 @@ _CLUSTER_PIPELINE = textwrap.dedent(
 )
 
 
-def _free_port_base(n: int) -> int:
-    for base in range(24000, 60000, 211):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 def test_cluster_monitoring_and_trace_stitching(tmp_path):
     """2-process cluster, live: per-process /metrics on offset ports, the
     coordinator /status aggregates every peer's tick/watermark/backlog, and
@@ -629,7 +614,7 @@ def test_cluster_monitoring_and_trace_stitching(tmp_path):
     script.write_text(_CLUSTER_PIPELINE)
     # one contiguous free range: cluster plane at base..base+3 (coordinator,
     # peer links, heartbeats), monitoring HTTP at base+5/base+6
-    base = _free_port_base(7)
+    base = free_port_base(7)
     first_port = base
     http_base = base + 5
     env = dict(os.environ)
@@ -667,7 +652,16 @@ def test_cluster_monitoring_and_trace_stitching(tmp_path):
                     ).read()
                 )
                 cluster = status0.get("cluster")
-                if cluster and cluster["n_reporting"] == 2:
+                # wait on what the assertions below read, not on the head
+                # count alone: a peer can report before the coordinator has
+                # built its own graph, and then no input has a watermark yet
+                if (
+                    cluster
+                    and cluster["n_reporting"] == 2
+                    and status0.get("watermarks")
+                    and cluster.get("watermark_min") is not None
+                    and all(p.get("tick") is not None for p in cluster["processes"].values())
+                ):
                     got["status0"] = status0
                     got["metrics1"] = (
                         urllib.request.urlopen(
@@ -738,7 +732,6 @@ def test_fused_chain_error_attributed_to_member_not_tail(monkeypatch):
     from pathway_tpu.internals.monitoring import prometheus_text
 
     monkeypatch.setenv("PATHWAY_TERMINATE_ON_ERROR", "0")
-    monkeypatch.setenv("PATHWAY_FUSE", "on")
     error_log.clear()
 
     class S(pw.Schema):
@@ -777,7 +770,6 @@ def test_fused_chain_filter_error_attributed_to_filter(monkeypatch):
     from pathway_tpu.internals import error_log
 
     monkeypatch.setenv("PATHWAY_TERMINATE_ON_ERROR", "0")
-    monkeypatch.setenv("PATHWAY_FUSE", "on")
     error_log.clear()
 
     class S(pw.Schema):

@@ -37,6 +37,7 @@ import pytest
 
 import pathway_tpu as pw
 from pathway_tpu.observability import requests as req_mod
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -497,24 +498,6 @@ _CLUSTER_DELAY_SCRIPT = textwrap.dedent(
 )
 
 
-def _free_port_base(n: int) -> int:
-    for base in range(24000, 60000, 103):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 def _run_cluster(script_text: str, argv: list[str], extra_env: dict, timeout=240):
     import tempfile
 
@@ -527,7 +510,7 @@ def _run_cluster(script_text: str, argv: list[str], extra_env: dict, timeout=240
             PATHWAY_PROCESSES="2",
             PATHWAY_THREADS="1",
             PATHWAY_BARRIER_TIMEOUT="60",
-            PATHWAY_FIRST_PORT=str(_free_port_base(3)),
+            PATHWAY_FIRST_PORT=str(free_port_base(3)),
             JAX_PLATFORMS="cpu",
             PYTHONPATH=REPO,
         )

@@ -36,6 +36,7 @@ from pathway_tpu.resilience import (
     heartbeat,
     last_committed_epoch,
 )
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -379,25 +380,6 @@ def test_supervise_cli_runs(tmp_path):
 # ----------------------------------------------------- cluster recovery (slow)
 
 
-def _free_port_base(n: int) -> int:
-    """Reserve a base port such that base..base+n are free right now."""
-    for base in range(24100, 60000, 103):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 _STREAMING_PIPELINE = textwrap.dedent(
     """
     import time
@@ -438,7 +420,7 @@ def test_cluster_peer_killed_midrun_raises_other_worker_error(tmp_path):
         JAX_PLATFORMS="cpu",
         PATHWAY_PROCESSES="2",
         PATHWAY_THREADS="1",
-        PATHWAY_FIRST_PORT=str(_free_port_base(3)),
+        PATHWAY_FIRST_PORT=str(free_port_base(3)),
         PATHWAY_BARRIER_TIMEOUT="60",
         PATHWAY_FAULT_PLAN="kill:proc=1,tick=10",
     )
@@ -549,7 +531,7 @@ def test_supervisor_cluster_kill_recovery(tmp_path):
         [sys.executable, str(script), out],
         processes=2,
         threads=1,
-        first_port=_free_port_base(3),
+        first_port=free_port_base(3),
         max_restarts=2,
         backoff_s=0.2,
         env=env,

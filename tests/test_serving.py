@@ -27,6 +27,7 @@ import pytest
 
 import pathway_tpu as pw
 from pathway_tpu.internals.parse_graph import G
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -643,24 +644,6 @@ _CLUSTER_SCRIPT = textwrap.dedent(
 )
 
 
-def _free_port_base(n: int) -> int:
-    for base in range(24000, 60000, 103):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 def test_cluster_route_live_on_coordinator(tmp_path):
     """2-process cluster with the REST route served by the coordinator: the
     query flows through the pod (barriers, heartbeats) and comes back upper-
@@ -673,7 +656,7 @@ def test_cluster_route_live_on_coordinator(tmp_path):
         PATHWAY_PROCESSES="2",
         PATHWAY_THREADS="1",
         PATHWAY_BARRIER_TIMEOUT="45",
-        PATHWAY_FIRST_PORT=str(_free_port_base(3)),
+        PATHWAY_FIRST_PORT=str(free_port_base(3)),
         JAX_PLATFORMS="cpu",
         PYTHONPATH=REPO,
     )

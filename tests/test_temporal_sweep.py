@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import csv
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -29,6 +28,7 @@ import pathway_tpu as pw
 from pathway_tpu.internals.parse_graph import G
 from pathway_tpu.observability import audit as audit_mod
 from utils import rows_of
+from conftest import free_port_base
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -154,24 +154,6 @@ _SWEEP_PIPELINE = textwrap.dedent(
 )
 
 
-def _free_port_base(n: int) -> int:
-    for base in range(25200, 60000, 107):
-        socks = []
-        try:
-            for p in range(base, base + n + 1):
-                s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-                s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-                s.bind(("127.0.0.1", p))
-                socks.append(s)
-            return base
-        except OSError:
-            continue
-        finally:
-            for s in socks:
-                s.close()
-    raise RuntimeError("no free port range found")
-
-
 def _run_procs(script: str, out: str, processes: int) -> None:
     env = dict(os.environ)
     env.update(
@@ -183,7 +165,7 @@ def _run_procs(script: str, out: str, processes: int) -> None:
         PYTHONPATH=REPO,
     )
     if processes > 1:
-        env["PATHWAY_FIRST_PORT"] = str(_free_port_base(processes + 1))
+        env["PATHWAY_FIRST_PORT"] = str(free_port_base(processes + 1))
     procs = []
     for pid in range(processes):
         penv = dict(env, PATHWAY_PROCESS_ID=str(pid))

@@ -403,7 +403,7 @@ def test_bottleneck_ranks_dominant_stage_with_knob(monkeypatch):
     assert top["cause"] == "stage:sweep/q"
     assert top["score"] == pytest.approx(2.0 / 2.4, abs=1e-3)
     assert "sweep-bound" in top["verdict"]
-    assert "PATHWAY_FUSE" in top["knob"]
+    assert "profile the UDF" in top["knob"]
 
 
 def test_bottleneck_phase_backlog_and_idle(monkeypatch):
@@ -672,7 +672,7 @@ def test_seeded_stall_attribution_and_pod_bundle(monkeypatch, tmp_path):
     # the injected stage dominates request time: the verdict NAMES it
     assert top["cause"].startswith("stage:sweep/"), top
     assert "sweep-bound" in top["verdict"]
-    assert "PATHWAY_FUSE" in top["knob"]
+    assert "profile the UDF" in top["knob"]
     # /status surfaces the same verdict
     assert out["status_bn"] and out["status_bn"]["top"]["cause"] == top["cause"]
     # exactly one pod-level bundle for the activation, lead-up attached
@@ -705,7 +705,7 @@ def test_cli_render_top_and_timeline_diff(tmp_path):
         "health": {"doors": {"/q": "ready"}, "alerts": {"active": []}},
         "bottleneck": {"top": {"cause": "stage:sweep/q", "score": 0.83,
                                "verdict": "request sweep-bound",
-                               "knob": "enable PATHWAY_FUSE"}},
+                               "knob": "profile the UDF"}},
     }
     tl = {
         "proc": "pod",
@@ -724,7 +724,7 @@ def test_cli_render_top_and_timeline_diff(tmp_path):
     assert "sweep/q" in frame and "500.0 ms" in frame
     assert "tick split: probe=14ms" in frame
     assert "bound by: stage:sweep/q" in frame
-    assert "knob: enable PATHWAY_FUSE" in frame
+    assert "knob: profile the UDF" in frame
 
     # timeline diff: run B's probe phase 3x slower -> named worst
     dir_a, dir_b = tmp_path / "a", tmp_path / "b"
