@@ -1641,7 +1641,7 @@ class GroupByNode(Node):
                     for r, spec in enumerate(self.reducer_specs)
                 )
                 st["emitted"] = new
-            if old == new and not _tuple_differs(old, new):
+            if not _tuple_differs(old, new):
                 continue
             if old is not None:
                 out_keys.append(gk)

@@ -6,6 +6,16 @@ Any, Stateful, Earliest, Latest), keeping its two styles: **semigroup** reducers
 (commutative, retraction = subtraction — ``reduce.rs:40``) update from vectorized
 per-batch partial aggregates; **multiset** reducers (``reduce.rs:50``) maintain a
 value multiset and re-extract on change.
+
+The **ordered** multiset reducers (``tuple``, ``ndarray``) keep one thing between
+extracts besides the multiset: the group's entries in extract order (sort key,
+then arrival). A group's first extract sorts its entries, as every extract once
+did; a later one sorts only the entries created since and merges that run into
+the kept order. While every entry of the group counts once, the values are read
+off the order's own tuples and no entry is visited. The order is derived state:
+it is not pickled, and an extract that finds none, or finds that the fold before
+it did not track what it created (``_MultisetState.fresh``), rebuilds it with the
+full sort.
 """
 
 from __future__ import annotations
@@ -14,6 +24,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from pathway_tpu import observability as _obs
 from pathway_tpu.internals.errors import ERROR
 from pathway_tpu.internals.keys import _canonical_bytes
 
@@ -181,12 +192,33 @@ class ArraySumReducer(ReducerImpl):
 
 
 class _MultisetState:
-    __slots__ = ("items", "total")
+    __slots__ = ("items", "total", "order", "fresh", "plain")
 
     def __init__(self):
-        # canonical-bytes -> [value, count, first_seq, extra]
+        # canonical-bytes -> [value, count, (time, seq) of the creating row]
         self.items: dict[bytes, list] = {}
         self.total = 0
+        # ordered reducers only, derived from ``items`` and never pickled:
+        # ``order`` holds a sort tuple ``(sort_key, (time, seq), value,
+        # entry)`` per entry, sorted, as of the last extract (None: never
+        # extracted here); ``fresh`` the entries created since, in arrival
+        # order (None: no tracking fold has run since that extract, so
+        # ``order`` is not to be trusted); ``plain`` says every tuple of
+        # ``order`` stands for one copy of its value (each count 1, nothing
+        # dropped), so an extract reads the values off the tuples
+        self.order: list[tuple] | None = None
+        self.fresh: list[list] | None = None
+        self.plain = False
+
+    def __getstate__(self):
+        # the layout snapshots have always had, so old ones load and new ones
+        # are no larger
+        return None, {"items": self.items, "total": self.total}
+
+    def __setstate__(self, state):
+        self.items, self.total = state[1]["items"], state[1]["total"]
+        self.order = self.fresh = None
+        self.plain = False
 
 
 #: exact scalar types whose equality (with the type) implies equal canonical
@@ -219,6 +251,10 @@ def _encode_block(values: list[tuple]) -> list[bytes]:
 class MultisetReducer(ReducerImpl):
     """Base for reducers re-extracted from a value multiset."""
 
+    #: extracts in (sort key, arrival) order and keeps that order between
+    #: extracts: the fold notes the entries it creates in ``state.fresh``
+    ordered = False
+
     def make(self):
         return _MultisetState()
 
@@ -233,12 +269,24 @@ class MultisetReducer(ReducerImpl):
         # row's (time, seq) and moves to the end of ``items``
         values, cks, diffs = block
         items = state.items
+        # the one place entries are created, counted and dropped. The kept
+        # order is told of an entry created and that an older one's count
+        # moved (a dropped one's stays 0 for good: a refill is a new entry)
+        fresh = None
+        if self.ordered:
+            fresh = state.fresh
+            if fresh is None:
+                fresh = state.fresh = []
         total = 0
         for k, i in enumerate(rows):
             ck, diff = cks[i], diffs[i]
             ent = items.get(ck)
             if ent is None:
                 ent = items[ck] = [values[i], 0, (time, seq + k)]
+                if fresh is not None:
+                    fresh.append(ent)
+            else:
+                state.plain = False
             ent[1] += diff
             if ent[1] == 0:
                 del items[ck]
@@ -293,26 +341,96 @@ class AnyReducer(MultisetReducer):
         return state.items[ck][0][0]
 
 
-class TupleReducer(MultisetReducer):
+class OrderedMultisetReducer(MultisetReducer):
+    """Base of the reducers that extract in (sort key, arrival ``(time, seq)``)
+    order. The sorted order is kept in ``state.order`` from one extract to the
+    next: the first extract of a state sorts all its entries; a later one
+    sorts the entries the fold created since (``state.fresh``) and merges that
+    run in. A state with no order (restored, migrated) or folded by code that
+    did not track takes the full sort again. The entries are visited for
+    their counts only after a fold moved the count of one that was there
+    (``state.plain``)."""
+
+    ordered = True
+    #: values are (value, sort_key) pairs; False orders by arrival alone
+    with_sort_key = True
+    skip_nones = False
+
+    def _sorted_run(self, entries) -> list[tuple]:
+        """One sort tuple per live entry, ending in its value and the entry,
+        sorted. (time, seq) is unique in a state, so comparing two never
+        reaches the values."""
+        if self.with_sort_key:
+            run = [(e[0][1], e[2], e[0][0], e) for e in entries if e[1]]
+        else:
+            run = [(e[2], e[0][0], e) for e in entries if e[1]]
+        run.sort()
+        return run
+
+    def _pack(self, values: list) -> Any:
+        """The reducer's value from the group's values in extract order."""
+        raise NotImplementedError
+
+    def extract(self, state):
+        order, fresh = state.order, state.fresh
+        state.fresh = None
+        tok = None
+        if order is None or fresh is None:
+            # the full sort; a state's first extract here is that and nothing
+            # else, a rebuild (the fold before it tracked nothing) says so
+            if order is not None:
+                tok = _obs.begin("reduce/order{sort}")
+            order = state.order = self._sorted_run(state.items.values())
+            state.plain = False
+            attrs = {"pathway.entries": len(order)}
+        else:
+            tok = _obs.begin("reduce/order{merge}")
+            run = self._sorted_run(fresh)
+            if state.plain:
+                state.plain = all(e[1] == 1 for e in fresh)
+            elif len(order) + len(run) > len(state.items):
+                # entries were dropped since the order was made: they leave it
+                # (the lengths only say whether the pass is worth making: a
+                # dropped entry's count is 0 and takes no place in the value)
+                order = state.order = [t for t in order if t[-1][1]]
+            # two sorted runs: Timsort finds them and merges in one pass
+            order.extend(run)
+            order.sort()
+            attrs = {"pathway.entries": len(order), "pathway.fresh": len(run)}
+        if state.plain:
+            # no entry is visited: in sort order they lie scattered through
+            # memory, each count four loads deep
+            values = [t[-2] for t in order]
+        else:
+            values = []
+            plain = True
+            for t in order:
+                count = t[-1][1]
+                if count == 1:
+                    values.append(t[-2])
+                else:  # duplicates; a negative count takes no place
+                    plain = False
+                    values.extend([t[-2]] * max(count, 0))
+            state.plain = plain
+        if self.skip_nones:
+            values = [v for v in values if v is not None]
+        out = self._pack(values)
+        if tok is not None:
+            _obs.end(tok, attrs)
+        return out
+
+
+class TupleReducer(OrderedMultisetReducer):
     """Collect values; ordered by arrival (time, seq) for stability. With
-    ``sort_by`` values are (value, sort_key) pairs ordered by sort_key."""
+    ``sort_by`` values are (value, sort_key) pairs ordered by sort_key. The
+    order is kept between extracts (``OrderedMultisetReducer``)."""
 
     def __init__(self, skip_nones: bool = False, with_sort_key: bool = False):
         self.skip_nones = skip_nones
         self.with_sort_key = with_sort_key
 
-    def extract(self, state):
-        if self.with_sort_key:
-            entries = sorted(state.items.values(), key=lambda e: (e[0][1], e[2]))
-        else:
-            entries = sorted(state.items.values(), key=lambda e: e[2])
-        out = []
-        for e in entries:
-            v = e[0][0]
-            if self.skip_nones and v is None:
-                continue
-            out.extend([v] * max(e[1], 0))
-        return tuple(out)
+    def _pack(self, values):
+        return tuple(values)
 
 
 class SortedTupleReducer(MultisetReducer):
@@ -329,15 +447,12 @@ class SortedTupleReducer(MultisetReducer):
         return tuple(sorted(vals))
 
 
-class NdarrayReducer(MultisetReducer):
-    """values = (value, sort_key); returns np.ndarray sorted by sort_key."""
+class NdarrayReducer(OrderedMultisetReducer):
+    """values = (value, sort_key); returns np.ndarray sorted by sort_key, the
+    order kept between extracts (``OrderedMultisetReducer``)."""
 
-    def extract(self, state):
-        entries = sorted(state.items.values(), key=lambda e: (e[0][1], e[2]))
-        vals = []
-        for e in entries:
-            vals.extend([e[0][0]] * max(e[1], 0))
-        return np.asarray(vals)
+    def _pack(self, values):
+        return np.asarray(values)
 
 
 class EarliestReducer(MultisetReducer):
