@@ -135,7 +135,7 @@ def run(n_starting_documents: int = 2, factor: int = 2, max_iterations: int = 4)
     )
     qa = qa_set()
     queries = pw.debug.table_from_rows(
-        rag.AnswerQuerySchema, [(q, None, None) for q, _ in qa]
+        rag.AnswerQuerySchema, [(q, None, None, None) for q, _ in qa]
     )
     res = rag.answer_query(queries)
     paired = queries.select(q=pw.this.prompt)

@@ -883,9 +883,14 @@ class MicrobatchApplyNode(Node):
         ins = np.flatnonzero(batch.diffs > 0)
         if len(ins):
             out.extend(self._enqueue(batch, ins, time))
-        if len(self.waiting) >= self._effective_max_batch():
-            out.extend(self._flush(time, only_full=True))
+        out.extend(self._launch_full(time))
         return out
+
+    def _launch_full(self, time) -> list[DeltaBatch]:
+        """Full ``max_batch`` chunks launch as soon as they have accumulated."""
+        if len(self.waiting) >= self._effective_max_batch():
+            return self._flush(time, only_full=True)
+        return []
 
     def _effective_max_batch(self) -> int:
         """Launch bucket for this flush: the static ``max_batch`` cap, tuned
@@ -1069,6 +1074,12 @@ class MicrobatchApplyNode(Node):
                     "pathway.udfs": ",".join(s.name for s in self.udf_specs),
                 },
             )
+        return self._emit(keys, entries, udf_vals, time)
+
+    def _emit(self, keys: list, entries: list, udf_vals: list, time) -> list[DeltaBatch]:
+        """Settle ``entries`` with their UDF values: the output rows (and, in
+        pending mode, the retractions of their placeholders), remembered for
+        the retracts that may follow."""
         out_keys: list[int] = []
         out_diffs: list[int] = []
         out_rows: list[tuple] = []
@@ -1094,6 +1105,8 @@ class MicrobatchApplyNode(Node):
                 rec[k] = (self._entry_sig(entry[2], entry[3]), row)
                 if len(rec) > self._RECENT_MAX:
                     rec.popitem(last=False)
+        if not out_keys:
+            return []
         return [
             DeltaBatch.from_rows(
                 out_keys, out_rows, self.out_columns, time,
@@ -1101,24 +1114,28 @@ class MicrobatchApplyNode(Node):
             )
         ]
 
-    def _should_flush(self, time) -> str | None:
-        """Why the buffer flushes at this frontier (``drain``, ``deadline``),
-        or None when it keeps accumulating."""
+    def _draining(self, time) -> bool:
+        """Nothing more will accumulate: the stream's close, a static run's
+        one tick, or every source exhausted."""
         if time == END_OF_STREAM:
-            return "drain"
+            return True
         rt = self.runtime
         if rt is None or not getattr(rt, "streaming", False):
             # static run: exactly one tick — flush at its frontier (emissions
             # re-enter the same logical time, matching the inline path)
-            return "drain"
+            return True
         conns = getattr(rt, "connectors", None)
-        if conns and all(d.is_finished() for d in conns):
-            # drain tick: sources exhausted, nothing more will accumulate
+        return bool(conns) and all(d.is_finished() for d in conns)
+
+    def _should_flush(self, time) -> str | None:
+        """Why the buffer flushes at this frontier (``drain``, ``deadline``),
+        or None when it keeps accumulating."""
+        if self._draining(time):
             return "drain"
         first = next(iter(self.waiting.values()))
         deadline = self.flush_ms
         if deadline is None:
-            deadline = getattr(rt, "autocommit_duration_ms", 20) or 20
+            deadline = getattr(self.runtime, "autocommit_duration_ms", 20) or 20
         import time as _t
 
         if (_t.perf_counter() - first[1]) * 1000.0 >= deadline:
@@ -1130,6 +1147,156 @@ class MicrobatchApplyNode(Node):
         if reason is None:
             return []
         return self._flush(time, reason=reason)
+
+
+class SteppingApplyNode(MicrobatchApplyNode):
+    """A microbatched select whose UDF's launch does not finish every row: the
+    UDF declares a ``RowStepper`` (``UDF.microbatch_stepper``,
+    ``ops/microbatch.py``) — a decoder, whose rows take many steps each.
+
+    Rows wait in ``waiting``, oldest first, until the stepper has room; an
+    admitted row is ``inflight`` and joins the rows already stepping at the
+    next step, so it never waits for another row's result. At each frontier
+    the node admits, then runs steps until a row finishes, an arrival asks the
+    loop for a tick (``TickWakeup.due``) or the autocommit period has passed —
+    the cadence every other deadline of the graph rides on — and yields; the
+    finished rows are emitted in that tick, each under its own key, in the
+    order they finished. It tells the runtime how many rows it holds
+    (``Runtime.in_flight``), and while there are any the loop does not sleep
+    on its period. A drain (the stream's close, a static run) steps until
+    nothing is left.
+
+    A retract of a waiting row cancels it in the buffer, of a row in flight
+    cancels it in the stepper (its place is free again, nothing is emitted),
+    of a settled row replays what was emitted, as in the base class. A
+    snapshot holds the rows in flight, not the stepper's state: restored, they
+    wait again ahead of the others and are admitted anew, so every row is
+    answered once.
+    """
+
+    name = "select_stepping"
+
+    snapshot_attrs = ("waiting", "inflight", "emitted")
+
+    def __init__(self, *args, stepper: Any, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.stepper = stepper
+        #: key -> entry (as in ``waiting``) of the rows the stepper holds
+        self.inflight: dict[int, list] = {}
+        self._stepped_at: int | None = None
+
+    def restore_state(self, state: dict) -> None:
+        for a, v in state.items():
+            setattr(self, a, v)
+        self.waiting = {**self.inflight, **self.waiting}
+        self.inflight = {}
+        super().restore_state({})
+
+    def _launch_full(self, time) -> list[DeltaBatch]:
+        return []  # nothing launches on arrival: rows are admitted at the frontier, as slots allow
+
+    def _retract(self, batch, idx, time):
+        cand = [int(i) for i in idx if int(batch.keys[i]) in self.inflight]
+        if not cand:
+            return super()._retract(batch, idx, time)
+        _k, _d, pts, cls = self._entry_rows(batch.take(np.asarray(cand, dtype=np.int64)))
+        gone: set[int] = set()
+        out_keys, out_diffs, out_rows = [], [], []
+        for i, p, c in zip(cand, pts, cls):
+            k = int(batch.keys[i])
+            entry = self.inflight[k]
+            if not self._sig_matches(self._entry_sig(p, c), self._entry_sig(entry[2], entry[3])):
+                continue  # an older, settled version of the key: the base class replays it
+            cancel = max(int(batch.diffs[i]), -entry[0])
+            if cancel != int(batch.diffs[i]):
+                continue  # more retracted than is in flight: not this row alone
+            gone.add(i)
+            if self.mode == "pending":
+                out_keys.append(k)
+                out_diffs.append(cancel)
+                out_rows.append(self._pending_row(entry))
+            entry[0] += cancel
+            if entry[0] <= 0:
+                del self.inflight[k]
+                self.stepper.cancel(k)
+        out = []
+        if out_keys:
+            out.append(DeltaBatch.from_rows(
+                out_keys, out_rows, self.out_columns, time, diffs=out_diffs, np_dtypes=self.np_dtypes,
+            ))
+        rest = np.asarray([int(i) for i in idx if int(i) not in gone], dtype=np.int64)
+        if len(rest):
+            out.extend(super()._retract(batch, rest, time))
+        return out
+
+    def _admit(self) -> list[tuple[int, Any]]:
+        """Move waiting rows into the stepper while it has room; returns the
+        rows that are finished already."""
+        free = self.stepper.free()
+        if not self.waiting or not free:
+            return []
+        from pathway_tpu import observability as _obs
+
+        spec = self.udf_specs[0]
+        tok = _obs.begin("generate/admit")
+        done: list[tuple[int, Any]] = []
+        rows = []
+        # a key that is in flight already (inserted again, not yet retracted) waits its turn
+        for k in [k for k in self.waiting if k not in self.inflight][:free]:
+            entry = self.inflight[k] = self.waiting.pop(k)
+            cell = entry[3][0]
+            if cell[0] == "done":  # decided on arrival (ERROR, a propagated None)
+                done.append((k, cell[1]))
+            else:
+                rows.append((k, cell[1], dict(zip(spec.kw_names, cell[2]))))
+        if tok is not None:
+            _obs.end(tok, {
+                "pathway.operator.id": self.node_index, "pathway.rows": len(rows),
+                "pathway.waiting": len(self.waiting), "pathway.slots_free": free - len(rows),
+            })
+        return done + (self.stepper.admit(rows) if rows else [])
+
+    def on_frontier(self, time):
+        rt = self.runtime
+        held = getattr(rt, "in_flight", None)
+        if not self.waiting and not self.inflight:
+            if held is not None:
+                held[self.node_index] = 0
+            return []
+        if self._stepped_at == time:
+            return []  # once a tick: its own emissions bring the frontier round back here
+        self._stepped_at = time
+        import time as _t
+
+        drain = self._draining(time)
+        wakeup = None if drain else getattr(rt, "wakeup", None)
+        until = _t.perf_counter() + (getattr(rt, "autocommit_duration_ms", 20) or 20) / 1000.0
+        finished: list[tuple[int, Any]] = []
+        while True:
+            finished.extend(self._admit())
+            if not self.stepper.live():
+                break
+            finished.extend(self.stepper.step())
+            if not drain and (
+                finished or _t.perf_counter() >= until or (wakeup is not None and wakeup.due())
+            ):
+                break
+        if held is not None:  # rows in flight: the next tick starts now, not a period from now
+            held[self.node_index] = len(self.inflight) + len(self.waiting) - len(finished)
+        if not finished:
+            return []
+        keys = [k for k, _v in finished]
+        entries = [self.inflight.pop(k) for k in keys]
+        from pathway_tpu import observability as _obs
+
+        tok = _obs.begin("generate/finish")
+        if tok is not None:
+            _obs.end(tok, {
+                "pathway.operator.id": self.node_index, "pathway.rows": len(keys),
+                "pathway.tokens_out": sum(self.stepper.size(v) for _k, v in finished),
+                "pathway.oldest_wait_ns": int((_t.perf_counter() - min(e[1] for e in entries)) * 1e9),
+            })
+        return self._emit(keys, entries, [[v] for _k, v in finished], time)
 
 
 # ---------------------------------------------------------------------------- groupby

@@ -55,6 +55,15 @@ class TickWakeup:
                 self._deadline = deadline
             self._cond.notify_all()
 
+    def due(self) -> bool:
+        """Has a tick been asked for that should start now? A node that holds
+        the tick (``SteppingApplyNode`` runs decode steps at the frontier)
+        asks between steps and yields when an arrival wants the loop."""
+        with self._cond:
+            return self._immediate or (
+                self._deadline is not None and self._deadline <= _time.perf_counter()
+            )
+
     def wait(self, timeout: float) -> str:
         """Sleep until ``timeout`` elapses, a pending coalesce deadline
         passes, or an immediate tick is requested — whichever is first, and
@@ -117,6 +126,11 @@ class Runtime:
         # arrival-driven tick scheduling: connectors (the REST serving plane)
         # request a wakeup instead of waiting out the autocommit poll
         self.wakeup = TickWakeup()
+        #: rows a node holds in flight across ticks (``SteppingApplyNode``:
+        #: operator id -> rows admitted or waiting for a slot). While there
+        #: are any the loop does not sleep on its period: the next tick runs
+        #: their next steps
+        self.in_flight: dict[int, int] = {}
         #: set once the graph is built: live-connector runs tick repeatedly, so
         #: cross-tick accumulators (microbatch UDF buffers) may hold rows until
         #: their autocommit deadline; static runs have exactly one tick and
@@ -226,7 +240,7 @@ class Runtime:
                     break
                 if not all_virtual:
                     elapsed = _time.perf_counter() - t0
-                    if elapsed < period:
+                    if elapsed < period and not any(self.in_flight.values()):
                         # the sleep between ticks has a span of its own, so
                         # "host asleep on its period" and "host busy" part
                         tr = scheduler.tracer

@@ -775,23 +775,36 @@ def _microbatch_factory(
     np_dtypes = schema.np_dtypes()
     node_mode = "pending" if mode == "pending" else "hold"
     flush_ms = cfg.microbatch_flush_ms
+    # a UDF that declares a stepper holds its rows in flight across ticks: its
+    # select is a node of another kind, told apart by the declaration alone
+    steppers = [
+        getattr(getattr(e, "udf", None), "microbatch_stepper", None) for _, e in udf_items
+    ]
+    stepper_of = next((s for s in steppers if s is not None), None)
+    if stepper_of is not None and len(udf_items) > 1:
+        raise ValueError(
+            "a UDF that declares microbatch_stepper must be the only batched UDF "
+            f"of its select; this one has {sorted(udf_names)}"
+        )
 
     def factory() -> ops.MicrobatchApplyNode:
         from pathway_tpu.internals.logical import current_build
 
         build = current_build()
         runtime = build.shared_runtime if build is not None else None
-        return ops.MicrobatchApplyNode(
-            out_columns,
-            pass_names,
-            pre_program,
-            [ops.MicrobatchUdfSpec(**sc) for sc in specs_cfg],
+        kwargs = dict(
             np_dtypes=np_dtypes,
             mode=node_mode,
             max_batch=max_batch,
             flush_ms=flush_ms,
             runtime=runtime,
         )
+        specs = [ops.MicrobatchUdfSpec(**sc) for sc in specs_cfg]
+        if stepper_of is not None:
+            return ops.SteppingApplyNode(
+                out_columns, pass_names, pre_program, specs, stepper=stepper_of(), **kwargs
+            )
+        return ops.MicrobatchApplyNode(out_columns, pass_names, pre_program, specs, **kwargs)
 
     return factory
 

@@ -223,6 +223,12 @@ class UDF:
     microbatch_max_batch: int | None = None
     microbatch_min_bucket: int = 8
     microbatch_length: Callable[..., int] | None = None
+    #: declared by a UDF whose launch does not finish every row (a decoder:
+    #: a row takes many steps and rows join and leave between them): a factory
+    #: of the ``RowStepper`` (``ops/microbatch.py``) that holds the rows in
+    #: flight. Such a select runs as ``engine.operators.SteppingApplyNode``;
+    #: the UDF's batch function stays the blocking fallback
+    microbatch_stepper: Callable[[], Any] | None = None
 
     def __init__(
         self,

@@ -21,7 +21,7 @@ Works for any callee that maps ``list[values] -> list[results]``; TPU model UDFs
 
 from __future__ import annotations
 
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, Protocol, Sequence
 
 import numpy as np
 
@@ -49,6 +49,33 @@ def bucket_size(n: int, min_bucket: int = _MIN_BUCKET, max_bucket: int | None = 
     while b < n and b < max_bucket:
         b *= 2
     return b
+
+
+class RowStepper(Protocol):
+    """Rows in flight across launches, for a UDF whose launch does not finish
+    every row (``UDF.microbatch_stepper`` returns one). The dataflow node
+    (``engine.operators.SteppingApplyNode``) admits waiting rows while
+    ``free()`` says there is room, calls ``step()`` between ticks' other work,
+    and emits each ``(handle, result)`` in the tick in which it came back."""
+
+    def free(self) -> int:
+        """How many more rows ``admit`` can take now."""
+
+    def live(self) -> int:
+        """Rows admitted and not yet finished or cancelled."""
+
+    def admit(self, rows: list[tuple[Any, tuple, dict]]) -> list[tuple[Any, Any]]:
+        """Take ``(handle, args, kwargs)`` rows in (at most ``free()``); they
+        join the live rows at the next ``step``. Returns rows finished at once."""
+
+    def step(self) -> list[tuple[Any, Any]]:
+        """One launch over every live row; returns the rows it finished."""
+
+    def cancel(self, handle: Any) -> None:
+        """Drop a live row and free its place; it is never returned."""
+
+    def size(self, result: Any) -> int:
+        """What a finished row produced, in the stepper's unit (tokens): for the spans."""
 
 
 class MicrobatchDispatcher:

@@ -105,6 +105,7 @@ class BaseRAGQuestionAnswerer:
         prompt: str
         filters: str | None = pw.column_definition(default_value=None)
         model: str | None = pw.column_definition(default_value=None)
+        return_context_docs: bool | None = pw.column_definition(default_value=None)
 
     class SummarizeQuerySchema(pw.Schema):
         text_list: Any
@@ -137,22 +138,42 @@ class BaseRAGQuestionAnswerer:
         )
         hits = self.indexer.retrieve_query(retrieve)
         prompt_template = self.prompt_template
-        combined = queries.with_columns(
-            __docs=pw.apply_with_type(
-                lambda res: [d["text"] for d in (res.value if hasattr(res, "value") else res or [])],
-                dt.ANY,
-                hits.with_universe_of(queries).result,
-            )
+
+        def as_list(res) -> list:
+            return list(res.value if hasattr(res, "value") else res or [])
+
+        # a query may ask for the chunks its answer rests on (the reference's
+        # ``return_context_docs``); a table built without the column asks for none
+        wants = (
+            queries.return_context_docs
+            if "return_context_docs" in queries.column_names()
+            else pw.declare_type(dt.Optional(dt.BOOL), None)
         )
-        prompts = combined.select(
+        prompts = queries.select(
+            __hits=hits.with_universe_of(queries).result,
+            __wants=wants,
             __prompt=pw.apply_with_type(
-                lambda q, docs: prompt_template(q, list(docs)),
+                lambda q, res: prompt_template(q, [d["text"] for d in as_list(res)]),
                 dt.STR,
                 pw.this.prompt,
-                pw.this["__docs"],
+                hits.with_universe_of(queries).result,
+            ),
+        )
+        # the chat as a top-level column: a batched chat rides the microbatcher
+        answered = prompts.select(
+            pw.this["__hits"], pw.this["__wants"], __answer=self.llm(pw.this["__prompt"])
+        )
+        return answered.select(
+            result=pw.apply_with_type(
+                lambda answer, res, wants: (
+                    pw.Json({"response": answer, "context_docs": as_list(res)}) if wants else answer
+                ),
+                dt.ANY,
+                pw.this["__answer"],
+                pw.this["__hits"],
+                pw.this["__wants"],
             )
         )
-        return prompts.select(result=self.llm(pw.this["__prompt"]))
 
     answer = answer_query
 

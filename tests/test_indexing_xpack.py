@@ -316,7 +316,7 @@ def test_adaptive_rag_answerer_end_to_end():
         max_iterations=2,
     )
     queries = pw.debug.table_from_rows(
-        rag.AnswerQuerySchema, [("how to read kafka", None, None)]
+        rag.AnswerQuerySchema, [("how to read kafka", None, None, None)]
     )
     out = list(rows_of(rag.answer_query(queries)))
     assert out == [("Kafka answer",)]
