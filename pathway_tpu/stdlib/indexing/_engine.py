@@ -49,14 +49,22 @@ class IndexBackend:
         raise NotImplementedError
 
 
+def _pow2_ceil(n: int) -> int:
+    """``n`` rounded up to a power of two (0 stays 0): ``k`` is a static jit
+    argument of the search kernels, so an unquantized fetch would compile a
+    fresh kernel every time the live-row count moves."""
+    return 1 << (n - 1).bit_length() if n else 0
+
+
 def overfetch(kmax: int, n_live: int) -> int:
-    """Candidate over-fetch so post-filtering still fills k (filters are rare
-    and the einsum cost is independent of k). Rounded up to a power of two:
-    ``k`` is a static jit argument of the search kernels, so an unquantized
-    fetch would compile a fresh kernel every time the live-row count moves.
-    Shared by VectorBackend and the tiered backend — one factor to tune."""
-    fetch = min(n_live, max(kmax * 10, kmax))
-    return 1 << max(0, (fetch - 1)).bit_length() if fetch else 0
+    """Candidate over-fetch, ten times k, so post-filtering still fills k.
+    The einsum's cost is independent of k; the selection after it is not
+    (``ops.knn._topk_rows`` keeps k of every 256 scores and then selects over
+    N*k/256 of them), so ``VectorBackend`` fetches this many only for the
+    queries whose filter rejected a hit. The tiered backend, whose cold tier
+    prunes by candidate keys, fetches it on every search. Power-of-two
+    quantized — one factor to tune."""
+    return _pow2_ceil(min(n_live, max(kmax * 10, kmax)))
 
 
 class VectorBackend(IndexBackend):
@@ -81,6 +89,20 @@ class VectorBackend(IndexBackend):
         self.index.remove(key)
         self.metadata.pop(key, None)
 
+    def _pick(self, hits, k, flt):
+        """The first ``k`` hits the filter accepts, and whether it rejected one
+        on the way."""
+        picked = []
+        rejected = False
+        for key, score in hits:
+            if len(picked) >= k:
+                break
+            if flt(self.metadata.get(key)):
+                picked.append((key, float(score)))
+            else:
+                rejected = True
+        return picked, rejected
+
     def search(self, items, ks, filters):
         if not items:
             return []
@@ -88,19 +110,32 @@ class VectorBackend(IndexBackend):
         if n_live == 0:
             return [[] for _ in items]
         kmax = max(ks, default=0)
-        fetch = overfetch(kmax, n_live)
+        # what the queries asked for: the canonical top-k is a prefix of the
+        # canonical top-(10 k), so a query whose filter rejects nothing reads
+        # the same hits off the narrow selection as off the over-fetch
+        fetch = _pow2_ceil(min(n_live, kmax))
+        wide = overfetch(kmax, n_live)
         tok = _obs.begin("index/search")
         batch = np.stack([np.asarray(q, dtype=np.float32) for q in items])
         raw = self.index.search(batch, fetch)
         out = []
-        for hits, k, flt in zip(raw, ks, filters):
-            picked = []
-            for key, score in hits:
-                if len(picked) >= k:
-                    break
-                if flt(self.metadata.get(key)):
-                    picked.append((key, float(score)))
+        # queries a rejection left with fewer than their k, while the
+        # over-fetch has candidates the first search did not see
+        short = []
+        for qi, (hits, k, flt) in enumerate(zip(raw, ks, filters)):
+            picked, rejected = self._pick(hits, k, flt)
+            if rejected and len(picked) < k and wide > fetch:
+                short.append(qi)
             out.append(picked)
+        if short:
+            raw = self.index.search(batch[short], wide)
+            for qi, hits in zip(short, raw):
+                out[qi] = self._pick(hits, ks[qi], filters[qi])[0]
+        stats = _obs.device.stats()
+        if stats.enabled:
+            asked = sum(min(k, n_live) for k in ks)
+            fetched = fetch * len(items) + wide * len(short)
+            stats.note_pad_rows("knn.fetch", asked, fetched - asked)
         if tok is not None:
             _obs.end(
                 tok,
@@ -108,6 +143,7 @@ class VectorBackend(IndexBackend):
                     "pathway.queries": len(items),
                     "pathway.fetch": fetch,
                     "pathway.live_rows": n_live,
+                    "pathway.refetched": len(short),
                 },
             )
         return out

@@ -203,6 +203,124 @@ def test_vector_backend_k_zero():
     assert b.search([np.ones(4, np.float32)], [0], [lambda m: True]) == [[]]
 
 
+# -- VectorBackend.search fetches what was asked; ten times k only after a
+# rejection (ISSUE 33) --------------------------------------------------------
+
+_KNN_DIM = 8
+
+
+def _lazy_overfetch_backend(n_rows):
+    """Integer rows under the dot metric: every score is exact in float32, in
+    numpy as on the device, and many of them tie. Capacity 8192, so
+    ``_topk_rows`` takes its chunked path as it does at the cell's size."""
+    from pathway_tpu.stdlib.indexing._engine import VectorBackend
+
+    rng = np.random.default_rng(33)
+    rows = rng.integers(-3, 4, size=(n_rows, _KNN_DIM)).astype(np.float32)
+    backend = VectorBackend(dimension=_KNN_DIM, metric="dot", reserved_space=8192)
+    keys = [int(key) for key in rng.choice(1 << 40, size=n_rows, replace=False)]
+    for key, row in zip(keys, rows):
+        backend.add(key, row, {"key": key})
+    return backend, keys, rows
+
+
+def _ranked(keys, rows, query):
+    """Every row as (key, score), in the canonical (score desc, key order)
+    order of a plain numpy scan."""
+    from pathway_tpu.internals.keys import tie_order
+
+    scores = rows @ query
+    order = sorted(range(len(keys)), key=lambda i: (-scores[i], tie_order(keys[i])))
+    return [(keys[i], float(scores[i])) for i in order]
+
+
+def _parent_rule(keys, rows, queries, ks, filters):
+    """What the parent commit answers: the canonical top ten times kmax
+    (rounded up to a power of two), walked through the filter until k are
+    accepted."""
+    fetch = min(len(keys), 10 * max(ks, default=0))
+    fetch = 1 << (fetch - 1).bit_length() if fetch else 0
+    return [
+        [hit for hit in _ranked(keys, rows, query)[:fetch] if flt({"key": hit[0]})][:k]
+        for query, k, flt in zip(queries, ks, filters)
+    ]
+
+
+def _node_filter_none():
+    from pathway_tpu.stdlib.indexing._engine import ExternalIndexNode, VectorBackend
+
+    return ExternalIndexNode(lambda: VectorBackend(dimension=_KNN_DIM), as_of_now=True)._filter(None)
+
+
+def _reject(ranked, positions):
+    dropped = {ranked[p] for p in positions}
+    return lambda meta: meta["key"] not in dropped
+
+
+def _accept_only(ranked, positions):
+    kept = {ranked[p] for p in positions}
+    return lambda meta: meta["key"] in kept
+
+
+# case: (live rows, the k of each query, a filter of each query built from its
+# canonical ranking, the launches [(queries, k)] expected, queries refetched)
+_LAZY_OVERFETCH_CASES = {
+    "accept_all_lambda": (300, [6, 6], [lambda _r: (lambda _meta: True)] * 2, [(2, 8)], 0),
+    "the_node_s_filter_of_none": (300, [6, 6], [lambda _r: _node_filter_none()] * 2, [(2, 8)], 0),
+    "rejects_two_of_the_top_k_and_the_margin_fills_it": (
+        300, [6], [lambda r: _reject(r, [0, 4])], [(1, 8)], 0),
+    "rejects_three_of_the_top_k": (300, [6], [lambda r: _reject(r, [1, 2, 5])], [(1, 8), (1, 64)], 1),
+    "rejects_so_much_that_k_stays_unfilled": (
+        300, [6], [lambda r: _accept_only(r, [3, 40, 63, 64, 200])], [(1, 8), (1, 64)], 1),
+    "short_and_satisfied_queries_with_different_k": (
+        300,
+        [6, 2, 3],
+        [lambda _r: (lambda _meta: True), lambda r: _reject(r, range(0, 8)), lambda r: _reject(r, [0])],
+        [(3, 8), (1, 64)],
+        1,
+    ),
+    "fewer_live_rows_than_k": (3, [6], [lambda r: _reject(r, [0])], [(1, 4)], 0),
+    "k_zero": (300, [0], [lambda _r: (lambda _meta: True)], [(1, 0)], 0),
+    "k_not_a_power_of_two": (300, [5, 3], [lambda _r: (lambda _meta: True)] * 2, [(2, 8)], 0),
+    "k_one": (300, [1], [lambda _r: (lambda _meta: True)], [(1, 1)], 0),
+}
+
+
+@pytest.mark.parametrize("case", list(_LAZY_OVERFETCH_CASES))
+def test_vector_backend_overfetches_only_after_a_rejection(case, monkeypatch):
+    from pathway_tpu import observability as obs
+
+    n_rows, ks, make_filters, launches, refetched = _LAZY_OVERFETCH_CASES[case]
+    backend, keys, rows = _lazy_overfetch_backend(n_rows)
+    rng = np.random.default_rng(len(case))
+    queries = [rng.integers(-3, 4, size=_KNN_DIM).astype(np.float32) for _ in ks]
+    filters = [make([key for key, _ in _ranked(keys, rows, q)]) for make, q in zip(make_filters, queries)]
+
+    seen = []
+    index_search = backend.index.search
+    monkeypatch.setattr(
+        backend.index, "search", lambda batch, k: seen.append((len(batch), k)) or index_search(batch, k)
+    )
+    monkeypatch.setenv("PATHWAY_TRACE", "on")
+    tracer = obs.install_from_env()
+    try:
+        tracer.begin_tick(0)
+        obs.device.stats().reset_run()
+        got = backend.search(queries, ks, filters)
+        spans = [r[5] for r in tracer.buffer.records() if r[0] == "index/search"]
+        pad = obs.device.stats().pad["knn.fetch"]
+    finally:
+        obs.shutdown()
+
+    assert got == _parent_rule(keys, rows, queries, ks, filters)
+    assert seen == launches
+    asked = sum(min(k, n_rows) for k in ks)
+    assert pad[:2] == [asked, sum(q * k for q, k in launches) - asked]
+    assert len(spans) == 1  # one span a call, however many launches
+    assert spans[0]["pathway.refetched"] == refetched
+    assert spans[0]["pathway.fetch"] == launches[0][1] and spans[0]["pathway.queries"] == len(ks)
+
+
 def test_filter_runtime_error_excludes_doc_only():
     store = DocumentStore(make_docs(), retriever_factory=TantivyBM25Factory())
     # contains(path, 5) parses but raises per doc (int in str) — query must
