@@ -1,45 +1,50 @@
-"""A decoder-only language model on the chip: latent attention and a sparse
-expert layer (the DeepSeek-V3 block, which Kimi-K2 shares), served by
-``prefill`` and ``step`` through a latent cache.
+"""A decoder-only language model on the chip, served by ``prefill`` and
+``step`` through a cache of slots: a layer is (a mixer, a feed-forward), each
+read from ``DecoderConfig``.
 
-Block ``l``: ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN_l(RMSNorm(h))``; a
-final RMSNorm; ``logits = y W_head``; embedding and head untied.
+Block ``l``: ``h = x + r * Mixer_l(RMSNorm(x))``, ``y = h + r * FFN_l(RMSNorm(
+h))`` (``r`` the ``residual_multiplier``, 1 where a model has none); ``x_0 =
+E[ids] * embedding_multiplier``; a final RMSNorm; ``logits = y W_head /
+logits_scaling``, ``W_head`` the embedding's transpose where the two are tied.
 
-- Latent attention. ``c_q = RMSNorm(x W_qa)``; ``q = c_q W_qb`` gives every
-  head a no-position part and a rotary part; ``[c_kv ; k_r] = x W_kva``,
-  ``c_kv = RMSNorm(c_kv)``, ``k_r`` one rotary vector shared by all heads
-  (YaRN frequencies); ``[k_nope ; v] = c_kv W_kvb``. **The cache holds
-  ``c_kv`` after its norm and ``k_r`` after RoPE**, ``kv_lora_rank +
-  qk_rope_head_dim`` numbers a token a layer. Prefill up-projects keys and
-  values from the latents; a decode step never does: it folds ``W_kvb``'s key
-  half into the query, scores against ``c_kv`` itself and applies the value
-  half after the weighted sum. Both are the same function of the same weights.
-- The first ``first_k_dense`` layers have a dense SwiGLU. Every other layer
-  routes: ``s = sigmoid(x W_r)`` in float32 over ALL the published experts,
-  the ``experts_per_token`` largest ``s + b`` are chosen (``b`` a selection
-  bias that never enters the weights), ``w_e = s_e / sum(s_chosen) *
-  routed_scaling_factor``. The layer is told which experts it holds
-  (``first_expert``, ``n_held``: one chip's share of an expert-parallel
-  deployment); it gathers the (token, expert) pairs whose expert is here,
-  sorts them by expert into blocks of equal size and runs the blocks that
-  hold a pair as one grouped product (no pair is dropped: the buffer is sized
-  for every pair the tokens could make). What absent experts would add is
-  left out. A shared expert runs for every token.
+- Mixers (``ops/mixers.py``, by ``DecoderConfig.mixers``): ``latent``
+  (DeepSeek-V3's latent attention with YaRN), ``gqa`` (grouped-query attention
+  without a position term), ``mamba2`` (a state-space layer: a recurrence over
+  the positions, scanned in chunks by a prefill). Each kind declares what one
+  cache slot holds for it: latents a position, keys and values a position, or
+  a recurrent state and a convolution's tail that are constant in the
+  prompt's length.
+- Feed-forwards: a dense SwiGLU, or (a layer whose parameters hold a
+  ``router``) routed experts: ``s = sigmoid(x W_r)`` in float32 over ALL the
+  published experts, the ``experts_per_token`` largest ``s + b`` are chosen
+  (``b`` a selection bias that never enters the weights), ``w_e = s_e /
+  sum(s_chosen) * routed_scaling_factor``. The layer is told which experts
+  it holds (``first_expert``, ``n_held``: one chip's share of an
+  expert-parallel deployment); it gathers the (token, expert) pairs whose
+  expert is here, sorts them by expert into blocks of equal size and runs the
+  blocks that hold a pair as one grouped product (no pair is dropped: the
+  buffer is sized for every pair the tokens could make). What absent experts
+  would add is left out. A shared expert runs for every token.
 
 bfloat16 weights and matmul operands by default; float32 for the residual
-stream, RMSNorm's statistics, the router, softmax, RoPE and the logits.
+stream, RMSNorm's statistics, the router, softmax, RoPE, a recurrent state
+with its update, and the logits.
 
-``JaxDecoder`` owns the parameters, the cache (``cache_rows x cache_len``
-latents a layer, a free list of slots) and the bucketing: a prefill launch
-pads to a row bucket and a length bucket, a step to a row bucket, so a server
-asks for a small closed set of executables and ``warm()`` compiles them all.
+``JaxDecoder`` owns the parameters, the cache (a layer's arrays are its
+mixer's slot times ``cache_rows``; one free list of slots serves every layer,
+so a row's latents, keys and values and recurrent states live under one slot
+number) and the bucketing: a prefill launch pads to a row bucket and a length
+bucket, a step to a row bucket, so a server asks for a small closed set of
+executables and ``warm()`` compiles them all. A prefill writes all of a slot
+that later steps read (a positional slot's stale entries past the row's
+position are masked; a recurrent slot is overwritten whole, with the state
+after the row's last real token), so a freed slot is handed on as it is.
 ``DecodeSession`` is what a dataflow node drives (``ops/microbatch.py``
 ``RowStepper``): rows join at a step boundary and leave when done.
 """
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from typing import Any
 
@@ -48,13 +53,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from pathway_tpu.observability import device as _dev_prof
+from pathway_tpu.ops.mixers import MIXERS, QUERY_BLOCK, _mm, _rms, rope_inv_freq, rope_tables, softmax_scale  # noqa: F401
 
 #: rows of one expert block in the grouped product: a full MXU tile for a
 #: prefill; a step's few rows take the smallest tile
 EXPERT_BLOCK = 128
-#: queries per attention block in prefill (the score matrix is never whole)
-QUERY_BLOCK = 256
-#: prompt lengths pad to a multiple of this
+#: prompt lengths pad to a multiple of this (a multiple of a recurrent layer's scan chunk)
 LENGTH_STEP = 512
 #: rows of one prefill launch while serving. A prompt of a thousand tokens
 #: fills the chip by itself, and every further row bucket multiplies what
@@ -66,10 +70,11 @@ BOS = 1  # HashTokenizer: 0 pad, 1 [CLS], 2 [SEP]
 
 @dataclass(frozen=True)
 class DecoderConfig:
-    """Shapes of the model as this process holds it. ``n_routed_experts`` is
-    the router's width (the published count); ``first_expert`` and ``n_held``
-    say which of them live here; ``vocab_size`` is the rows of the embedding
-    and of the head held here."""
+    """Shapes of the model as this process holds it. ``layer_types`` names
+    each layer's mixer (empty: ``latent`` everywhere). ``n_routed_experts``
+    is the router's width (the published count); ``first_expert`` and
+    ``n_held`` say which of them live here; ``vocab_size`` is the rows of the
+    embedding and of the head held here."""
 
     vocab_size: int = 256
     hidden_size: int = 64
@@ -99,11 +104,65 @@ class DecoderConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 1.0
     dtype: Any = jnp.bfloat16
+    # a mixer a layer; the gqa mixer's shapes; the mamba2 mixer's
+    layer_types: tuple[str, ...] = ()
+    n_kv_heads: int = 0
+    head_dim: int = 0
+    attention_multiplier: float = 1.0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # scalars on the residual stream, and whether the head is the embedding
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_embeddings: bool = False
 
     @classmethod
     def from_hf(cls, c: dict, dtype: Any = jnp.bfloat16) -> "DecoderConfig":
-        """From a ``config.json`` of the ``deepseek_v3`` / ``kimi_k2`` kind.
-        Where the file states a share (``n_routed_experts_published``,
+        """From a ``config.json``, by its ``model_type``: ``granitemoehybrid``
+        (state-space and attention layers by ``layer_types``), else the
+        ``deepseek_v3`` / ``kimi_k2`` kind (latent attention everywhere)."""
+        if c.get("model_type") == "granitemoehybrid":
+            return cls._from_hybrid(c, dtype)
+        return cls._from_latent(c, dtype)
+
+    @classmethod
+    def _from_hybrid(cls, c: dict, dtype: Any) -> "DecoderConfig":
+        kinds = {"mamba": "mamba2", "attention": "gqa"}
+        unknown = sorted(set(c["layer_types"]) - set(kinds))
+        if unknown or len(c["layer_types"]) != c["num_hidden_layers"]:
+            raise ValueError(f"layer_types names {unknown or 'another count than num_hidden_layers'}")
+        if c.get("num_local_experts", 0) or c.get("num_experts_per_tok", 0):
+            raise ValueError("routed experts beside recurrent layers are not implemented")
+        if c.get("position_embedding_type", "nope") != "nope":
+            raise ValueError("a position term in grouped-query attention (rope) is not implemented")
+        if c.get("mamba_n_groups", 1) != 1 or c.get("mamba_proj_bias", False) or c.get("attention_bias", False):
+            raise ValueError("grouped B/C (mamba_n_groups > 1) and projection biases are not implemented")
+        if c.get("hidden_act", "silu") != "silu" or c.get("normalization_function", "rmsnorm") != "rmsnorm":
+            raise ValueError("only silu and rmsnorm are implemented")
+        if c["mamba_n_heads"] * c["mamba_d_head"] != c["mamba_expand"] * c["hidden_size"]:
+            raise ValueError("mamba_n_heads * mamba_d_head is not mamba_expand * hidden_size")
+        if not c.get("mamba_conv_bias", True):
+            raise ValueError("a convolution without bias is not implemented")
+        return cls(
+            vocab_size=c["vocab_size"], hidden_size=c["hidden_size"], n_layers=c["num_hidden_layers"],
+            layer_types=tuple(kinds[t] for t in c["layer_types"]), n_heads=c["num_attention_heads"],
+            n_kv_heads=c["num_key_value_heads"], head_dim=c["hidden_size"] // c["num_attention_heads"],
+            attention_multiplier=float(c["attention_multiplier"]), intermediate_size=c["shared_intermediate_size"],
+            first_k_dense=c["num_hidden_layers"], n_routed_experts=0, n_held=0, n_shared_experts=0,
+            experts_per_token=0, rms_norm_eps=float(c["rms_norm_eps"]), ssm_heads=c["mamba_n_heads"],
+            ssm_head_dim=c["mamba_d_head"], ssm_state=c["mamba_d_state"], ssm_conv=c["mamba_d_conv"],
+            ssm_chunk=c["mamba_chunk_size"], embedding_multiplier=float(c["embedding_multiplier"]),
+            residual_multiplier=float(c["residual_multiplier"]), logits_scaling=float(c["logits_scaling"]),
+            tie_embeddings=bool(c["tie_word_embeddings"]), dtype=dtype,
+        )
+
+    @classmethod
+    def _from_latent(cls, c: dict, dtype: Any) -> "DecoderConfig":
+        """Where the file states a share (``n_routed_experts_published``,
         ``first_expert``), ``n_routed_experts`` is the count held here."""
         if c.get("n_group", 1) != 1 or c.get("topk_group", 1) != 1:
             raise ValueError("group-limited routing (n_group > 1) is not implemented")
@@ -130,6 +189,19 @@ class DecoderConfig:
         )
 
     @property
+    def mixers(self) -> tuple[str, ...]:
+        return self.layer_types or ("latent",) * self.n_layers
+
+    @property
+    def recurrent_layers(self) -> int:
+        return sum(MIXERS[kind].recurrent for kind in self.mixers)
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the convolution: the heads' inputs, then ``B`` and ``C``."""
+        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_state
+
+    @property
     def latent_dim(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
@@ -151,64 +223,7 @@ class DecoderConfig:
         return self.n_layers - self.first_k_dense
 
 
-# ---------------------------------------------------------------- RoPE (YaRN)
-
-
-def _yarn_mscale(factor: float, mscale: float) -> float:
-    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
-
-
-def rope_inv_freq(cfg: DecoderConfig) -> np.ndarray:
-    """YaRN's blend of the base frequencies (kept where a dimension turns more
-    than ``beta`` times over the original context) and the same divided by
-    ``factor`` (interpolated where it turns less)."""
-    dim = cfg.qk_rope_head_dim
-    freq = cfg.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if cfg.rope_factor <= 1:
-        return freq.astype(np.float32)
-
-    def turns_dim(turns: float) -> float:
-        return dim * math.log(cfg.rope_original_len / (turns * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
-
-    low = max(math.floor(turns_dim(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(turns_dim(cfg.rope_beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    keep = 1.0 - np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
-    return (freq / cfg.rope_factor * (1.0 - keep) + freq * keep).astype(np.float32)
-
-
-def softmax_scale(cfg: DecoderConfig) -> float:
-    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) if cfg.rope_mscale_all_dim else 1.0
-    return cfg.qk_head_dim ** -0.5 * m * m
-
-
-def _rope_tables(cfg: DecoderConfig, positions: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """cos and sin, float32, ``positions.shape + (rope/2,)``."""
-    angle = positions.astype(jnp.float32)[..., None] * jnp.asarray(rope_inv_freq(cfg))
-    scale = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
-    return jnp.cos(angle) * scale, jnp.sin(angle) * scale
-
-
-def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate the pairs (2i, 2i+1) of the last axis; float32 in and out."""
-    a, b = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
-
-
 # ------------------------------------------------------------------- pieces
-
-
-def _rms(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
-
-
-def _mm(spec: str, a: jax.Array, b: jax.Array, dtype: Any) -> jax.Array:
-    """Operands in the compute type, float32 out."""
-    precision = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
-    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype), preferred_element_type=jnp.float32,
-                      precision=precision)
 
 
 def _swiglu(x: jax.Array, p: dict, dtype: Any) -> jax.Array:
@@ -278,74 +293,24 @@ def _ffn(lp: dict, x: jax.Array, valid: jax.Array, cfg: DecoderConfig, block: in
     return y + _swiglu(x, lp["shared"], cfg.dtype), stats
 
 
-def _queries_and_latent(lp: dict, x: jax.Array, cos, sin, cfg: DecoderConfig):
-    """``x [..., d]`` after its norm -> per-head queries (no-position part,
-    rotated rotary part) and the token's cache entry ``[c_kv ; k_r ; 0...]``."""
-    dt, H = cfg.dtype, cfg.n_heads
-    cq = _rms(_mm("...d,dr->...r", x, lp["wq_a"], dt), lp["q_norm"], cfg.rms_norm_eps)
-    q = _mm("...r,re->...e", cq, lp["wq_b"], dt).reshape(*x.shape[:-1], H, cfg.qk_head_dim)
-    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
-    q_rope = _rope(q_rope, cos[..., None, :], sin[..., None, :])
-    kv = _mm("...d,dr->...r", x, lp["wkv_a"], dt)
-    c_kv = _rms(kv[..., : cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_norm_eps)
-    k_r = _rope(kv[..., cfg.kv_lora_rank:], cos, sin)
-    fill = jnp.zeros(c_kv.shape[:-1] + (cfg.cache_width - cfg.latent_dim,), jnp.float32)
-    return q_nope, q_rope, jnp.concatenate([c_kv, k_r, fill], axis=-1).astype(dt)
-
-
-def _attend_prefill(lp: dict, x: jax.Array, cos, sin, cfg: DecoderConfig):
-    """``x [R, L, d]`` -> attention output ``[R, L, d]`` float32 and the
-    cache entries ``[R, L, cache_width]``. Keys and values are up-projected from the
-    latents as the cache will hold them; queries go a block at a time
-    against the keys at or before the block's end."""
-    dt, H, kvr, nope = cfg.dtype, cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    R, L, _ = x.shape
-    q_nope, q_rope, latent = _queries_and_latent(lp, x, cos, sin, cfg)
-    kvb = _mm("rlc,ce->rle", latent[..., :kvr], lp["wkv_b"], dt).reshape(R, L, H, nope + cfg.v_head_dim)
-    k_nope, v, k_r = kvb[..., :nope].astype(dt), kvb[..., nope:].astype(dt), latent[..., kvr : cfg.latent_dim]
-    scale = softmax_scale(cfg)
-    out = []
-    for q0 in range(0, L, QUERY_BLOCK):
-        q1 = min(L, q0 + QUERY_BLOCK)
-        s = _mm("rqhd,rkhd->rhqk", q_nope[:, q0:q1], k_nope[:, :q1], dt)
-        s = s + _mm("rqhd,rkd->rhqk", q_rope[:, q0:q1], k_r[:, :q1], dt)
-        causal = jnp.arange(q1)[None, :] <= jnp.arange(q0, q1)[:, None]
-        p = jax.nn.softmax(jnp.where(causal, s * scale, -1e30), axis=-1)
-        out.append(_mm("rhqk,rkhd->rqhd", p, v[:, :q1], dt))
-    ctx = jnp.concatenate(out, axis=1).reshape(R, L, H * cfg.v_head_dim)
-    return _mm("rle,ed->rld", ctx, lp["wo"], dt), latent
-
-
-def _attend_step(lp: dict, x: jax.Array, cache_l: jax.Array, slots, positions, cos, sin, cfg: DecoderConfig):
-    """``x [R, d]``, one new token a row -> attention output ``[R, d]`` and
-    the layer's cache with the rows' new entries. Scores are taken against the
-    latents themselves: ``W_kvb``'s key half is folded into the query and its
-    value half applied after the weighted sum."""
-    dt, H, kvr, nope = cfg.dtype, cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    q_nope, q_rope, latent = _queries_and_latent(lp, x, cos, sin, cfg)
-    # the rows' slots are read as they were and the new entry set into the copy
-    # read, so that the cache's own update has no other reader and stays in place
-    # (a row at a time: a gather of the rows reads the whole layer)
-    last = cache_l.shape[0] - 1
-    lat = jnp.concatenate(
-        [jax.lax.dynamic_slice_in_dim(cache_l, jnp.minimum(slots[r], last), 1) for r in range(x.shape[0])]
-    )  # [R, cache_len, cache_width]
-    lat = lat.at[jnp.arange(lat.shape[0]), positions].set(latent, mode="drop")
-    cache_l = cache_l.at[slots, positions].set(latent, mode="drop")
-    wkv_b = lp["wkv_b"].reshape(kvr, H, nope + cfg.v_head_dim)
-    q_lat = _mm("rhd,chd->rhc", q_nope, wkv_b[..., :nope], dt)
-    s = _mm("rhc,rkc->rhk", q_lat, lat[..., :kvr], dt)
-    s = s + _mm("rhd,rkd->rhk", q_rope, lat[..., kvr : cfg.latent_dim], dt)
-    seen = jnp.arange(lat.shape[1])[None, :] <= positions[:, None]
-    p = jax.nn.softmax(jnp.where(seen[:, None, :], s * softmax_scale(cfg), -1e30), axis=-1)
-    o_lat = _mm("rhk,rkc->rhc", p, lat[..., :kvr], dt)
-    ctx = _mm("rhc,chd->rhd", o_lat, wkv_b[..., nope:], dt).reshape(x.shape[0], H * cfg.v_head_dim)
-    return _mm("re,ed->rd", ctx, lp["wo"], dt), cache_l
-
-
 def _head(params: dict, x: jax.Array, cfg: DecoderConfig):
-    logits = _mm("rd,dv->rv", _rms(x, params["norm_f"], cfg.rms_norm_eps), params["head"], cfg.dtype)
+    h = _rms(x, params["norm_f"], cfg.rms_norm_eps)
+    if cfg.tie_embeddings:
+        logits = _mm("rd,vd->rv", h, params["embed"], cfg.dtype)
+    else:
+        logits = _mm("rd,dv->rv", h, params["head"], cfg.dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+
+
+def _embed(params: dict, ids: jax.Array, cfg: DecoderConfig) -> jax.Array:
+    x = params["embed"][ids].astype(jnp.float32)
+    return x * cfg.embedding_multiplier if cfg.embedding_multiplier != 1.0 else x
+
+
+def _add(x: jax.Array, y: jax.Array, cfg: DecoderConfig) -> jax.Array:
+    return x + (y * cfg.residual_multiplier if cfg.residual_multiplier != 1.0 else y)
 
 
 def _decoder_prefill(params, cache, slots, ids, lengths, *, cfg: DecoderConfig):
@@ -357,19 +322,20 @@ def _decoder_prefill(params, cache, slots, ids, lengths, *, cfg: DecoderConfig):
     with the slots filled)."""
     R, L = ids.shape
     eps = cfg.rms_norm_eps
-    cos, sin = _rope_tables(cfg, jnp.arange(L))
+    rope = rope_tables(cfg, jnp.arange(L)) if "latent" in cfg.mixers else None
     valid = (jnp.arange(L)[None, :] < lengths[:, None]).reshape(-1)
-    x = params["embed"][ids].astype(jnp.float32)
+    x = _embed(params, ids, cfg)
     cache = list(cache)
     stats = jnp.zeros((3,), jnp.int32)
     block = min(EXPERT_BLOCK, max(8, R * L))
-    for l, lp in enumerate(params["layers"]):
-        a, latent = _attend_prefill(lp, _rms(x, lp["attn_norm"], eps).astype(cfg.dtype), cos, sin, cfg)
-        cache[l] = cache[l].at[slots, :L].set(latent, mode="drop")
-        x = x + a
+    for l, (lp, kind) in enumerate(zip(params["layers"], cfg.mixers)):
+        a, cache[l] = MIXERS[kind].prefill(
+            lp, _rms(x, lp["attn_norm"], eps).astype(cfg.dtype), cache[l], slots, lengths, rope, cfg
+        )
+        x = _add(x, a, cfg)
         h = _rms(x, lp["ffn_norm"], eps).astype(cfg.dtype).reshape(R * L, -1)
         f, st = _ffn(lp, h, valid, cfg, block)
-        x = x + f.reshape(R, L, -1)
+        x = _add(x, f.reshape(R, L, -1), cfg)
         stats = stats + st
     tokens, logits = _head(params, x[jnp.arange(R), lengths - 1], cfg)
     return jnp.concatenate([tokens, stats]), logits, cache
@@ -382,18 +348,18 @@ def _decoder_step(params, cache, rows, *, cfg: DecoderConfig):
     counts ``[R + 3]``, the logits, the cache), as ``prefill`` gives them."""
     slots, ids, positions = rows
     eps = cfg.rms_norm_eps
-    cos, sin = _rope_tables(cfg, positions)
-    valid = slots < cache[0].shape[0]
-    x = params["embed"][ids].astype(jnp.float32)
+    rope = rope_tables(cfg, positions) if "latent" in cfg.mixers else None
+    valid = slots < cache[0][0].shape[0]
+    x = _embed(params, ids, cfg)
     cache = list(cache)
     stats = jnp.zeros((3,), jnp.int32)
-    for l, lp in enumerate(params["layers"]):
-        a, cache[l] = _attend_step(
-            lp, _rms(x, lp["attn_norm"], eps).astype(cfg.dtype), cache[l], slots, positions, cos, sin, cfg
+    for l, (lp, kind) in enumerate(zip(params["layers"], cfg.mixers)):
+        a, cache[l] = MIXERS[kind].step(
+            lp, _rms(x, lp["attn_norm"], eps).astype(cfg.dtype), cache[l], slots, positions, rope, cfg
         )
-        x = x + a
+        x = _add(x, a, cfg)
         f, st = _ffn(lp, _rms(x, lp["ffn_norm"], eps).astype(cfg.dtype), valid, cfg, 8)
-        x = x + f
+        x = _add(x, f, cfg)
         stats = stats + st
     tokens, logits = _head(params, x, cfg)
     return jnp.concatenate([tokens, stats]), logits, cache
@@ -421,9 +387,13 @@ def _row_buckets(n: int) -> tuple[int, ...]:
 class JaxDecoder:
     """Parameters, cache and launch shapes of one served model.
 
-    ``params`` is the tree ``prefill`` and ``step`` read (``embed``, ``head``,
-    ``norm_f``, ``layers``: the names of ``_queries_and_latent``, ``_ffn`` and
-    ``route``). A prefill launch pads its rows to a power of two (serving
+    ``params`` is the tree ``prefill`` and ``step`` read (``embed``, ``head``
+    unless the two are tied, ``norm_f``, ``layers``: a layer's ``attn_norm``
+    and ``ffn_norm`` and the names of its mixer in ``ops/mixers.py``, of
+    ``_ffn`` and of ``route``). The cache is a list with one tuple of arrays a
+    layer, each ``cache_rows`` of what its mixer's ``slot`` declares: latents
+    or keys and values over ``cache_len`` positions, or a recurrent state and
+    a convolution's tail. A prefill launch pads its rows to a power of two (serving
     sends ``PREFILL_ROWS`` a launch) and its prompts to a multiple of
     ``LENGTH_STEP`` up to ``cache_len``; a step pads its rows to a power of
     two up to ``cache_rows``."""
@@ -436,8 +406,9 @@ class JaxDecoder:
         self.length_buckets = tuple(range(step_len, cache_len, step_len)) + (cache_len,)
 
     def new_cache(self) -> list:
-        return [jnp.zeros((self.cache_rows, self.cache_len, self.cfg.cache_width), self.cfg.dtype)
-                for _ in range(self.cfg.n_layers)]
+        return [tuple(jnp.zeros((self.cache_rows,) + shape, dtype)
+                      for shape, dtype in MIXERS[kind].slot(self.cfg, self.cache_len))
+                for kind in self.cfg.mixers]
 
     @staticmethod
     def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -463,10 +434,17 @@ class JaxDecoder:
         need = sum(len(r) * (len(r) + 1) // 2 for r in rows)
         made = R * sum((min(L, q0 + QUERY_BLOCK) - q0) * min(L, q0 + QUERY_BLOCK) for q0 in range(0, L, QUERY_BLOCK))
         st.note_pad_tokens("decoder.prefill.scores", need, made - need)
+        if self.cfg.recurrent_layers:  # tokens a recurrent layer's chunks scan: the real ones, and their padding
+            st.note_pad_tokens("decoder.prefill.scan", real, R * self.scan_chunks(L) * self.cfg.ssm_chunk - real)
         out, logits, cache = prefill(
             self.params, cache, slot_arr, _dev_prof.put(ids, "decoder.prompt_ids"), lengths, cfg=self.cfg
         )
         return out, logits, cache, L
+
+    def scan_chunks(self, length: int) -> int:
+        """Chunks a recurrent layer scans over a row of ``length`` positions
+        (0 where the model has no such layer)."""
+        return -(-length // self.cfg.ssm_chunk) if self.cfg.recurrent_layers else 0
 
     def run_step(self, cache: list, slots: list[int], ids: list[int], positions: list[int]):
         """One decode step for ``len(slots)`` live rows."""
@@ -555,7 +533,8 @@ class DecodeSession:
             got = _dev_prof.fetch(out, "decoder.first_tokens")
             real = sum(len(p) for p in prompts)
             if tok is not None:
-                _obs.end(tok, {"pathway.rows": len(chunk), "pathway.real_tokens": real, "pathway.padded_len": L})
+                _obs.end(tok, {"pathway.rows": len(chunk), "pathway.real_tokens": real, "pathway.padded_len": L,
+                               "pathway.scan_chunks": len(chunk) * m.scan_chunks(L)})
             m.note_experts(got[-3:], real)
             for (handle, _ids, max_tokens), slot, p, first in zip(chunk, slots, prompts, got.tolist()):
                 row = _Row(handle, slot, len(p), first, min(max_tokens, m.cache_len - len(p)))
@@ -618,13 +597,21 @@ def generate(model: JaxDecoder, prompts: list[np.ndarray], max_tokens: list[int]
 # --------------------------------------------------------------- the counts
 
 
-def layer_params(cfg: DecoderConfig, sparse: bool, experts: int | None = None) -> int:
-    """Parameters of one layer (``experts``: how many routed experts are
-    counted; default the held ones)."""
+def layer_params(cfg: DecoderConfig, sparse: bool = False, experts: int | None = None,
+                 mixer: str = "latent") -> int:
+    """Parameters of one layer's mixer and feed-forward (``experts``: how
+    many routed experts are counted; default the held ones)."""
     d, H = cfg.hidden_size, cfg.n_heads
-    attn = (d * cfg.q_lora_rank + cfg.q_lora_rank * H * cfg.qk_head_dim + d * cfg.latent_dim
-            + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim + cfg.v_head_dim) + H * cfg.v_head_dim * d)
+    if mixer == "latent":
+        mix = (d * cfg.q_lora_rank + cfg.q_lora_rank * H * cfg.qk_head_dim + d * cfg.latent_dim
+               + cfg.kv_lora_rank * H * (cfg.qk_nope_head_dim + cfg.v_head_dim) + H * cfg.v_head_dim * d)
+    elif mixer == "gqa":
+        mix = 2 * d * cfg.head_dim * (H + cfg.n_kv_heads)
+    else:  # mamba2: the two projections; the convolution, dt_bias, A_log, D and the gated norm's gain
+        inner = cfg.ssm_heads * cfg.ssm_head_dim
+        mix = (d * (inner + cfg.ssm_conv_dim + cfg.ssm_heads) + inner * d
+               + (cfg.ssm_conv + 1) * cfg.ssm_conv_dim + 3 * cfg.ssm_heads + inner)
     if not sparse:
-        return attn + 3 * d * cfg.intermediate_size
+        return mix + 3 * d * cfg.intermediate_size
     e = cfg.n_held if experts is None else experts
-    return attn + d * cfg.n_routed_experts + 3 * d * cfg.moe_intermediate_size * (cfg.n_shared_experts + e)
+    return mix + d * cfg.n_routed_experts + 3 * d * cfg.moe_intermediate_size * (cfg.n_shared_experts + e)

@@ -36,6 +36,20 @@ def chat() -> JaxChat:
     return JaxChat(CFG, params=init_params(TINY, 1), max_tokens=6, cache_rows=2, cache_len=64)
 
 
+@pytest.fixture(scope="module", params=["latent", "hybrid"])
+def served_chat(request, chat) -> JaxChat:
+    """The chat of the cases that go through ``pw.run()``, once a model kind:
+    latent attention with experts, and state-space layers with grouped-query
+    attention (two kinds of slot under one slot number)."""
+    if request.param == "latent":
+        return chat
+    import reference_hybrid as hybrid
+
+    cfg = DecoderConfig.from_hf(hybrid.TINY, jnp.float32)
+    assert cfg.recurrent_layers == 9 and cfg.mixers.count("gqa") == 1
+    return JaxChat(cfg, params=hybrid.init_params(hybrid.TINY, 1), max_tokens=6, cache_rows=2, cache_len=64)
+
+
 def alone(chat: JaxChat, key: int, n: int | None = None) -> str:
     """What the model gives that row by itself, through the blocking batch call."""
     return chat.func([PROMPTS[key]], [n])[0]
@@ -180,9 +194,10 @@ def test_other_batched_udfs_keep_the_flush_path(chat):
         _microbatch_factory({"a": chat(t.q), "y": Doubler()(t.x)}, t, pw.schema_from_types(a=str, y=int))
 
 
-def test_a_select_over_a_stream_answers_every_row_as_the_model_does_alone(chat):
+def test_a_select_over_a_stream_answers_every_row_as_the_model_does_alone(served_chat):
     """Through ``pw.run()`` on the normal path: rows arrive over several ticks
     with their own ``max_tokens``."""
+    chat = served_chat
     G.clear()
 
     class Questions(pw.io.python.ConnectorSubject):
@@ -203,7 +218,7 @@ def _post(port: int, route: str, payload: dict):
         return json.loads(r.read())
 
 
-def test_the_template_answers_through_v2_answer_with_its_context_documents(chat, monkeypatch):
+def test_the_template_answers_through_v2_answer_with_its_context_documents(served_chat, monkeypatch):
     """``BaseRAGQuestionAnswerer(llm=JaxChat(...))`` behind ``QARestServer``:
     same tick loop, microbatch node, server and store as any other chat. The
     loop sleeps on its period between questions, never over a row in flight."""
@@ -215,6 +230,7 @@ def test_the_template_answers_through_v2_answer_with_its_context_documents(chat,
     from pathway_tpu.xpacks.llm.prompts import prompt_qa_geometric_rag
     from pathway_tpu.xpacks.llm.question_answering import BaseRAGQuestionAnswerer
 
+    chat = served_chat
     G.clear()
     slept_with_rows: list[int] = []
     sound = TickWakeup.wait
@@ -255,5 +271,5 @@ def test_the_template_answers_through_v2_answer_with_its_context_documents(chat,
     assert len(body["context_docs"]) == 3 and all(d["text"] in texts for d in body["context_docs"])
     prompt = prompt_qa_geometric_rag("what holds w7", [d["text"] for d in body["context_docs"]])
     assert body["response"] == chat.func([prompt], [None])[0]
-    assert all(0 <= int(t) < CFG.vocab_size for t in body["response"].split())
+    assert all(0 <= int(t) < chat.model.cfg.vocab_size for t in body["response"].split())
     assert slept_with_rows and not any(slept_with_rows)
