@@ -165,6 +165,11 @@ class Node:
         """Called at tick start; source nodes emit their pending input here."""
         return []
 
+    def has_queued_input(self) -> bool:
+        """Does this source hold input that a later ``poll`` will emit? A
+        source that cannot say answers True (``Runtime.input_queued``)."""
+        return True
+
     def process(self, inputs: list[DeltaBatch | None], time: int) -> list[DeltaBatch]:
         """Consume one round of input batches, return emissions (all at ``time``)."""
         return []

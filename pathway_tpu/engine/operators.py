@@ -63,6 +63,9 @@ class StaticInputNode(Node):
         self._emitted = True
         return [self.batch_factory(time)]
 
+    def has_queued_input(self) -> bool:
+        return not self._emitted
+
 
 class StreamInputNode(Node):
     """Receives events from connector threads via a lock-protected queue.
@@ -335,6 +338,10 @@ class StreamInputNode(Node):
                 return False
         gate.cancel(1)
         return True
+
+    def has_queued_input(self) -> bool:
+        with self._lock:
+            return bool(self._pending)
 
     def poll(self, time: int) -> list[DeltaBatch]:
         gate = self.flow_gate
@@ -688,10 +695,12 @@ class MicrobatchApplyNode(Node):
     throughput — rows are buffered **across ticks** per UDF, padded to
     power-of-two buckets (``ops/microbatch.py``, compile-cache discipline) and
     launched once per bucket. Full ``max_batch`` chunks launch as soon as they
-    accumulate; the tail flushes when the oldest buffered row ages past the
-    autocommit deadline, so added latency is bounded by
-    ``autocommit_duration_ms``. Static runs flush at their single tick's
-    frontier and behave exactly like the inline path.
+    accumulate; the tail is held only while input is queued behind its tick
+    (``Runtime.input_queued``: the next tick can make the launch fuller) and
+    no longer than the autocommit deadline, so added latency is bounded by
+    ``autocommit_duration_ms``; with nothing queued it launches at the
+    frontier of the tick that brought it. Static runs flush at their single
+    tick's frontier and behave exactly like the inline path.
 
     ``mode="hold"`` (the measured default): buffered rows are invisible
     downstream until their batch completes, then appear at the flush tick —
@@ -1128,8 +1137,13 @@ class MicrobatchApplyNode(Node):
         return bool(conns) and all(d.is_finished() for d in conns)
 
     def _should_flush(self, time) -> str | None:
-        """Why the buffer flushes at this frontier (``drain``, ``deadline``),
-        or None when it keeps accumulating."""
+        """Why the tail leaves at this frontier, or None while it is held. A
+        tail is held only while holding it can make the launch fuller: while
+        input is queued behind this tick, and no longer than the deadline.
+        ``drain``: nothing more will come. ``deadline``: the oldest row has
+        been held ``flush_ms``. ``idle``: no source holds unpolled input, so
+        holding would only launch the same rows later; a runtime that cannot
+        say (no ``input_queued``) counts as queued."""
         if self._draining(time):
             return "drain"
         first = next(iter(self.waiting.values()))
@@ -1140,6 +1154,9 @@ class MicrobatchApplyNode(Node):
 
         if (_t.perf_counter() - first[1]) * 1000.0 >= deadline:
             return "deadline"
+        input_queued = getattr(self.runtime, "input_queued", None)
+        if input_queued is not None and not input_queued():
+            return "idle"
         return None
 
     def on_frontier(self, time):

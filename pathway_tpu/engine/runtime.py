@@ -132,10 +132,22 @@ class Runtime:
         #: their next steps
         self.in_flight: dict[int, int] = {}
         #: set once the graph is built: live-connector runs tick repeatedly, so
-        #: cross-tick accumulators (microbatch UDF buffers) may hold rows until
-        #: their autocommit deadline; static runs have exactly one tick and
-        #: must flush at its frontier
+        #: cross-tick accumulators (microbatch UDF buffers) may hold rows while
+        #: input is queued behind the tick (``input_queued``), up to their
+        #: autocommit deadline; static runs have exactly one tick and must
+        #: flush at its frontier
         self.streaming = False
+
+    def input_queued(self) -> bool:
+        """Does a source hold input that no tick has polled yet? A node that
+        holds rows for a fuller launch (``MicrobatchApplyNode``) asks at the
+        frontier: with nothing queued, holding them only launches the same
+        rows later. Each source answers under its own lock, and a push may
+        land right after: that costs one smaller launch, never a row."""
+        scheduler = self.scheduler
+        if scheduler is None:
+            return True
+        return any(node.has_queued_input() for node in scheduler.plan.pollers)
 
     def register_connector(self, driver: ConnectorDriver) -> None:
         self.connectors.append(driver)

@@ -277,7 +277,8 @@ class PathwayConfig:
         (embedders/rerankers): ``off`` = one call per delta block (the r5
         behavior), ``auto``/``on`` = buffer rows across ticks per (UDF, bucket)
         and launch padded power-of-two batches, holding rows until their batch
-        completes (flushed on the autocommit deadline, so added latency is
+        completes (a tail is held only while input is queued behind its tick,
+        and no longer than the autocommit deadline, so added latency is
         bounded by ``autocommit_duration_ms``), ``pending`` = same batching but
         rows appear immediately with ``PENDING`` in the UDF columns and settle
         via a retract/insert correction on the completing tick (the
@@ -305,8 +306,9 @@ class PathwayConfig:
 
     @property
     def microbatch_flush_ms(self) -> float | None:
-        """Override the buffer-age flush deadline (defaults to the runtime's
-        ``autocommit_duration_ms``)."""
+        """The longest a tail is held while input is queued behind it
+        (defaults to the runtime's ``autocommit_duration_ms``); with nothing
+        queued a tail launches at once, whatever this says."""
         raw = os.environ.get("PATHWAY_MICROBATCH_FLUSH_MS")
         return None if raw in (None, "") else float(raw)
 

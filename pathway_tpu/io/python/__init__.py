@@ -221,6 +221,10 @@ class _TimedInputNode(ops.StreamInputNode):
             self.columnarize(self.events, self.columns, self.np_dtypes)
         )
 
+    def has_queued_input(self) -> bool:
+        # events of a later time are queued as surely as a pushed row
+        return self.idx < len(self.events) or super().has_queued_input()
+
     def _hooked(self) -> bool:
         # persistence replaces push/push_many with logging wrappers as
         # INSTANCE attributes; their presence forces the per-event path

@@ -128,7 +128,8 @@ class MicrobatchDispatcher:
         order. ``only_full=True`` launches only complete ``max_batch`` chunks
         (zero padding waste) and leaves the remainder buffered — the cross-tick
         accumulation mode: the engine keeps feeding rows and flushes the tail
-        on its autocommit deadline.
+        when nothing is queued behind it, at the latest on its autocommit
+        deadline.
 
         With a declared ``length_of``, a flush of more than one launch cuts
         its launches from the rows stable-sorted by length (the partial chunk,

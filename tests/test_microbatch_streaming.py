@@ -17,7 +17,9 @@ import pytest
 
 import pathway_tpu as pw
 from pathway_tpu.engine.blocks import DeltaBatch
-from pathway_tpu.engine.operators import MicrobatchApplyNode, MicrobatchUdfSpec
+from pathway_tpu.engine.graph import EngineGraph, Node, Scheduler
+from pathway_tpu.engine.operators import MicrobatchApplyNode, MicrobatchUdfSpec, StreamInputNode
+from pathway_tpu.engine.runtime import Runtime
 from pathway_tpu.internals.errors import ERROR, PENDING
 from pathway_tpu.internals.parse_graph import G
 from pathway_tpu.internals.udfs import UDF
@@ -204,6 +206,150 @@ def test_flush_on_deadline_ordering():
     node.process([_batch(list(range(10, 22)), list(range(12)), 4)], 4)
     assert calls[1:] == [8]  # one full chunk of 8 launched, 4 rows remain
     assert len(node.waiting) == 4
+
+
+class _OpaqueSource(Node):
+    """A source that cannot say whether input is queued (the base answer)."""
+
+    name = "opaque_source"
+
+    def __init__(self):
+        super().__init__(n_inputs=0)
+
+    def poll(self, time):
+        return []
+
+
+def _live_graph(opaque=False, flush_ms=None):
+    """source -> microbatch node under a live single-process ``Runtime`` that
+    the test ticks by hand. ``arrivals[t]`` are the rows that reach the
+    source's queue while tick ``t`` runs (after its poll); ``launches`` notes
+    every flush as (reason, rows)."""
+    rt = Runtime(autocommit_duration_ms=5)
+    rt.connectors.append(_LiveDriver())
+    rt.streaming = True
+    node, calls = _make_node(max_batch=8, runtime=rt, flush_ms=flush_ms)
+    src = StreamInputNode(["x"], {"x": np.dtype(np.int64)})
+    graph = EngineGraph()
+    graph.add_node(src, [])
+    if opaque:
+        graph.add_node(_OpaqueSource(), [])
+    graph.add_node(node, [src])
+    rt.scheduler = Scheduler(graph)
+    arrivals: dict[int, list] = {}
+    launches: list[tuple[str, int]] = []
+
+    def poll(time, _poll=src.poll):
+        out = _poll(time)
+        for k, x in arrivals.pop(time, ()):
+            src.push(k, (x,))
+        return out
+
+    def flush(time, only_full=False, reason="full", _flush=node._flush):
+        held = len(node.waiting)
+        out = _flush(time, only_full=only_full, reason=reason)
+        if held != len(node.waiting):
+            launches.append((reason, held - len(node.waiting)))
+        return out
+
+    src.poll, node._flush = poll, flush
+    return rt, src, node, calls, arrivals, launches
+
+
+@pytest.mark.parametrize(
+    "case", ["idle", "queued_behind", "poller_cannot_say", "runtime_cannot_say", "arrivals_under_a_launch"]
+)
+def test_tail_is_held_only_while_input_is_queued_behind_it(case):
+    """ISSUE 35: a tail leaves at the frontier of the tick that brought it
+    when no source holds unpolled input (``idle``); with input queued it waits
+    for the tick that input brings, the deadline its upper bound; a runtime or
+    a source that cannot say is held to the deadline, as before."""
+    if case == "runtime_cannot_say":
+        node, calls = _make_node(max_batch=8, runtime=_FakeRuntime())
+        node.process([_batch([1, 2], [10, 20], 0)], 0)
+        assert node._should_flush(0) is None
+        time.sleep(0.01)  # > autocommit_duration_ms
+        assert node._should_flush(1) == "deadline"
+        return
+    rt, src, node, calls, arrivals, launches = _live_graph(
+        opaque=case == "poller_cannot_say", flush_ms=60000 if case == "queued_behind" else None
+    )
+    src.push(1, (10,))
+    src.push(2, (20,))
+    if case == "idle":
+        assert rt.input_queued()  # the two rows, until a tick polls them
+        rt.scheduler.run_tick(0)
+        assert not rt.input_queued()
+        # one launch of the minimum bucket, in the tick that brought the rows
+        assert launches == [("idle", 2)] and calls == [8] and not node.waiting
+    elif case == "queued_behind":
+        arrivals[0] = [(3, 30), (4, 40)]  # queued while tick 0 runs
+        rt.scheduler.run_tick(0)
+        assert rt.input_queued() and launches == [] and len(node.waiting) == 2
+        rt.scheduler.run_tick(1)  # the tick that input asked for: all four leave together
+        assert launches == [("idle", 4)] and calls == [8] and not node.waiting
+    elif case == "poller_cannot_say":
+        rt.scheduler.run_tick(0)
+        assert rt.input_queued() and launches == []
+        time.sleep(0.01)  # > autocommit_duration_ms
+        rt.scheduler.run_tick(1)
+        assert launches == [("deadline", 2)] and calls == [8]
+    else:
+        # batching under load needs no timer: what arrives while a launch and
+        # its tick run waits in the source's queue, and the next tick takes it
+        # all in one launch
+        arrivals[0] = [(3, 30), (4, 40), (5, 50)]
+        rt.scheduler.run_tick(0)
+        assert launches == [] and rt.input_queued()  # held: the next tick is already asked for
+        arrivals[1] = [(k, k * 10) for k in range(6, 11)]
+        rt.scheduler.run_tick(1)
+        assert launches == [] and len(node.waiting) == 5  # still under the chunk, still queued behind
+        rt.scheduler.run_tick(2)
+        # ten rows over three ticks: one full chunk on arrival, the tail at
+        # the first frontier that finds nothing behind it
+        assert launches == [("full", 8), ("idle", 2)] and calls == [8, 8]
+
+
+def _launches_of(run, monkeypatch, parent_rule: bool):
+    """The launches (inputs, in order) of ``run`` under this tree's rule or
+    the parent's (every runtime counts as holding queued input)."""
+    G.clear()
+    u = _TrackingUdf()
+    with monkeypatch.context() as m:
+        if parent_rule:
+            m.setattr(Runtime, "input_queued", lambda self: True)
+        return run(u), u.batches
+
+
+@pytest.mark.parametrize("stream", ["events", "retraction", "two_sources"])
+def test_debug_streams_launch_what_they_launched_before(stream, monkeypatch):
+    """A debug stream's later times are queued input: its tails are held as
+    before ISSUE 35 and the same rows reach the UDF in the same launches."""
+
+    def run(u):
+        # wall-clock out of the picture: only the stream's drain flushes a tail
+        monkeypatch.setenv("PATHWAY_MICROBATCH", "auto")
+        monkeypatch.setenv("PATHWAY_MICROBATCH_FLUSH_MS", "60000")
+        if stream == "events":
+            s, g = _pipeline(u)
+            return keyed_rows_of(s), rows_of(g)
+        if stream == "retraction":
+            t = pw.debug.table_from_rows(
+                KS, [(1, 10, 0, 1), (3, 13, 0, 1), (2, 20, 1, 1), (3, 13, 2, -1)], is_stream=True
+            )
+            return keyed_rows_of(t.select(t.k, y=u(t.x)))
+        # one source ends at tick 1, the other at tick 4: the first's tail
+        # waits for the second's last tick
+        a = pw.debug.table_from_rows(KS, [(i, i, i // 2, 1) for i in range(4)], is_stream=True)
+        b = pw.debug.table_from_rows(KS, [(10 + i, i, i, 1) for i in range(5)], is_stream=True)
+        t = a.concat_reindex(b)
+        return rows_of(t.select(y=u(t.x)))
+
+    new, launched = _launches_of(run, monkeypatch, parent_rule=False)
+    old, launched_before = _launches_of(run, monkeypatch, parent_rule=True)
+    assert new == old
+    # everything leaves at the drain: one launch a run ("events" runs twice, a capture each)
+    assert launched == launched_before and len(launched) == (2 if stream == "events" else 1)
 
 
 def _make_deterministic_node(max_batch=8, runtime=None):

@@ -42,13 +42,17 @@ def _stream(rows, pause=0.03, hook=None):
     return Subj()
 
 
-def _index_pipeline(subject):
-    """Live documents -> embed (microbatched) -> KNN index <- one static query."""
+def _index_pipeline(subject, on_text=None):
+    """Live documents -> embed (microbatched) -> KNN index <- one static query.
+    ``on_text`` sees every document's text in the sweep of the tick that
+    polled it, before the embedder's frontier."""
     from pathway_tpu.stdlib.indexing import BruteForceKnnFactory
     from pathway_tpu.xpacks.llm.mocks import FakeEmbedder
 
     G.clear()
     docs = pw.io.python.read(subject, schema=pw.schema_from_types(text=str))
+    if on_text is not None:
+        docs = docs.select(text=pw.apply(on_text, docs.text))
     index = BruteForceKnnFactory(
         embedder=FakeEmbedder(dimension=8, deterministic=True), reserved_space=64
     ).build_index(docs.text, docs)
@@ -220,16 +224,41 @@ def test_an_idle_tick_records_its_wait_and_nothing_else(monkeypatch):
 # ------------------------------------------------------------------- waits
 
 
-def test_oldest_wait_equals_the_deadline_in_a_one_row_flush(monkeypatch):
+@pytest.mark.parametrize("queued", [False, True])
+def test_oldest_wait_equals_the_deadline_in_a_one_row_flush(queued, monkeypatch):
+    """A tail is held only while input is queued behind its tick. A lone row
+    of a live run leaves at the frontier of the tick that brought it
+    (``idle``); rows that each find another queued behind them, and never fill
+    the chunk, are held to the 20 ms deadline and leave together."""
     monkeypatch.setenv("PATHWAY_TRACE", "on")
-    _index_pipeline(_stream(["doc 0"], pause=0.2))
+    subject = _stream(["doc 0"], pause=0.3)
+
+    def push_next(text):
+        # from the engine thread, mid-sweep: the next document is queued
+        # before this tick's frontier asks
+        i = int(text.split()[1])
+        if i < 5:
+            subject.next(text=f"doc {i + 1}")
+        return text
+
+    _index_pipeline(subject, on_text=push_next if queued else None)
     pw.run(monitoring_level="none", autocommit_duration_ms=20)
-    launches = [r for r in _records() if r[NAME] == "microbatch/launch" and r[ATTRS]["pathway.rows"] == 1]
+    launches = [r[ATTRS] for r in _records() if r[NAME] == "microbatch/launch"]
     assert launches
-    first = launches[0][ATTRS]
-    assert first["pathway.reason"] == "deadline"
-    # the 20 ms deadline, plus at most the ticks it takes to notice
-    assert 20_000_000 <= first["pathway.oldest_wait_ns"] < 120_000_000
+    if queued:
+        # the documents' embedder (the static query's launches its one row)
+        docs_node = max(launches, key=lambda a: a["pathway.rows"])["pathway.operator.id"]
+        launches = [a for a in launches if a["pathway.operator.id"] == docs_node]
+        first = launches[0]
+        assert first["pathway.reason"] == "deadline" and first["pathway.rows"] >= 2
+        # the 20 ms deadline, plus at most the ticks it takes to notice
+        assert 20_000_000 <= first["pathway.oldest_wait_ns"] < 120_000_000
+        assert sum(a["pathway.rows"] for a in launches) == 6
+    else:
+        # the document's embedder and the static query's: a row each
+        assert {(a["pathway.reason"], a["pathway.rows"]) for a in launches} == {("idle", 1)}
+        # under the period: the tick that brought it
+        assert all(a["pathway.oldest_wait_ns"] < 20_000_000 for a in launches)
 
 
 # --------------------------------------------------------------- transfers
