@@ -1289,23 +1289,32 @@ class SteppingApplyNode(MicrobatchApplyNode):
         wakeup = None if drain else getattr(rt, "wakeup", None)
         until = _t.perf_counter() + (getattr(rt, "autocommit_duration_ms", 20) or 20) / 1000.0
         finished: list[tuple[int, Any]] = []
+        steps = 0
         while True:
             finished.extend(self._admit())
             if not self.stepper.live():
+                reason = "drain" if drain else "empty"
                 break
             finished.extend(self.stepper.step())
-            if not drain and (
-                finished or _t.perf_counter() >= until or (wakeup is not None and wakeup.due())
-            ):
+            steps += 1
+            if drain:
+                continue
+            reason = ("finished" if finished else "period" if _t.perf_counter() >= until
+                      else "arrival" if wakeup is not None and wakeup.due() else None)
+            if reason is not None:
                 break
+        from pathway_tpu import observability as _obs
+
+        tracer = _obs.current()
+        if steps and tracer is not None and tracer.active:
+            tracer.event("generate/yield", {"pathway.operator.id": self.node_index, "pathway.steps": steps,
+                                            "pathway.reason": reason})
         if held is not None:  # rows in flight: the next tick starts now, not a period from now
             held[self.node_index] = len(self.inflight) + len(self.waiting) - len(finished)
         if not finished:
             return []
         keys = [k for k, _v in finished]
         entries = [self.inflight.pop(k) for k in keys]
-        from pathway_tpu import observability as _obs
-
         tok = _obs.begin("generate/finish")
         if tok is not None:
             _obs.end(tok, {

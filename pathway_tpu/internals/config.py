@@ -631,10 +631,11 @@ class PathwayConfig:
 
     @property
     def trace_buffer_spans(self) -> int:
-        """Span ring size. The default holds a whole traced 50 s benchmark
-        window of either cell twice over (31,583 records in the retrieve
-        cell, 2,716 in ingest: PERF.md §6, PR 25) — a reader that finds
-        ``dropped`` > 0 returns nothing."""
+        """Span ring size. A traced 50 s benchmark window takes 26,399
+        records in ``kimi-k2.answer-rag`` and 7,646 in the ingest cell
+        (PERF.md §6, PR 36: every kernel call is a ``device/launch`` span);
+        the retrieve cell's was not measured at PR 36 (31,583 at PR 25, before
+        the hand-over spans). A reader that finds ``dropped`` > 0 returns nothing."""
         return max(64, _env_int("PATHWAY_TRACE_BUFFER", 65536))
 
     @property
@@ -699,17 +700,15 @@ class PathwayConfig:
     def profile(self) -> str:
         """Device profiling plane: ``on`` (default — compile/shape counters,
         padding-waste accounting, device-memory gauges and the flight-recorder
-        ring, all at negligible cost), ``full`` (additionally measures the
-        host/device time split by blocking on every traced dispatch — use for
-        investigation, not steady state), or ``off``."""
+        ring, all at negligible cost) or ``off``. The host/device split of a
+        launch is the spans' (``device/launch``, ``device/fetch``) read
+        against the device trace: nothing here waits for the device."""
         raw = os.environ.get("PATHWAY_PROFILE", "on").strip().lower()
         if raw in ("", "1", "true", "yes", "on"):
             return "on"
         if raw in ("0", "false", "no", "off"):
             return "off"
-        if raw == "full":
-            return "full"
-        raise ValueError(f"PATHWAY_PROFILE must be off/on/full, got {raw!r}")
+        raise ValueError(f"PATHWAY_PROFILE must be off/on, got {raw!r}")
 
     @property
     def profile_dir(self) -> str | None:

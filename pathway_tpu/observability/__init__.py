@@ -18,12 +18,15 @@ the observability the ROADMAP's live-RAG serving target actually needs:
 Lifecycle: each runtime ``run()`` calls :func:`install_from_env` (next to the
 fault-plan install) and :func:`shutdown` in its run wrapper; ``current()`` is
 the hot-path accessor — **None when tracing is off**, so engine loops pay one
-``is None`` test. The last recording's ring stays readable after the run
+``is None`` test. A tracer that records also has its ``on_gc`` in
+``gc.callbacks`` (the collector's passes), for as long as it is installed and
+no longer. The last recording's ring stays readable after the run
 (:func:`last_recording`).
 """
 
 from __future__ import annotations
 
+import gc
 import secrets
 
 from pathway_tpu.observability import (
@@ -81,11 +84,20 @@ def end(tok: tuple, attrs: dict | None = None) -> None:
     tok[0].end(tok, attrs)
 
 
+def _bring_up(tracer: Tracer) -> Tracer:
+    global _tracer
+    _tracer = tracer
+    gc.callbacks.append(tracer.on_gc)
+    return tracer
+
+
 def _retire(emit_root: bool) -> None:
     global _tracer, _last_buffer
     tracer, _tracer = _tracer, None
     if tracer is None:
         return
+    if tracer.on_gc in gc.callbacks:
+        gc.callbacks.remove(tracer.on_gc)
     _last_buffer = tracer.buffer
     try:
         tracer.close(emit_root=emit_root)
@@ -99,7 +111,6 @@ def tick_tracer(tracer: Tracer | None) -> Tracer | None:
     a tracer up (ring only, no sink, every tick sampled) — at the first tick
     that finds a JAX profiler session — and retires it at the first tick that
     finds the session gone; without a session it costs one flag read."""
-    global _tracer
     on = spans.profiler_session_active()
     if tracer is None:
         if not on:
@@ -108,12 +119,12 @@ def tick_tracer(tracer: Tracer | None) -> Tracer | None:
             from pathway_tpu.internals.config import get_pathway_config
 
             cfg = get_pathway_config()
-            _tracer = Tracer(
+            _bring_up(Tracer(
                 trace_id=run_trace_id(),
                 process_id=cfg.process_id,
                 buffer=SpanBuffer(max_spans=cfg.trace_buffer_spans),
                 session=True,
-            )
+            ))
         tracer = _tracer
     elif tracer.session and not on:
         _retire(emit_root=False)
@@ -139,7 +150,6 @@ def install_from_env(runtime=None) -> Tracer | None:
     next to ``faults.install_from_env``): reset the per-run metrics state,
     and build a tracer when ``PATHWAY_TRACE`` is on. Idempotent per run —
     a previous run's tracer is closed first."""
-    global _tracer
     from pathway_tpu.internals.config import get_pathway_config
 
     metrics.reset()
@@ -175,13 +185,12 @@ def install_from_env(runtime=None) -> Tracer | None:
         if cfg.processes > 1:
             path = f"{path}.p{cfg.process_id}"
         sink = RotatingTraceSink(path, rotate_bytes=cfg.trace_rotate_mb * 1024 * 1024)
-    _tracer = Tracer(
+    return _bring_up(Tracer(
         trace_id=run_trace_id(),
         process_id=cfg.process_id,
         sample=cfg.trace_sample,
         buffer=SpanBuffer(max_spans=cfg.trace_buffer_spans, sink=sink),
-    )
-    return _tracer
+    ))
 
 
 def shutdown() -> None:

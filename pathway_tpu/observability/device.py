@@ -24,11 +24,12 @@ negligible cost:
   reranker params, microbatch buffers) register weakly and are summed into
   ``pathway_device_bytes{component=...}``; ``jax`` backend memory stats ride
   along when the platform exposes them (TPU/GPU — CPU returns none and the
-  gauge degrades gracefully). Under ``PATHWAY_PROFILE=full`` — and only
-  then: tracing adds no device sync — traced dispatches measure dispatch-vs-
-  ``block_until_ready`` time. :func:`put` and :func:`fetch` are the host↔device
-  crossings of the kernels' call sites, each a ``device/put`` / ``device/fetch``
-  span carrying its bytes while a tracer records.
+  gauge degrades gracefully). Tracing adds no device sync: while a tracer
+  records, every traced call is a ``device/launch`` span (label, cold) — the
+  host's cost to hand the arguments to the executable — and :func:`put` and
+  :func:`fetch` are the host↔device crossings of the kernels' call sites, each
+  a ``device/put`` / ``device/fetch`` span carrying its bytes; the device's
+  own time is the device trace's, joined on the clock.
 - **flight recorder** — bounded rings of recent ticks and device events
   (compiles, storms, launches, faults), dumped as a post-mortem JSON to
   ``PATHWAY_FLIGHT_DIR`` on ``terminate_on_error`` aborts,
@@ -132,6 +133,20 @@ def _block(out: Any) -> None:
         j.block_until_ready(out)
 
 
+_obs = None
+
+
+def _observability():
+    """``pathway_tpu.observability`` (which imports this module: resolved at
+    the first call)."""
+    global _obs
+    if _obs is None:
+        from pathway_tpu import observability
+
+        _obs = observability
+    return _obs
+
+
 _listener_registered = False
 
 
@@ -173,8 +188,8 @@ class DeviceStats:
     """Per-process device profiling state.
 
     Compile/shape tracking is process-cumulative (the XLA compile cache it
-    mirrors is, too); pad/time-split accounting resets per run via
-    :meth:`reset_run` so ``/metrics`` describes the current run.
+    mirrors is, too); pad accounting resets per run via :meth:`reset_run` so
+    ``/metrics`` describes the current run.
     """
 
     def __init__(self) -> None:
@@ -208,9 +223,6 @@ class DeviceStats:
         with self.lock:
             # label -> [real_rows, pad_rows, real_tokens, pad_tokens]
             self.pad: dict[str, list] = {}
-            # name -> [host_ns, device_ns, samples]
-            self.split: dict[str, list] = {}
-            self.device_wait_ns = 0
 
     # -- compile / shape telemetry -------------------------------------------
     def first_shape(self, label: str, bucket: Any) -> bool:
@@ -254,22 +266,6 @@ class DeviceStats:
             ent[2] += real
             ent[3] += pad
 
-    # -- host/device time split ----------------------------------------------
-    def want_split(self) -> bool:
-        """Measure the dispatch-vs-device split on this call? Only in
-        ``full`` mode: it blocks after the launch, and a traced run must
-        overlap host and device exactly where an untraced one does."""
-        return self.mode == "full"
-
-    def note_split(self, name: str, host_ns: int, device_ns: int) -> None:
-        """Per-dispatch split (traced_jit, ``full`` mode)."""
-        with self.lock:
-            ent = self.split.setdefault(name, [0, 0, 0])
-            ent[0] += host_ns
-            ent[1] += device_ns
-            ent[2] += 1
-            self.device_wait_ns += device_ns
-
 
 _stats = DeviceStats()
 
@@ -281,12 +277,10 @@ def stats() -> DeviceStats:
 def put(x: Any, label: str, dtype: Any = None):
     """``jnp.asarray(x, dtype)``: a host array crossing to the device — a
     ``device/put`` span with its bytes while a tracer records."""
-    from pathway_tpu import observability as _obs
-
-    tok = _obs.begin("device/put")
+    tok = _observability().begin("device/put")
     out = _jax_mod().numpy.asarray(x, dtype)
     if tok is not None:
-        _obs.end(tok, {"pathway.label": label, "pathway.bytes": int(out.nbytes)})
+        tok[0].end(tok, {"pathway.label": label, "pathway.bytes": int(out.nbytes)})
     return out
 
 
@@ -294,12 +288,10 @@ def fetch(x: Any, label: str):
     """``np.asarray(x)``: the host blocked until the device has the value,
     then the copy down — a ``device/fetch`` span with its bytes while a tracer
     records. Its duration is the sync; counting these spans counts syncs."""
-    from pathway_tpu import observability as _obs
-
-    tok = _obs.begin("device/fetch")
+    tok = _observability().begin("device/fetch")
     out = np.asarray(x)
     if tok is not None:
-        _obs.end(tok, {"pathway.label": label, "pathway.bytes": int(out.nbytes)})
+        tok[0].end(tok, {"pathway.label": label, "pathway.bytes": int(out.nbytes)})
     return out
 
 
@@ -308,8 +300,8 @@ def fetch(x: Any, label: str):
 
 class _TracedJit:
     """Wrapper around a jitted callable: shape-set / cold-call accounting on
-    every call, host-vs-device timing on sampled calls. Off mode costs one
-    attribute read + ``is``-test."""
+    every call, and a ``device/launch`` span around the call while a tracer
+    records. Off mode costs one attribute read + ``is``-test."""
 
     def __init__(self, label: str, fn: Callable):
         self.label = label
@@ -370,7 +362,7 @@ class _TracedJit:
             if cold:
                 _ensure_listener()
                 t0 = _time.perf_counter()
-                out = self.fn(*args, **kwargs)
+                out = self._launch(args, kwargs, True)
                 _block(out)
                 dt = _time.perf_counter() - t0
                 self.cold_calls += 1
@@ -387,14 +379,7 @@ class _TracedJit:
                     )
                     _storm_alert(self.label, len(self._seen))
                 return out
-            if st.want_split():
-                t0 = _time.perf_counter_ns()
-                out = self.fn(*args, **kwargs)
-                t1 = _time.perf_counter_ns()
-                _block(out)
-                st.note_split(self.label, t1 - t0, _time.perf_counter_ns() - t1)
-                return out
-            return self.fn(*args, **kwargs)
+            return self._launch(args, kwargs, False)
         except Exception as e:
             from pathway_tpu.internals.errors import mark_device_error
 
@@ -402,6 +387,17 @@ class _TracedJit:
             raise
         finally:
             pop_label()
+
+    def _launch(self, args: tuple, kwargs: dict, cold: bool):
+        """The call into the executable: a ``device/launch`` span (label,
+        cold) while a tracer records — the host's hand-over of the
+        arguments, a compile on a cold call, the enqueue; never a wait for
+        the device."""
+        tok = _observability().begin("device/launch")
+        out = self.fn(*args, **kwargs)
+        if tok is not None:
+            tok[0].end(tok, {"pathway.label": self.label, "pathway.cold": cold})
+        return out
 
 
 _wrappers: "weakref.WeakSet[_TracedJit]" = weakref.WeakSet()
@@ -867,7 +863,6 @@ def status_summary(runtime: Any = None) -> dict[str, Any]:
         return {"enabled": False, "mode": "off"}
     callables = _callables_view()
     with st.lock:
-        split = {k: list(v) for k, v in st.split.items()}
         compile_s = st.process_compile_s
     mem = memory_components()
     mb = _microbatch_buffer_bytes(runtime)
@@ -880,14 +875,6 @@ def status_summary(runtime: Any = None) -> dict[str, Any]:
         "callables": callables,
         "pad": _pad_view(),
         "memory": {"components": mem, "backend": backend_memory()},
-        "time_split": {
-            name: {
-                "host_ms": round(h / 1e6, 3),
-                "device_ms": round(d / 1e6, 3),
-                "samples": n,
-            }
-            for name, (h, d, n) in sorted(split.items())
-        },
         "profiler": _profile_state(),
         "flight": {
             "events": len(_recorder.events),
@@ -913,8 +900,6 @@ def heartbeat_summary() -> dict[str, Any] | None:
     callables = _callables_view()
     with st.lock:
         pad = [sum(v[0] for v in st.pad.values()), sum(v[1] for v in st.pad.values())]
-        split_host = sum(v[0] for v in st.split.values())
-        split_dev = sum(v[1] for v in st.split.values())
         compile_s = st.process_compile_s
     return {
         "compiles": sum(c["compiles"] for c in callables.values()),
@@ -926,8 +911,6 @@ def heartbeat_summary() -> dict[str, Any] | None:
         "storm": any(c["storm"] for c in callables.values()),
         "pad_rows": pad,
         "device_bytes": sum(memory_components().values()),
-        "host_ms": round(split_host / 1e6, 3),
-        "device_ms": round(split_dev / 1e6, 3),
     }
 
 
@@ -947,8 +930,6 @@ def merge_heartbeat_summaries(blocks: list[dict]) -> dict[str, Any] | None:
             sum((b.get("pad_rows") or [0, 0])[1] for b in blocks),
         ],
         "device_bytes": sum(b.get("device_bytes") or 0 for b in blocks),
-        "host_ms": round(sum(b.get("host_ms") or 0.0 for b in blocks), 3),
-        "device_ms": round(sum(b.get("device_ms") or 0.0 for b in blocks), 3),
     }
 
 

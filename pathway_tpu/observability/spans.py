@@ -6,7 +6,8 @@ serving workloads need: while the pipeline runs, every sampled tick produces
 
 - one ``tick`` span per process (child of a shared, deterministic run root),
 - child spans for each ``_sweep`` node execution, microbatch UDF launch,
-  device dispatch, persistence epoch commit, and cluster barrier round,
+  device dispatch and kernel launch, persistence epoch commit, cluster
+  barrier round, and the collector's passes (``Tracer.on_gc``),
 
 appended incrementally to a bounded in-memory ring (served by the monitoring
 server's ``/trace?since=`` endpoint) and, when configured, to a rotating
@@ -195,7 +196,12 @@ class SpanBuffer:
     OTLP span dict on the READ side — ``since()`` and the file sink's
     background writer thread, which drains new records every
     ``_SINK_FLUSH_S`` seconds. ``dropped`` counts the records the ring
-    overwrote: a reader that needs a whole window checks it is 0."""
+    overwrote: a reader that needs a whole window checks it is 0.
+
+    A collector pass can start on a thread that holds the lock (a pass runs
+    between any two bytecodes): its callback appends with ``wait`` False,
+    and where the lock is taken the record waits in ``_late`` for the next
+    append or read."""
 
     def __init__(self, max_spans: int = 8192, sink: RotatingTraceSink | None = None):
         self.max_spans = max_spans
@@ -207,6 +213,7 @@ class SpanBuffer:
         self.dropped = 0
         self._lock = threading.Lock()
         self._ring: deque[tuple[int, Any]] = deque(maxlen=max_spans)
+        self._late: deque = deque()
         self._seq = 0
         self._pending_sink: list[tuple[int, Any]] = []
         self._stop = threading.Event()
@@ -217,20 +224,34 @@ class SpanBuffer:
             )
             self._writer.start()
 
-    def append(self, record: Any) -> None:
-        with self._lock:
-            self._seq += 1
-            if len(self._ring) == self.max_spans:
-                self.dropped += 1
-            self._ring.append((self._seq, record))
-            if self.sink is not None:
-                self._pending_sink.append((self._seq, record))
+    def append(self, record: Any, wait: bool = True) -> None:
+        if not self._lock.acquire(wait):
+            self._late.append(record)
+            return
+        try:
+            self._take_late()
+            self._push(record)
+        finally:
+            self._lock.release()
+
+    def _push(self, record: Any) -> None:
+        self._seq += 1
+        if len(self._ring) == self.max_spans:
+            self.dropped += 1
+        self._ring.append((self._seq, record))
+        if self.sink is not None:
+            self._pending_sink.append((self._seq, record))
+
+    def _take_late(self) -> None:
+        while self._late:
+            self._push(self._late.popleft())
 
     def records(self) -> list:
         """The ring's raw records, oldest first (monotonic stamps, parent
         ids, threads — what a reader in this process computes self times and
         gap covers from; stays readable after the tracer is retired)."""
         with self._lock:
+            self._take_late()
             return [r for _q, r in self._ring]
 
     def since(self, seq: int, limit: int = 4096) -> tuple[list[dict], int]:
@@ -239,6 +260,7 @@ class SpanBuffer:
         actually RETURNED (not the ring head), so a slow poller drains the
         backlog over successive requests instead of silently skipping it."""
         with self._lock:
+            self._take_late()
             out = [(q, r) for q, r in self._ring if q > seq]
             if len(out) > limit:
                 out = out[:limit]
@@ -340,6 +362,10 @@ class Tracer:
         # per-thread stack of open spans: [span id, TraceAnnotation | None,
         # children recorded]
         self._tls = threading.local()
+        # the collector's pass in progress (one at a time, on any thread), and
+        # the open tick's generation-0 passes: [count, nanoseconds]
+        self._gc_t0: int | None = None
+        self._gc0 = [0, 0]
 
     def _hex_id(self, span_id: int | str) -> str:
         if isinstance(span_id, str):
@@ -476,6 +502,7 @@ class Tracer:
         suppresses every child span of the tick (head sampling)."""
         self.active = self.tick_sampled(tick)
         self._unwind(self._stack(), 0)
+        self._gc0 = [0, 0]
         tok = self.begin("tick")
         self._tick_id = tok[2] if tok is not None else None
         self.tick_span_id = self._hex_id(tok[2]) if tok is not None else None
@@ -491,6 +518,8 @@ class Tracer:
         self.current_tick = None
         attrs["pathway.tick"] = tick
         attrs["pathway.process_id"] = self.process_id
+        if self._gc0[0]:
+            attrs["pathway.gc0_passes"], attrs["pathway.gc0_ns"] = self._gc0
         self.end(tok, attrs, keep=worked)
 
     # ---------------------------------------------------- spans from stamps
@@ -509,6 +538,36 @@ class Tracer:
     def event(self, name: str, attrs: dict | None = None) -> None:
         now = _time.monotonic_ns()
         self.span(name, now, now, attrs)
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        """The ``gc.callbacks`` entry, registered by ``observability`` only
+        while this tracer records. A pass of generation 1 or 2 is a
+        ``gc/collect`` span (generation, collected) on the thread that ran
+        it, under the span open there: it holds the GIL, so every thread
+        waits on it. Generation 0's passes are too many for the ring: their
+        count and nanoseconds go on the open tick (``pathway.gc0_passes``,
+        ``pathway.gc0_ns``)."""
+        now = _time.monotonic_ns()
+        if phase == "start":
+            self._gc_t0 = now
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is None or not self.active:
+            return
+        gen = info["generation"]
+        if gen == 0:
+            if self.tick_span_id is not None:
+                self._gc0[0] += 1
+                self._gc0[1] += now - t0
+            return
+        st = getattr(self._tls, "stack", None)
+        if st:
+            st[-1][2] += 1
+            parent = st[-1][0]
+        else:
+            parent = self.root_span_id
+        attrs = {"pathway.generation": gen, "pathway.collected": info["collected"]}
+        self.buffer.append(("gc/collect", None, parent, t0, now, attrs, None, threading.get_ident()), wait=False)
 
     def first_shape(self, label: str, bucket: int) -> bool:
         """True exactly once per (udf label, padded bucket) — marks the

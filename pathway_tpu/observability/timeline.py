@@ -213,10 +213,6 @@ def derive_point(new: dict, old: dict) -> dict[str, Any]:
         useful, padded = max(0, pn[0] - po[0]), max(0, pn[1] - po[1])
         if useful + padded:
             p["device_pad_waste"] = round(padded / (useful + padded), 4)
-        for k in ("host_ms", "device_ms"):
-            d = (dev_new.get(k) or 0.0) - (dev_old.get(k) or 0.0)
-            if d > 0:
-                p[f"device_{k}"] = round(d, 3)
     fl = new.get("flow")
     if fl:
         p["flow_pressure"] = round(fl.get("pressure") or 0.0, 4)
@@ -707,8 +703,7 @@ _SUM_EXACT = {
     "serve_qps", "serve_shed_per_s", "serve_errors_per_s", "serve_timeouts_per_s",
     "rows_in_per_s", "rows_out_per_s", "backlog_rows", "flow_occupied",
     "flow_shed_per_s", "delivery_depth", "delivery_failures_per_s",
-    "canary_failed_per_s", "alerts_active", "device_compiles_per_s",
-    "device_host_ms", "device_device_ms", "tick_rate",
+    "canary_failed_per_s", "alerts_active", "device_compiles_per_s", "tick_rate",
 }
 _SUM_PREFIX = ("phase_ms:", "route_qps:")
 _MIN_EXACT = {"tick"}

@@ -532,15 +532,15 @@ class DecodeSession:
             out, _logits, self.cache, L = m.run_prefill(self.cache, slots, prompts)
             got = _dev_prof.fetch(out, "decoder.first_tokens")
             real = sum(len(p) for p in prompts)
-            if tok is not None:
-                _obs.end(tok, {"pathway.rows": len(chunk), "pathway.real_tokens": real, "pathway.padded_len": L,
-                               "pathway.scan_chunks": len(chunk) * m.scan_chunks(L)})
             m.note_experts(got[-3:], real)
             for (handle, _ids, max_tokens), slot, p, first in zip(chunk, slots, prompts, got.tolist()):
                 row = _Row(handle, slot, len(p), first, min(max_tokens, m.cache_len - len(p)))
                 self.rows[handle] = row
                 if len(row.out) >= row.max_tokens:
                     done.append(self._leave(row))
+            if tok is not None:
+                _obs.end(tok, {"pathway.rows": len(chunk), "pathway.real_tokens": real, "pathway.padded_len": L,
+                               "pathway.scan_chunks": len(chunk) * m.scan_chunks(L), "pathway.live": len(self.rows)})
         return done
 
     def step(self) -> list[tuple[Any, list[int]]]:
@@ -555,8 +555,6 @@ class DecodeSession:
             self.cache, [r.slot for r in rows], [r.out[-1] for r in rows], [r.position for r in rows]
         )
         got = _dev_prof.fetch(out, "decoder.tokens")
-        if tok is not None:
-            _obs.end(tok, {"pathway.rows": len(rows), "pathway.bucket": R})
         self.model.note_experts(got[-3:], len(rows), "decoder.step.experts")
         done = []
         for row, t in zip(rows, got.tolist()):
@@ -564,6 +562,8 @@ class DecodeSession:
             row.position += 1
             if len(row.out) >= row.max_tokens:
                 done.append(self._leave(row))
+        if tok is not None:
+            _obs.end(tok, {"pathway.rows": len(rows), "pathway.bucket": R, "pathway.live": len(self.rows)})
         return done
 
     def cancel(self, handle) -> None:

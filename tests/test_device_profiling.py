@@ -210,24 +210,6 @@ def test_cold_dispatch_span_carries_compile_ms(monkeypatch):
     assert device.stats().process_compile_s > before
 
 
-# ------------------------------------------------------------ host/device split
-
-
-def test_full_mode_records_host_device_split(monkeypatch):
-    import jax.numpy as jnp
-
-    monkeypatch.setenv("PATHWAY_PROFILE", "full")
-    device.install_from_env()
-    f = device.traced_jit("test.split", _jit_square())
-    x = jnp.ones((64,))
-    f(x)  # cold
-    f(x)  # warm, split-sampled (full mode)
-    split = device.status_summary()["time_split"]["test.split"]
-    assert split["samples"] == 1
-    assert split["host_ms"] >= 0.0 and split["device_ms"] >= 0.0
-    assert device.stats().device_wait_ns >= 0
-
-
 # -------------------------------------------------------------- /status wiring
 
 
@@ -246,7 +228,7 @@ def test_run_status_has_device_section_and_metric_families():
     stats = run_stats(rt)
     dev = stats["device"]
     assert dev["enabled"] and dev["mode"] == "on"
-    for key in ("callables", "pad", "memory", "time_split", "flight"):
+    for key in ("callables", "pad", "memory", "flight"):
         assert key in dev
     text = prometheus_text(rt)
     assert "pathway_jit_compiles_total" in text

@@ -303,21 +303,200 @@ def test_put_and_fetch_bytes_equal_the_arrays_nbytes(what, monkeypatch):
 # ---------------------------------------------------------------- no sync
 
 
-@pytest.mark.parametrize("mode,blocks", [("on", 0), ("full", 1)])
-def test_a_traced_warm_call_blocks_only_in_full_mode(mode, blocks, monkeypatch):
+def test_a_traced_warm_call_never_blocks(monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    monkeypatch.setenv("PATHWAY_PROFILE", mode)
+    monkeypatch.setenv("PATHWAY_PROFILE", "on")
     _active_tracer(monkeypatch)  # a sampled tick is open
-    f = device.traced_jit(f"test.nosync.{mode}", jax.jit(lambda x: x * x))
+    f = device.traced_jit("test.nosync.on", jax.jit(lambda x: x * x))
     x = jnp.ones((32,))
     f(x)  # cold: blocks to time the compile
     called = []
     monkeypatch.setattr(device, "_block", lambda out: called.append(1))
+    for _ in range(3):
+        f(x)
+    assert called == []
+
+
+@pytest.mark.parametrize("raw,mode", [("on", "on"), ("off", "off"), ("full", None)])
+def test_the_profile_knob_takes_off_and_on_only(raw, mode, monkeypatch):
+    from pathway_tpu.internals.config import get_pathway_config
+
+    monkeypatch.setenv("PATHWAY_PROFILE", raw)
+    if mode is None:
+        with pytest.raises(ValueError, match="off/on"):
+            get_pathway_config().profile
+    else:
+        assert get_pathway_config().profile == mode
+
+
+# ------------------------------------------------------------ device/launch
+
+
+def test_a_traced_call_records_its_launch_cold_on_the_first_call_only(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    f = device.traced_jit("test.launch", jax.jit(lambda x: x + 1))
+    x = jnp.ones((8,))
+    f(x)  # no tracer: nothing recorded, nothing to record into
+    assert obs.current() is None
+    tracer = _active_tracer(monkeypatch)
+    tracer.buffer._ring.clear()
+    g = device.traced_jit("test.launch.fresh", jax.jit(lambda x: x - 1))
+    g(x)
+    g(x)
+    f(x)  # compiled before the tracer came up: warm
+    launches = [r for r in tracer.buffer.records() if r[NAME] == "device/launch"]
+    assert [(r[ATTRS]["pathway.label"], r[ATTRS]["pathway.cold"]) for r in launches] == [
+        ("test.launch.fresh", True), ("test.launch.fresh", False), ("test.launch", False)]
+    for r in launches:
+        assert r[PARENT] == tracer._tick_id and r[THREAD] is not None and r[T0] <= r[T1]
+    obs.shutdown()
+    n = len(obs.last_recording().records())
     f(x)
-    assert len(called) == blocks
-    assert device.stats().want_split() is (mode == "full")
+    assert len(obs.last_recording().records()) == n
+
+
+# ---------------------------------------------------------------- decode loop
+
+
+@pytest.fixture(scope="module")
+def chat():
+    import jax.numpy as jnp
+    from reference_decoder import TINY, init_params
+
+    from pathway_tpu.ops.decoder import DecoderConfig
+    from pathway_tpu.xpacks.llm.llms import JaxChat
+
+    return JaxChat(DecoderConfig.from_hf(TINY, jnp.float32), params=init_params(TINY, 1), max_tokens=6,
+                   cache_rows=2, cache_len=64)
+
+
+def _stepping_node(chat, rt):
+    from pathway_tpu.engine.operators import MicrobatchUdfSpec, SteppingApplyNode
+
+    spec = MicrobatchUdfSpec("a", lambda b: ([b.data["q"]], [b.data["n"]]), chat.func, ["max_tokens"], False)
+    node = SteppingApplyNode(["q", "a"], ["q"], lambda b: {"q": b.data["q"]}, [spec],
+                             stepper=chat.microbatch_stepper(), runtime=rt)
+    node.node_index = 3
+    return node
+
+
+def _questions(keys, ns):
+    from pathway_tpu.engine.blocks import DeltaBatch
+
+    return DeltaBatch.from_rows(keys, [(f"question {k} about w{k}", n) for k, n in zip(keys, ns)], ["q", "n"], 0)
+
+
+@pytest.mark.parametrize("reason", ["finished", "period", "arrival", "drain"])
+def test_the_decode_loop_stamps_its_launches_and_why_it_yields(reason, chat, monkeypatch):
+    import types
+
+    tracer = _active_tracer(monkeypatch)
+    period_ms = 1e-6 if reason == "period" else 60_000
+    rt = types.SimpleNamespace(streaming=reason != "drain", connectors=[], autocommit_duration_ms=period_ms,
+                               wakeup=TickWakeup(), in_flight={})
+    node = _stepping_node(chat, rt)
+    node.process([_questions([1, 2], [4, 6])], 0)
+    if reason == "arrival":
+        rt.wakeup.request()
+    node.on_frontier(0)
+    recs = tracer.buffer.records()
+    [y] = [r for r in recs if r[NAME] == "generate/yield"]
+    # prefills give the first token; 4 tokens end the first row after 3 steps, 6 the second after 5
+    steps = {"finished": 3, "period": 1, "arrival": 1, "drain": 5}[reason]
+    assert y[ATTRS]["pathway.reason"] == reason and y[ATTRS]["pathway.steps"] == steps and y[T0] == y[T1]
+    launches = [r for r in recs if r[NAME] in ("generate/prefill", "generate/step")]
+    assert [r[NAME] for r in launches] == ["generate/prefill"] * 2 + ["generate/step"] * steps
+    live = {"finished": [1, 2, 2, 2, 1], "period": [1, 2, 2], "arrival": [1, 2, 2], "drain": [1, 2, 2, 2, 1, 1, 0]}
+    assert [r[ATTRS]["pathway.live"] for r in launches] == live[reason]
+    for r in launches:
+        kids = {k[NAME]: k for k in recs if k[PARENT] == r[SID]}
+        assert kids["device/launch"][ATTRS]["pathway.label"] == r[NAME].replace("generate/", "decoder.")
+        assert r[T0] <= kids["device/launch"][T0] <= kids["device/launch"][T1] <= kids["device/fetch"][T0]
+        assert kids["device/fetch"][T1] <= r[T1]
+    assert y[T0] >= launches[-1][T1]
+
+
+def test_a_frontier_that_steps_nothing_yields_no_event(chat, monkeypatch):
+    import types
+
+    tracer = _active_tracer(monkeypatch)
+    rt = types.SimpleNamespace(streaming=True, connectors=[], autocommit_duration_ms=20, wakeup=TickWakeup(),
+                               in_flight={})
+    assert _stepping_node(chat, rt).on_frontier(0) == []
+    assert not [r for r in tracer.buffer.records() if r[NAME] == "generate/yield"]
+
+
+# ------------------------------------------------------------------ collector
+
+
+def _our_callbacks():
+    import gc
+
+    return [cb for cb in gc.callbacks if isinstance(getattr(cb, "__self__", None), Tracer)]
+
+
+def test_a_collection_under_a_recording_tracer_is_a_span_on_its_thread(monkeypatch):
+    import gc
+    import threading
+
+    assert _our_callbacks() == []  # no tracer records
+    tracer = _active_tracer(monkeypatch)
+    assert _our_callbacks() == [tracer.on_gc]
+    tok = tracer.begin("sweep/x")
+    gc.collect()
+    tracer.end(tok)
+    recs = tracer.buffer.records()
+    [sweep] = [r for r in recs if r[NAME] == "sweep/x"]
+    passes = [r for r in recs if r[NAME] == "gc/collect"]
+    full = [r for r in passes if r[ATTRS]["pathway.generation"] == 2]
+    assert len(full) == 1 and full[0][ATTRS]["pathway.collected"] >= 0
+    assert full[0][THREAD] == threading.get_ident() and full[0][PARENT] == sweep[SID]
+    assert sweep[T0] <= full[0][T0] <= full[0][T1] <= sweep[T1]
+    obs.shutdown()
+    assert _our_callbacks() == []
+
+
+def test_a_sessions_tracer_takes_its_callback_with_it(monkeypatch):
+    state = {"on": True}
+    monkeypatch.setattr(spans, "profiler_session_active", lambda: state["on"])
+    tracer = obs.tick_tracer(None)
+    assert tracer is not None and tracer.session and _our_callbacks() == [tracer.on_gc]
+    state["on"] = False
+    assert obs.tick_tracer(tracer) is None
+    assert _our_callbacks() == []
+
+
+def test_generation_0_passes_sum_into_the_tick(monkeypatch):
+    tr = Tracer(trace_id="ef" * 16, buffer=SpanBuffer(max_spans=16))
+    tick = tr.begin_tick(4)
+    for _ in range(3):
+        tr.on_gc("start", {"generation": 0})
+        tr.on_gc("stop", {"generation": 0, "collected": 0, "uncollectable": 0})
+    tr.end_tick(4, tick)
+    tick2 = tr.begin_tick(5)
+    tr.end_tick(5, tick2)
+    ticks = [r for r in tr.buffer.records() if r[NAME] == "tick"]
+    assert ticks[0][ATTRS]["pathway.gc0_passes"] == 3 and ticks[0][ATTRS]["pathway.gc0_ns"] >= 0
+    assert "pathway.gc0_passes" not in ticks[1][ATTRS]  # counted afresh each tick
+    assert not [r for r in tr.buffer.records() if r[NAME] == "gc/collect"]
+
+
+def test_a_pass_inside_a_holder_of_the_ring_lock_waits_for_the_next_append():
+    """A pass runs between any two bytecodes, the ring's own lock held or
+    not: its record never blocks on that lock."""
+    tr = Tracer(trace_id="12" * 16, buffer=SpanBuffer(max_spans=16))
+    tick = tr.begin_tick(0)
+    with tr.buffer._lock:
+        tr.on_gc("start", {"generation": 2})
+        tr.on_gc("stop", {"generation": 2, "collected": 5, "uncollectable": 0})
+        assert list(tr.buffer._late)
+    tr.end_tick(0, tick)
+    names = [r[NAME] for r in tr.buffer.records()]
+    assert names == ["gc/collect", "tick"] and not tr.buffer._late
 
 
 # ----------------------------------------------------- readable after the run

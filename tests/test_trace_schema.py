@@ -155,6 +155,9 @@ def test_all_emitted_span_kinds_round_trip(tmp_path, monkeypatch):
         # machinery the audit plane uses (provoking a real data-corruption
         # fault here would abort the run)
         tracer = obs.current()
+        import gc
+
+        gc.collect()  # a pass of generation 2 while the tracer records: a gc/collect span
         tracer.event(
             "audit/violation",
             {
@@ -190,6 +193,8 @@ def test_all_emitted_span_kinds_round_trip(tmp_path, monkeypatch):
     assert any(n.startswith("microbatch/") or n == "device/dispatch" for n in names), names
     assert "serve/respond" in names, names
     assert "audit/violation" in names
+    # PR 36: every kernel call's hand-over, and the collector's passes
+    assert "device/launch" in names and "gc/collect" in names, names
     # request-plane spans (7-tuple records with per-request trace ids)
     assert "serve/request" in names and "serve/admission" in names, names
     req_spans = [s for s in spans if s["name"] == "serve/request"]
@@ -212,6 +217,38 @@ def test_all_emitted_span_kinds_round_trip(tmp_path, monkeypatch):
     sink_names = {s["name"] for s in sink_spans}
     assert "tick" in sink_names
     assert "serve/request" in sink_names, "request spans missing from the file sink"
+
+
+def test_decode_loop_and_collector_span_shapes():
+    """Unit (PR 36): ``device/launch`` (a bool attribute), the decode loop's
+    ``pathway.live`` and ``generate/yield`` event, a ``gc/collect`` span and
+    a tick carrying its generation-0 sums, through both serializers."""
+    from pathway_tpu.observability.spans import SpanBuffer, Tracer
+
+    tr = Tracer(trace_id="ab" * 16, buffer=SpanBuffer(max_spans=64))
+    tick = tr.begin_tick(1)
+    step = tr.begin("generate/step")
+    tr.end(tr.begin("device/launch"), {"pathway.label": "decoder.step", "pathway.cold": False})
+    tr.end(tr.begin("device/fetch"), {"pathway.label": "decoder.tokens", "pathway.bytes": 16})
+    tr.end(step, {"pathway.rows": 2, "pathway.bucket": 2, "pathway.live": 1})
+    tr.event("generate/yield", {"pathway.operator.id": 3, "pathway.steps": 4, "pathway.reason": "period"})
+    for gen in (0, 2):
+        tr.on_gc("start", {"generation": gen})
+        tr.on_gc("stop", {"generation": gen, "collected": 7, "uncollectable": 0})
+    tr.end_tick(1, tick)
+    batch = list(tr.buffer._ring)
+    dict_spans = [tr._materialize(q, r) for q, r in batch]
+    line_spans = validate_export_line(tr._serialize_batch(batch))
+    assert [s["name"] for s in line_spans] == [s["name"] for s in dict_spans] == [
+        "device/launch", "device/fetch", "generate/step", "generate/yield", "gc/collect", "tick"]
+    for span in dict_spans:
+        validate_span(span)
+    attrs = {s["name"]: {a["key"]: a["value"] for a in s["attributes"]} for s in line_spans}
+    assert attrs["device/launch"]["pathway.cold"] == {"boolValue": False}
+    assert attrs["generate/step"]["pathway.live"] == {"intValue": "1"}
+    assert attrs["generate/yield"]["pathway.reason"] == {"stringValue": "period"}
+    assert attrs["gc/collect"]["pathway.generation"] == {"intValue": "2"}
+    assert attrs["tick"]["pathway.gc0_passes"] == {"intValue": "1"}
 
 
 def test_request_plane_span_record_shapes():
