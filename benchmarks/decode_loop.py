@@ -29,7 +29,12 @@ unchanged), then prints one more line, ``{"decode_loop": {...}}``:
   shifts of the device's times that keep every launch inside its stamps, per
   tenth of the window, the marker's error (ring against annotation), and the
   share of the host gaps in idle time on the trace's own clock, with the gap
-  ended at the hand-over's end (the reader's) and at its start.
+  ended at the hand-over's end (the reader's) and at its start. Where the
+  session launches a step before it fetches the one before, a
+  ``generate/step`` span hands over one step and fetches another, so these
+  pairs hold only for a program without that lookahead;
+  ``step_after_step_gap_us`` (quartiles of the chip's idle time between two
+  consecutive decode steps) and the share of them within 100 us hold for both.
 
 A chip tool, not a benchmark: ``chipbench.run`` refuses to run off a TPU."""
 
@@ -136,6 +141,10 @@ def clock(events: list[tuple], rep: dict, trace: dict) -> dict:
     """The ``clock`` entry of the module's docstring, all on the trace's clock."""
     host, dev = host_launches(events), device_launches(events)
     out: dict = {"host_launches": len(host), "device_launches": len(dev)}
+    # a step right after a step is one of a row's answer: the chip's idle time between the two
+    after = [b[1] - a[2] for a, b in zip(dev, dev[1:]) if a[0] == b[0] == "decoder.step"]
+    out["step_after_step_gap_us"] = quartiles_us(after)
+    out["step_after_step_within_100us_pct"] = 100.0 * sum(g <= 100_000 for g in after) / len(after) if after else None
     if not host or not dev:
         return out
     k, pairs = pair(host, dev)

@@ -1176,7 +1176,8 @@ class SteppingApplyNode(MicrobatchApplyNode):
     next step, so it never waits for another row's result. At each frontier
     the node admits, then runs steps until a row finishes, an arrival asks the
     loop for a tick (``TickWakeup.due``) or the autocommit period has passed —
-    the cadence every other deadline of the graph rides on — and yields; the
+    the cadence every other deadline of the graph rides on — and yields (the
+    decoder's next step still running on the chip); the
     finished rows are emitted in that tick, each under its own key, in the
     order they finished. It tells the runtime how many rows it holds
     (``Runtime.in_flight``), and while there are any the loop does not sleep

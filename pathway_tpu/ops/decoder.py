@@ -33,14 +33,16 @@ with its update, and the logits.
 ``JaxDecoder`` owns the parameters, the cache (a layer's arrays are its
 mixer's slot times ``cache_rows``; one free list of slots serves every layer,
 so a row's latents, keys and values and recurrent states live under one slot
-number) and the bucketing: a prefill launch pads to a row bucket and a length
-bucket, a step to a row bucket, so a server asks for a small closed set of
-executables and ``warm()`` compiles them all. A prefill writes all of a slot
+number, and so does its newest token, in one ``[cache_rows]`` array after the
+layers' arrays) and the bucketing: a prefill launch pads to a row bucket and a
+length bucket, a step to a row bucket, so a server asks for a small closed set
+of executables and ``warm()`` compiles them all. A prefill writes all of a slot
 that later steps read (a positional slot's stale entries past the row's
 position are masked; a recurrent slot is overwritten whole, with the state
 after the row's last real token), so a freed slot is handed on as it is.
 ``DecodeSession`` is what a dataflow node drives (``ops/microbatch.py``
-``RowStepper``): rows join at a step boundary and leave when done.
+``RowStepper``): rows join at a step boundary and leave when done; it keeps
+one step of lookahead, launching step k+1 before it fetches step k.
 """
 
 from __future__ import annotations
@@ -319,7 +321,7 @@ def _decoder_prefill(params, cache, slots, ids, lengths, *, cfg: DecoderConfig):
     (``[R + 3]`` int32: the greedy next token of each row, then ``[pairs,
     padded rows, experts hit]`` over the sparse layers — what the host
     fetches, in one array; the tokens' logits ``[R, V]`` float32; the cache
-    with the slots filled)."""
+    with the slots filled, the next token at each row's slot)."""
     R, L = ids.shape
     eps = cfg.rms_norm_eps
     rope = rope_tables(cfg, jnp.arange(L)) if "latent" in cfg.mixers else None
@@ -338,18 +340,22 @@ def _decoder_prefill(params, cache, slots, ids, lengths, *, cfg: DecoderConfig):
         x = _add(x, f.reshape(R, L, -1), cfg)
         stats = stats + st
     tokens, logits = _head(params, x[jnp.arange(R), lengths - 1], cfg)
+    cache[-1] = cache[-1].at[slots].set(tokens, mode="drop")
     return jnp.concatenate([tokens, stats]), logits, cache
 
 
 def _decoder_step(params, cache, rows, *, cfg: DecoderConfig):
     """One token a row. ``rows [3, R]`` int32 holds the rows' ``slots``, the
-    ``ids`` they feed and their ``positions`` (one array: every transfer to
-    the chip costs a step about a millisecond) -> (next tokens and expert
-    counts ``[R + 3]``, the logits, the cache), as ``prefill`` gives them."""
+    ``ids`` they feed (below 0: the newest token at the row's slot, so a step
+    can be launched before the one before it is fetched) and their
+    ``positions`` (one array: every transfer to the chip costs a step about a
+    millisecond) -> (next tokens and expert counts ``[R + 3]``, the logits,
+    the cache), as ``prefill`` gives them."""
     slots, ids, positions = rows
     eps = cfg.rms_norm_eps
     rope = rope_tables(cfg, positions) if "latent" in cfg.mixers else None
     valid = slots < cache[0][0].shape[0]
+    ids = jnp.where(ids < 0, cache[-1].at[slots].get(mode="clip"), ids)
     x = _embed(params, ids, cfg)
     cache = list(cache)
     stats = jnp.zeros((3,), jnp.int32)
@@ -362,6 +368,7 @@ def _decoder_step(params, cache, rows, *, cfg: DecoderConfig):
         x = _add(x, f, cfg)
         stats = stats + st
     tokens, logits = _head(params, x, cfg)
+    cache[-1] = cache[-1].at[slots].set(tokens, mode="drop")
     return jnp.concatenate([tokens, stats]), logits, cache
 
 
@@ -393,7 +400,8 @@ class JaxDecoder:
     ``_ffn`` and of ``route``). The cache is a list with one tuple of arrays a
     layer, each ``cache_rows`` of what its mixer's ``slot`` declares: latents
     or keys and values over ``cache_len`` positions, or a recurrent state and
-    a convolution's tail. A prefill launch pads its rows to a power of two (serving
+    a convolution's tail; after them one ``[cache_rows]`` int32 array, each
+    slot's newest token. A prefill launch pads its rows to a power of two (serving
     sends ``PREFILL_ROWS`` a launch) and its prompts to a multiple of
     ``LENGTH_STEP`` up to ``cache_len``; a step pads its rows to a power of
     two up to ``cache_rows``."""
@@ -408,7 +416,7 @@ class JaxDecoder:
     def new_cache(self) -> list:
         return [tuple(jnp.zeros((self.cache_rows,) + shape, dtype)
                       for shape, dtype in MIXERS[kind].slot(self.cfg, self.cache_len))
-                for kind in self.cfg.mixers]
+                for kind in self.cfg.mixers] + [jnp.zeros((self.cache_rows,), jnp.int32)]
 
     @staticmethod
     def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -447,7 +455,9 @@ class JaxDecoder:
         return -(-length // self.cfg.ssm_chunk) if self.cfg.recurrent_layers else 0
 
     def run_step(self, cache: list, slots: list[int], ids: list[int], positions: list[int]):
-        """One decode step for ``len(slots)`` live rows."""
+        """One decode step for ``len(slots)`` live rows; an id below 0 feeds
+        the newest token at the row's slot, as the launches before this one
+        left it."""
         n = len(slots)
         R = self._bucket(n, self.step_buckets)
         arr = np.zeros((3, R), np.int32)
@@ -489,11 +499,12 @@ class JaxDecoder:
 
 
 class _Row:
-    __slots__ = ("handle", "slot", "position", "out", "max_tokens")
+    __slots__ = ("handle", "slot", "position", "out", "max_tokens", "due")
 
     def __init__(self, handle, slot: int, position: int, first: int, max_tokens: int):
-        self.handle, self.slot, self.position = handle, slot, position
+        self.handle, self.slot, self.position = handle, slot, position  # position: what the next launch feeds
         self.out, self.max_tokens = [first], max_tokens
+        self.due = 1  # tokens the row holds once every launched step is fetched
 
 
 class DecodeSession:
@@ -502,13 +513,23 @@ class DecodeSession:
     and they join the decoding rows at the next ``step``; a row leaves, and
     its slot is free again, when it has ``max_tokens`` tokens (greedy, no
     end-of-sequence id) or is cancelled. A row's tokens do not depend on
-    which rows it shared its launches with."""
+    which rows it shared its launches with.
+
+    One step of lookahead: a step feeds each row the newest token at its slot
+    on the chip, so ``step`` launches the next step for the rows whose budget
+    goes on before it fetches the step launched last, and the chip runs it
+    while the host books the tokens, yields and comes back. At most one
+    launched step is unfetched. Every launch threads the cache through the
+    one before it, so a row cancelled under that step is skipped when it is
+    booked, and its slot can be prefilled again at once."""
 
     def __init__(self, model: JaxDecoder):
         self.model = model
         self.cache = model.new_cache()
         self.free_slots = list(range(model.cache_rows))
         self.rows: dict[Any, _Row] = {}
+        #: the step launched and not yet fetched: (device tokens, rows stepped, bucket)
+        self._pending: tuple[Any, list[_Row], int] | None = None
 
     def free(self) -> int:
         return len(self.free_slots)
@@ -544,27 +565,41 @@ class DecodeSession:
         return done
 
     def step(self) -> list[tuple[Any, list[int]]]:
-        """One decode step for every live row; returns the rows it finished."""
+        """Fetch one decode step of the live rows, the next one launched
+        before it; returns the rows the fetched step finished."""
         from pathway_tpu import observability as _obs
 
-        rows = list(self.rows.values())
-        if not rows:
+        if self._pending is None and not self.rows:
             return []
         tok = _obs.begin("generate/step")
-        out, _logits, self.cache, R = self.model.run_step(
-            self.cache, [r.slot for r in rows], [r.out[-1] for r in rows], [r.position for r in rows]
-        )
+        out, stepped, R = self._pending or self._launch(list(self.rows.values()), ahead=False)
+        more = [r for r in self.rows.values() if r.due < r.max_tokens]
+        self._pending = self._launch(more, ahead=True) if more else None
         got = _dev_prof.fetch(out, "decoder.tokens")
-        self.model.note_experts(got[-3:], len(rows), "decoder.step.experts")
+        self.model.note_experts(got[-3:], len(stepped), "decoder.step.experts")
         done = []
-        for row, t in zip(rows, got.tolist()):
+        for row, t in zip(stepped, got.tolist()):
+            if self.rows.get(row.handle) is not row:
+                continue  # cancelled while the step ran
             row.out.append(t)
-            row.position += 1
             if len(row.out) >= row.max_tokens:
                 done.append(self._leave(row))
         if tok is not None:
-            _obs.end(tok, {"pathway.rows": len(rows), "pathway.bucket": R, "pathway.live": len(self.rows)})
+            _obs.end(tok, {"pathway.rows": len(stepped), "pathway.bucket": R, "pathway.live": len(self.rows),
+                           "pathway.ahead": int(self._pending is not None)})
         return done
+
+    def _launch(self, rows: list[_Row], ahead: bool) -> tuple[Any, list[_Row], int]:
+        """One step of ``rows``, each fed the newest token at its slot;
+        counted as launched while an earlier step was unfetched or not."""
+        _dev_prof.stats().note_pad_rows("decoder.step.ahead", int(ahead), int(not ahead))
+        out, _logits, self.cache, R = self.model.run_step(
+            self.cache, [r.slot for r in rows], [-1] * len(rows), [r.position for r in rows]
+        )
+        for r in rows:
+            r.position += 1
+            r.due += 1
+        return out, rows, R
 
     def cancel(self, handle) -> None:
         row = self.rows.get(handle)

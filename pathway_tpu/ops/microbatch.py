@@ -69,7 +69,8 @@ class RowStepper(Protocol):
         join the live rows at the next ``step``. Returns rows finished at once."""
 
     def step(self) -> list[tuple[Any, Any]]:
-        """One launch over every live row; returns the rows it finished."""
+        """Advance every live row by one launch; returns the rows that are
+        finished. A stepper may leave its next launch running between calls."""
 
     def cancel(self, handle: Any) -> None:
         """Drop a live row and free its place; it is never returned."""

@@ -10,9 +10,12 @@ import numpy as np
 import pytest
 
 import reference_decoder as ref
+import reference_hybrid
 from pathway_tpu.ops import decoder as D
 
 LLM = ref.TINY
+#: the two kinds of model a session serves: latent attention with experts, and state-space with grouped-query layers
+REFERENCES = {"latent": ref, "hybrid": reference_hybrid}
 #: Kimi-K2-Instruct's published shapes (the catalog's row), one chip's share of 32
 K2 = {
     **LLM, "vocab_size": 20480, "hidden_size": 7168, "num_hidden_layers": 6, "num_attention_heads": 64,
@@ -209,3 +212,89 @@ def test_a_long_prompt_keeps_its_end_and_the_cache_bounds_the_answer():
     (out,) = D.generate(m, [long], [8])
     (tail,) = D.generate(m, [long[-127:]], [8])
     assert out == tail and len(out) == 1  # 127 of 128 positions hold the prompt: one token fits
+
+
+def tiny(kind: str, cache_rows: int = 2) -> D.JaxDecoder:
+    r = REFERENCES[kind]
+    cfg = D.DecoderConfig.from_hf(r.TINY, jnp.float32)
+    return D.JaxDecoder(cfg, r.init_params(r.TINY, 3, jnp.float32), cache_rows=cache_rows, cache_len=128)
+
+
+def host_fed(m: D.JaxDecoder, prompt, n: int) -> list[int]:
+    """A row alone, every step fed on the host the token the step before it gave."""
+    out, _l, cache, _L = m.run_prefill(m.new_cache(), [0], [prompt])
+    toks = [int(np.asarray(out)[0])]
+    for i in range(n - 1):
+        out, _l, cache, _R = m.run_step(cache, [0], [toks[-1]], [len(prompt) + i])
+        toks.append(int(np.asarray(out)[0]))
+    return toks
+
+
+@pytest.mark.parametrize("kind", sorted(REFERENCES))
+def test_a_session_with_lookahead_gives_the_tokens_of_steps_fed_from_the_host(kind, monkeypatch):
+    """Two slots. ``a`` (5 tokens) and ``c`` (2) decode; ``b`` (1) is done at
+    its prefill; ``d`` joins while a step is pending and is cancelled under the
+    next one, and ``e`` is prefilled into its slot before that step is
+    fetched; then a drain. Every row's tokens are what it gives alone with
+    host ids, ``d`` is never returned, and each launch is counted as launched
+    ahead of an unfetched step or not: only the first found none."""
+    from pathway_tpu import observability as obs
+    from pathway_tpu.observability import device as prof
+
+    m = tiny(kind)
+    r = REFERENCES[kind]
+    rng = np.random.default_rng(8)
+    p = {h: rng.integers(3, r.TINY["vocab_size"], size=n).astype(np.int32)
+         for h, n in zip("abcde", (37, 9, 21, 50, 4))}
+    want = {h: host_fed(m, p[h], n) for h, n in zip("abce", (5, 1, 2, 3))}
+    monkeypatch.setenv("PATHWAY_TRACE", "on")
+    tracer = obs.install_from_env()  # the run's counters start afresh
+    before = list(prof.stats().pad.get("decoder.step.ahead", [0, 0, 0, 0]))
+    try:
+        tracer.begin_tick(0)
+        s, got = D.DecodeSession(m), {}
+        assert s.admit([("a", p["a"], 5), ("c", p["c"], 2)]) == []
+        got.update(s.step())  # launches a and c, then a again before it fetches: c is done
+        assert set(got) == {"c"} and s.live() == 1 and s.free() == 1
+        got.update(s.admit([("b", p["b"], 1)]))  # done at its prefill, its slot free again
+        assert s.admit([("d", p["d"], 6)]) == [] and s.free() == 0
+        got.update(s.step())  # launches a and d, fetches a's
+        s.cancel("d")  # under the pending step
+        assert s.free() == 1 and s.admit([("e", p["e"], 3)]) == []  # d's slot, prefilled before d's step is fetched
+        while s.live():
+            got.update(s.step())
+        spans, _ = tracer.buffer.since(0)
+    finally:
+        obs.shutdown()
+    assert got == want
+    assert np.subtract(prof.stats().pad["decoder.step.ahead"], before).tolist() == [4, 1, 0, 0]
+    ahead = [{a["key"]: a["value"] for a in x["attributes"]}["pathway.ahead"] for x in spans
+             if x["name"] == "generate/step"]
+    assert ahead == [{"intValue": "1"}] * 4 + [{"intValue": "0"}]  # the last fetch left nothing to launch
+
+
+@pytest.mark.parametrize("kind", sorted(REFERENCES))
+def test_a_host_id_is_fed_as_given_and_a_negative_one_is_the_slots_newest_token(kind):
+    """Steps fed tokens that are not the greedy ones (as the benchmark's
+    replay feeds the served answer) give the full forward pass over those
+    tokens; fed -1 they give what the greedy tokens fed from the host give."""
+    m = tiny(kind, cache_rows=4)
+    r = REFERENCES[kind]
+    rows = [np.asarray(x, np.int32) for x in np.random.default_rng(9).integers(3, r.TINY["vocab_size"], (3, 12))]
+    fed = np.random.default_rng(10).integers(3, r.TINY["vocab_size"], (3, 4))
+    slots = [2, 0, 3]
+
+    def steps(ids_of):
+        out, _l, cache, _L = m.run_prefill(m.new_cache(), slots, rows)
+        toks, logs = np.asarray(out)[:3], []
+        for i in range(4):
+            out, logits, cache, _R = m.run_step(cache, slots, ids_of(i, toks), [12 + i] * 3)
+            toks = np.asarray(out)[:3]
+            logs.append(np.asarray(logits)[:3])
+        return np.stack(logs, 1)
+
+    given = steps(lambda i, _t: fed[:, i].tolist())
+    for j, row in enumerate(rows):
+        full = r.forward(m.params, r.TINY, list(row) + fed[j].tolist(), "f32", width=128)
+        np.testing.assert_allclose(given[j], full[len(row):], atol=2e-5, rtol=0)
+    np.testing.assert_array_equal(steps(lambda _i, _t: [-1] * 3), steps(lambda _i, t: t.tolist()))

@@ -412,11 +412,20 @@ def test_the_decode_loop_stamps_its_launches_and_why_it_yields(reason, chat, mon
     assert [r[NAME] for r in launches] == ["generate/prefill"] * 2 + ["generate/step"] * steps
     live = {"finished": [1, 2, 2, 2, 1], "period": [1, 2, 2], "arrival": [1, 2, 2], "drain": [1, 2, 2, 2, 1, 1, 0]}
     assert [r[ATTRS]["pathway.live"] for r in launches] == live[reason]
+    ahead = {"finished": [1, 1, 1], "period": [1], "arrival": [1], "drain": [1, 1, 1, 1, 0]}
+    assert [r[ATTRS]["pathway.ahead"] for r in launches if r[NAME] == "generate/step"] == ahead[reason]
+    first = True
     for r in launches:
-        kids = {k[NAME]: k for k in recs if k[PARENT] == r[SID]}
-        assert kids["device/launch"][ATTRS]["pathway.label"] == r[NAME].replace("generate/", "decoder.")
-        assert r[T0] <= kids["device/launch"][T0] <= kids["device/launch"][T1] <= kids["device/fetch"][T0]
-        assert kids["device/fetch"][T1] <= r[T1]
+        kids = [k for k in recs if k[PARENT] == r[SID]]
+        handed = [k for k in kids if k[NAME] == "device/launch"]
+        [fetch] = [k for k in kids if k[NAME] == "device/fetch"]
+        # a step hands over the next step before it fetches; the loop's first also the step it fetches
+        assert len(handed) == (int(first) + r[ATTRS]["pathway.ahead"] if r[NAME] == "generate/step" else 1)
+        first = first and r[NAME] != "generate/step"
+        for k in handed:
+            assert k[ATTRS]["pathway.label"] == r[NAME].replace("generate/", "decoder.")
+            assert r[T0] <= k[T0] <= k[T1] <= fetch[T0]
+        assert fetch[T1] <= r[T1]
     assert y[T0] >= launches[-1][T1]
 
 

@@ -221,8 +221,9 @@ def test_all_emitted_span_kinds_round_trip(tmp_path, monkeypatch):
 
 def test_decode_loop_and_collector_span_shapes():
     """Unit (PR 36): ``device/launch`` (a bool attribute), the decode loop's
-    ``pathway.live`` and ``generate/yield`` event, a ``gc/collect`` span and
-    a tick carrying its generation-0 sums, through both serializers."""
+    ``pathway.live`` and ``pathway.ahead`` (whether the call left the next
+    step running) and ``generate/yield`` event, a ``gc/collect`` span and a
+    tick carrying its generation-0 sums, through both serializers."""
     from pathway_tpu.observability.spans import SpanBuffer, Tracer
 
     tr = Tracer(trace_id="ab" * 16, buffer=SpanBuffer(max_spans=64))
@@ -230,7 +231,7 @@ def test_decode_loop_and_collector_span_shapes():
     step = tr.begin("generate/step")
     tr.end(tr.begin("device/launch"), {"pathway.label": "decoder.step", "pathway.cold": False})
     tr.end(tr.begin("device/fetch"), {"pathway.label": "decoder.tokens", "pathway.bytes": 16})
-    tr.end(step, {"pathway.rows": 2, "pathway.bucket": 2, "pathway.live": 1})
+    tr.end(step, {"pathway.rows": 2, "pathway.bucket": 2, "pathway.live": 1, "pathway.ahead": 1})
     tr.event("generate/yield", {"pathway.operator.id": 3, "pathway.steps": 4, "pathway.reason": "period"})
     for gen in (0, 2):
         tr.on_gc("start", {"generation": gen})
@@ -246,6 +247,7 @@ def test_decode_loop_and_collector_span_shapes():
     attrs = {s["name"]: {a["key"]: a["value"] for a in s["attributes"]} for s in line_spans}
     assert attrs["device/launch"]["pathway.cold"] == {"boolValue": False}
     assert attrs["generate/step"]["pathway.live"] == {"intValue": "1"}
+    assert attrs["generate/step"]["pathway.ahead"] == {"intValue": "1"}
     assert attrs["generate/yield"]["pathway.reason"] == {"stringValue": "period"}
     assert attrs["gc/collect"]["pathway.generation"] == {"intValue": "2"}
     assert attrs["tick"]["pathway.gc0_passes"] == {"intValue": "1"}
